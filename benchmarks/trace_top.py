@@ -9,8 +9,14 @@ per-term accounting.
     python benchmarks/trace_top.py benchmarks/profiles/r4_10m [N]
 
 Accepts a trace directory (finds the newest *.trace.json.gz under it)
-or a direct file path.  Prints one JSON line per op: name, calls, total
-ms, share of the traced device time.
+or a direct file path.  Prints one JSON line per leaf op: name, calls,
+total ms, share of the traced device time.  On a chip trace, one JSON
+line per phase of the round follows (the ``jax.named_scope`` each op
+ran under: churn, liveness, prep, push, pull, update, census, ...).
+When the trace holds the program's own spans (``gossip.<name>``, as a
+CLI ``--profile-dir`` capture does), one JSON line per span name
+follows: count, total ms, and the ms in which the device was idle with
+that span the innermost open.
 
 The summarizer itself lives in
 ``p2p_gossipprotocol_tpu/telemetry/traceview.py`` now (this script
@@ -28,9 +34,9 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 
 from p2p_gossipprotocol_tpu.telemetry.traceview import (  # noqa: E402
-    find_trace, summarize)
+    find_trace, program_spans, scopes, summarize)
 
-__all__ = ["find_trace", "summarize", "main"]
+__all__ = ["find_trace", "program_spans", "scopes", "summarize", "main"]
 
 
 def main() -> int:
@@ -41,6 +47,8 @@ def main() -> int:
     top_n = int(sys.argv[2]) if len(sys.argv) > 2 else 20
     print(json.dumps({"trace": trace_file}))
     for row in summarize(trace_file, top_n):
+        print(json.dumps(row))
+    for row in scopes(trace_file) + program_spans(trace_file):
         print(json.dumps(row))
     return 0
 

@@ -39,6 +39,7 @@ import jax.numpy as jnp
 from flax import struct
 
 from p2p_gossipprotocol_tpu import faults as faults_lib
+from p2p_gossipprotocol_tpu import telemetry
 from p2p_gossipprotocol_tpu.liveness import ChurnConfig
 from p2p_gossipprotocol_tpu.ops.aligned_kernel import (LANES, gossip_pass,
                                                        interpret_for,
@@ -2008,34 +2009,47 @@ class AlignedSimulator:
         return self._plan_cache
 
     def init_state(self) -> AlignedState:
-        rows = self.topo.rows
-        key = jax.random.PRNGKey(self.seed)
-        _, key = jax.random.split(key)     # k_byz consumed by the plan
-        valid_b = self.topo.valid_w != 0
-        byz_b, src = self._message_plan()
-        byz_w = jnp.where(byz_b, jnp.int32(-1), jnp.int32(0))
-        # Columns >= n_honest start empty (the adversary's injection
-        # budget); with staggered generation NO columns are seeded here
-        # — column m is injected at round m*k by aligned_round.
-        place = ((jnp.arange(self.n_msgs) < self._n_honest)
-                 & (self.message_stagger <= 0))
-        # Seed words in uint32 with scatter-ADD: distinct message bits add
-        # like OR (so colliding sources keep every rumor — every message
-        # is a distinct (plane, bit) pair), and bit 31 survives (an int32
-        # `1 << 31` would wrap negative and be dropped by a max-combiner).
-        # Bitcast back to the engine's int32 words.
-        m = jnp.arange(self.n_msgs)
-        bits = jnp.where(
-            place, jnp.uint32(1) << (m % WORD_BITS).astype(jnp.uint32), 0)
-        bits_u = jnp.zeros((self.n_words, rows * LANES), jnp.uint32).at[
-            m // WORD_BITS, jnp.where(place, src, 0)].add(bits)
-        seen = jax.lax.bitcast_convert_type(
-            bits_u, jnp.int32).reshape(self.n_words, rows, LANES)
-        strikes = (jnp.zeros((self.topo.n_slots, rows, LANES), jnp.int8)
-                   if self._liveness else None)
-        return AlignedState(seen_w=seen, frontier_w=seen, alive_b=valid_b,
-                            byz_w=byz_w, strikes=strikes, key=key,
-                            round=jnp.int32(0))
+        """The round-0 state, in eager steps; each group of steps is a
+        telemetry span inside ``init_state`` (docs/OBSERVABILITY.md)."""
+        with telemetry.span("init_state"):
+            rows = self.topo.rows
+            with telemetry.span("key"):
+                key = jax.random.PRNGKey(self.seed)
+                _, key = jax.random.split(key)  # k_byz consumed by the plan
+                valid_b = self.topo.valid_w != 0
+            with telemetry.span("message_plan"):
+                byz_b, src = self._message_plan()
+            with telemetry.span("seed"):
+                byz_w = jnp.where(byz_b, jnp.int32(-1), jnp.int32(0))
+                # Columns >= n_honest start empty (the adversary's
+                # injection budget); with staggered generation NO columns
+                # are seeded here — column m is injected at round m*k by
+                # aligned_round.
+                place = ((jnp.arange(self.n_msgs) < self._n_honest)
+                         & (self.message_stagger <= 0))
+                # Seed words in uint32 with scatter-ADD: distinct message
+                # bits add like OR (so colliding sources keep every rumor
+                # — every message is a distinct (plane, bit) pair), and
+                # bit 31 survives (an int32 `1 << 31` would wrap negative
+                # and be dropped by a max-combiner).  Bitcast back to the
+                # engine's int32 words.
+                m = jnp.arange(self.n_msgs)
+                bits = jnp.where(
+                    place,
+                    jnp.uint32(1) << (m % WORD_BITS).astype(jnp.uint32), 0)
+                bits_u = jnp.zeros((self.n_words, rows * LANES),
+                                   jnp.uint32).at[
+                    m // WORD_BITS, jnp.where(place, src, 0)].add(bits)
+                seen = jax.lax.bitcast_convert_type(
+                    bits_u, jnp.int32).reshape(self.n_words, rows, LANES)
+            with telemetry.span("strikes"):
+                strikes = (jnp.zeros((self.topo.n_slots, rows, LANES),
+                                     jnp.int8)
+                           if self._liveness else None)
+            return AlignedState(seen_w=seen, frontier_w=seen,
+                                alive_b=valid_b, byz_w=byz_w,
+                                strikes=strikes, key=key,
+                                round=jnp.int32(0))
 
     # ------------------------------------------------------------------
     def step(self, state: AlignedState, topo: AlignedTopology | None = None
@@ -2116,25 +2130,33 @@ class AlignedSimulator:
         state = self.init_state() if state is None else state
         topo = self.topo if topo is None else topo
         cache_key = (target, max_rounds, check_every)
-        if cache_key not in self._loop_cache:
-            from p2p_gossipprotocol_tpu.state import (build_coverage_loop,
-                                                      stagger_sched_end)
+        with telemetry.span("run_to_coverage"):
+            if cache_key not in self._loop_cache:
+                from p2p_gossipprotocol_tpu.state import (
+                    build_coverage_loop, stagger_sched_end)
 
-            sched_end = stagger_sched_end(self._n_honest,
-                                          self.message_stagger)
-            looped = build_coverage_loop(
-                self.step, target=target, max_rounds=max_rounds,
-                check_every=check_every, sched_end=sched_end)
-            fn = jax.jit(looped)
-            self._loop_cache[cache_key] = fn.lower(state, topo).compile()
-        fn_c = self._loop_cache[cache_key]
-        if warmup:
-            out = fn_c(state, topo)
-            jax.device_get(out[0].round)
-        t0 = _time.perf_counter()
-        st, tp, cov = fn_c(state, topo)
-        rounds_run = int(jax.device_get(st.round))
-        wall = _time.perf_counter() - t0
+                with telemetry.span("compile"):
+                    sched_end = stagger_sched_end(self._n_honest,
+                                                  self.message_stagger)
+                    looped = build_coverage_loop(
+                        self.step, target=target, max_rounds=max_rounds,
+                        check_every=check_every, sched_end=sched_end)
+                    fn = jax.jit(looped)
+                    self._loop_cache[cache_key] = fn.lower(
+                        state, topo).compile()
+                telemetry.counter_add("coverage_loop_compiles_total")
+            fn_c = self._loop_cache[cache_key]
+            if warmup:
+                out = fn_c(state, topo)
+                jax.device_get(out[0].round)
+            t0 = _time.perf_counter()
+            # dispatch: the compiled loop's call until it returns to the
+            # host; fetch: the wait for the round count, i.e. the device
+            with telemetry.span("dispatch"):
+                st, tp, cov = fn_c(state, topo)
+            with telemetry.span("fetch"):
+                rounds_run = int(jax.device_get(st.round))
+            wall = _time.perf_counter() - t0
         return st, tp, rounds_run, wall
 
 
@@ -2235,7 +2257,14 @@ def aligned_round(sim: AlignedSimulator, state: AlignedState,
         it on hardware) and a remote pass over the gathered planes,
         OR-seeded via ``acc_init`` (:func:`_overlap_plans`).
     Everything else — churn, strikes/rewire, byzantine, gossip passes,
-    metrics — is this one code path, so the engines cannot drift."""
+    metrics — is this one code path, so the engines cannot drift.
+
+    Each phase runs under a ``jax.named_scope`` (``churn``,
+    ``liveness``, ``injection``, ``exchange``, ``prep``, ``push``,
+    ``pull``, ``update``, ``census``) and the kernels carry their pass's
+    name (``push``, ``push_self``, ``pull``, ``liveness``), so a
+    profiler trace tells the phases apart; names change op metadata
+    only, never a result."""
     if msg_reduce is None:
         msg_reduce = reduce
     if hash_seed is None:
@@ -2265,133 +2294,136 @@ def aligned_round(sim: AlignedSimulator, state: AlignedState,
     key, k_churn, k_rew, k_pull, k_fan = jax.random.split(state.key, 5)
     del k_rew
 
-    alive_b = state.alive_b
-    if sim.churn.rate > 0.0 or sim.churn.revive > 0.0:
-        alive_b = churn_rows(k_churn, grows, alive_b, valid_b,
-                             state.round, sim.churn)
+    with jax.named_scope("churn"):
+        alive_b = state.alive_b
+        if sim.churn.rate > 0.0 or sim.churn.revive > 0.0:
+            alive_b = churn_rows(k_churn, grows, alive_b, valid_b,
+                                 state.round, sim.churn)
 
-    # -- fault plane (faults.FaultPlan; None compiles the plain round) --
-    # Every fault draw is keyed on (plan seed, round, global row) — never
-    # the simulation's own key chain — so an unfaulted run's trajectory
-    # is untouched and faulted runs keep the bitwise sharded-vs-unsharded
-    # contract (per-global-row fold_ins + the kernels' global-id hash).
-    plan = sim.faults
-    fkey = None
-    if plan is not None and plan.engine_active():
-        fkey = faults_lib.round_key(plan, state.round)
-    if plan is not None and (plan.crash or plan.recover):
-        # Scheduled crash/recovery: real deaths/revivals — the liveness
-        # strikes below observe them, unlike partitions, which sever
-        # transfers only.  Padding rows can never revive (& valid_b).
-        alive_b = faults_lib.schedule_step(
-            plan, fkey, alive_b, valid_b, state.round,
-            lambda k: row_uniform(k, grows, (LANES,)))
-    defer_w = None
-    if (plan is not None and plan.delay > 0.0
-            and sim.mode in ("push", "pushpull")):
-        # Relay delay: a peer's push of its frontier slips one round
-        # (sender-side, per-peer — the synchronous-round model's delayed
-        # delivery); pull serves are unaffected (the peer's state is
-        # intact, only its relay is late).
-        u = row_uniform(jax.random.fold_in(fkey, faults_lib.TAG_DEFER),
-                        grows, (LANES,))
-        defer_w = jnp.where((u < plan.delay) & alive_b,
-                            jnp.int32(-1), jnp.int32(0))
-    kf = plan is not None and plan.kernel_active()
-    if kf:
-        # Per-link drop + partition gates, evaluated in-register inside
-        # the kernels (ops/aligned_kernel.py fault gate) — no HBM mask
-        # tensor exists.  Push and pull passes get decorrelated hash
-        # seeds (two passes = two independent uses of the same links).
-        gbase_f = grows[::topo.rowblk]
-        fmeta_push = faults_lib.kernel_meta(plan, state.round, 0)
-        fmeta_pull = faults_lib.kernel_meta(plan, state.round, 1)
-    alive_w = jnp.where(alive_b, jnp.int32(-1), jnp.int32(0))
+        # -- fault plane (faults.FaultPlan; None compiles the plain round) --
+        # Every fault draw is keyed on (plan seed, round, global row) — never
+        # the simulation's own key chain — so an unfaulted run's trajectory
+        # is untouched and faulted runs keep the bitwise sharded-vs-unsharded
+        # contract (per-global-row fold_ins + the kernels' global-id hash).
+        plan = sim.faults
+        fkey = None
+        if plan is not None and plan.engine_active():
+            fkey = faults_lib.round_key(plan, state.round)
+        if plan is not None and (plan.crash or plan.recover):
+            # Scheduled crash/recovery: real deaths/revivals — the liveness
+            # strikes below observe them, unlike partitions, which sever
+            # transfers only.  Padding rows can never revive (& valid_b).
+            alive_b = faults_lib.schedule_step(
+                plan, fkey, alive_b, valid_b, state.round,
+                lambda k: row_uniform(k, grows, (LANES,)))
+        defer_w = None
+        if (plan is not None and plan.delay > 0.0
+                and sim.mode in ("push", "pushpull")):
+            # Relay delay: a peer's push of its frontier slips one round
+            # (sender-side, per-peer — the synchronous-round model's delayed
+            # delivery); pull serves are unaffected (the peer's state is
+            # intact, only its relay is late).
+            u = row_uniform(jax.random.fold_in(fkey, faults_lib.TAG_DEFER),
+                            grows, (LANES,))
+            defer_w = jnp.where((u < plan.delay) & alive_b,
+                                jnp.int32(-1), jnp.int32(0))
+        kf = plan is not None and plan.kernel_active()
+        if kf:
+            # Per-link drop + partition gates, evaluated in-register inside
+            # the kernels (ops/aligned_kernel.py fault gate) — no HBM mask
+            # tensor exists.  Push and pull passes get decorrelated hash
+            # seeds (two passes = two independent uses of the same links).
+            gbase_f = grows[::topo.rowblk]
+            fmeta_push = faults_lib.kernel_meta(plan, state.round, 0)
+            fmeta_pull = faults_lib.kernel_meta(plan, state.round, 1)
+        alive_w = jnp.where(alive_b, jnp.int32(-1), jnp.int32(0))
 
-    strikes = state.strikes
-    n_evict = jnp.int32(0)
     rolls_off = topo.rolls + t_off
-    if sim._liveness:
-        # Candidate lanes are hashed in-kernel from (global peer id,
-        # slot, round) — no int8[D, R, 128] tensor materialized per
-        # round — and with ``liveness_every > 1`` the whole pass
-        # (including its all_gather on the sharded path) only runs on
-        # sweep rounds, mirroring the reference's probe cadence of one
-        # ping sweep per ~2.6 message intervals (peer.cpp:330 vs 377).
-        blk = min(topo.rowblk, topo.colidx.shape[1])
+    with jax.named_scope("liveness"):
+        strikes = state.strikes
+        n_evict = jnp.int32(0)
+        if sim._liveness:
+            # Candidate lanes are hashed in-kernel from (global peer id,
+            # slot, round) — no int8[D, R, 128] tensor materialized per
+            # round — and with ``liveness_every > 1`` the whole pass
+            # (including its all_gather on the sharded path) only runs on
+            # sweep rounds, mirroring the reference's probe cadence of one
+            # ping sweep per ~2.6 message intervals (peer.cpp:330 vs 377).
+            blk = min(topo.rowblk, topo.colidx.shape[1])
 
-        def lv_run(ops):
-            col, stk = ops
-            y_alive = (gather(alive_w) if fused
-                       else prow(gather(alive_w)))
-            col2, stk2, evict8 = liveness_pass(
-                y_alive, col, stk, topo.deg, rolls_off, topo.subrolls,
-                gbase=grows[::blk], round_idx=state.round,
-                hash_seed=hash_seed,
-                ytab=ytab_local if fused else None,
-                max_strikes=sim.max_strikes,
-                rowblk=topo.rowblk, interpret=sim.interpret)
-            return col2, stk2, jnp.sum(evict8, dtype=jnp.int32)
+            def lv_run(ops):
+                col, stk = ops
+                y_alive = (gather(alive_w) if fused
+                           else prow(gather(alive_w)))
+                col2, stk2, evict8 = liveness_pass(
+                    y_alive, col, stk, topo.deg, rolls_off, topo.subrolls,
+                    gbase=grows[::blk], round_idx=state.round,
+                    hash_seed=hash_seed,
+                    ytab=ytab_local if fused else None,
+                    max_strikes=sim.max_strikes,
+                    rowblk=topo.rowblk, interpret=sim.interpret)
+                return col2, stk2, jnp.sum(evict8, dtype=jnp.int32)
 
-        def lv_skip(ops):
-            col, stk = ops
-            return col, stk, jnp.int32(0)
+            def lv_skip(ops):
+                col, stk = ops
+                return col, stk, jnp.int32(0)
 
-        if sim.liveness_every > 1:
-            colidx, strikes, ev_local = jax.lax.cond(
-                state.round % sim.liveness_every == 0, lv_run, lv_skip,
-                (topo.colidx, strikes))
-        else:
-            colidx, strikes, ev_local = lv_run((topo.colidx, strikes))
-        topo = topo.replace(colidx=colidx)
-        n_evict = reduce(ev_local)
+            if sim.liveness_every > 1:
+                colidx, strikes, ev_local = jax.lax.cond(
+                    state.round % sim.liveness_every == 0, lv_run, lv_skip,
+                    (topo.colidx, strikes))
+            else:
+                colidx, strikes, ev_local = lv_run((topo.colidx, strikes))
+            topo = topo.replace(colidx=colidx)
+            n_evict = reduce(ev_local)
 
-    seen_w, frontier_w = state.seen_w, state.frontier_w
-    if sim._n_honest < sim.n_msgs:
-        # Byzantine injection (models/byzantine.py:24-38): junk bits
-        # enter every byzantine peer's seen+frontier each round.
-        inject = state.byz_w[None] & jmask[:, None, None] & ~seen_w
-        seen_w = seen_w | inject
-        frontier_w = frontier_w | inject
+    with jax.named_scope("injection"):
+        seen_w, frontier_w = state.seen_w, state.frontier_w
+        if sim._n_honest < sim.n_msgs:
+            # Byzantine injection (models/byzantine.py:24-38): junk bits
+            # enter every byzantine peer's seen+frontier each round.
+            inject = state.byz_w[None] & jmask[:, None, None] & ~seen_w
+            seen_w = seen_w | inject
+            frontier_w = frontier_w | inject
 
-    if sim.message_stagger > 0:
-        # Staggered generation: round m*k injects column m's bit at its
-        # source (the messageGenerationLoop tick, peer.cpp:357-377) —
-        # one dynamic single-element update, no plane-sized traffic.
-        # Runs after churn, so a source that died before its activation
-        # round never generates (the reference's generation thread stops
-        # with its process); the frontier bit is relayed THIS round,
-        # like the round-0 seeding.  All coordinates derive from the
-        # replicated round scalar + the deterministic plan, so every
-        # shard computes the same global decision and applies it only if
-        # the (plane, row) cell is local.
-        k = sim.message_stagger
-        r = state.round
-        m = r // k
-        srcs = sim._message_plan()[1] if msg_srcs is None else msg_srcs
-        src = srcs[jnp.clip(m, 0, sim.n_msgs - 1)]
-        grow, lane = src // LANES, src % LANES
-        W_l, rows_l = seen_w.shape[0], seen_w.shape[1]
-        lrow = grow - grows[0]
-        lw = (m // WORD_BITS) - w_off
-        safe_r = jnp.clip(lrow, 0, rows_l - 1)
-        safe_w = jnp.clip(lw, 0, W_l - 1)
-        src_alive = jax.lax.dynamic_slice(
-            alive_b, (safe_r, lane), (1, 1))[0, 0]
-        do = ((r % k == 0) & (m < sim._n_honest) & src_alive
-              & (lrow >= 0) & (lrow < rows_l)
-              & (lw >= 0) & (lw < W_l))
-        bit = jnp.where(do,
-                        jnp.left_shift(jnp.int32(1), m % WORD_BITS),
-                        jnp.int32(0))
-        cell = (safe_w, safe_r, lane)
-        seen_w = jax.lax.dynamic_update_slice(
-            seen_w,
-            jax.lax.dynamic_slice(seen_w, cell, (1, 1, 1)) | bit, cell)
-        frontier_w = jax.lax.dynamic_update_slice(
-            frontier_w,
-            jax.lax.dynamic_slice(frontier_w, cell, (1, 1, 1)) | bit,
-            cell)
+        if sim.message_stagger > 0:
+            # Staggered generation: round m*k injects column m's bit at its
+            # source (the messageGenerationLoop tick, peer.cpp:357-377) —
+            # one dynamic single-element update, no plane-sized traffic.
+            # Runs after churn, so a source that died before its activation
+            # round never generates (the reference's generation thread stops
+            # with its process); the frontier bit is relayed THIS round,
+            # like the round-0 seeding.  All coordinates derive from the
+            # replicated round scalar + the deterministic plan, so every
+            # shard computes the same global decision and applies it only if
+            # the (plane, row) cell is local.
+            k = sim.message_stagger
+            r = state.round
+            m = r // k
+            srcs = sim._message_plan()[1] if msg_srcs is None else msg_srcs
+            src = srcs[jnp.clip(m, 0, sim.n_msgs - 1)]
+            grow, lane = src // LANES, src % LANES
+            W_l, rows_l = seen_w.shape[0], seen_w.shape[1]
+            lrow = grow - grows[0]
+            lw = (m // WORD_BITS) - w_off
+            safe_r = jnp.clip(lrow, 0, rows_l - 1)
+            safe_w = jnp.clip(lw, 0, W_l - 1)
+            src_alive = jax.lax.dynamic_slice(
+                alive_b, (safe_r, lane), (1, 1))[0, 0]
+            do = ((r % k == 0) & (m < sim._n_honest) & src_alive
+                  & (lrow >= 0) & (lrow < rows_l)
+                  & (lw >= 0) & (lw < W_l))
+            bit = jnp.where(do,
+                            jnp.left_shift(jnp.int32(1), m % WORD_BITS),
+                            jnp.int32(0))
+            cell = (safe_w, safe_r, lane)
+            seen_w = jax.lax.dynamic_update_slice(
+                seen_w,
+                jax.lax.dynamic_slice(seen_w, cell, (1, 1, 1)) | bit, cell)
+            frontier_w = jax.lax.dynamic_update_slice(
+                frontier_w,
+                jax.lax.dynamic_slice(frontier_w, cell, (1, 1, 1)) | bit,
+                cell)
 
     # -- frontier-sparse exchange (sharded engines, fr is not None) ----
     # Runs AFTER the injections above: byzantine junk and staggered
@@ -2405,131 +2437,135 @@ def aligned_round(sim: AlignedSimulator, state: AlignedState,
     fr_sparse = fr_words = fr_sparse_ici = None
     fr_halving = fr_halving_ici = None
     if fr is not None:
-        (F_g, fr, fr_sparse, fr_words, fr_sparse_ici, fr_halving,
-         fr_halving_ici) = _frontier_exchange(
-                sim, frontier_w, fr, fr_axis, fr_pmax_axes, fr_shards,
-                ici_axis=fr_ici_axis, n_hosts=fr_hosts)
-        seen_g = fr.replica_w
-        if not fused:
-            g_alive = gather(alive_w)
-            g_byz = fr.byz_g        # static draw, gathered at carry init
-            if defer_w is not None:
-                g_defer = gather(defer_w)
+        with jax.named_scope("exchange"):
+            (F_g, fr, fr_sparse, fr_words, fr_sparse_ici, fr_halving,
+             fr_halving_ici) = _frontier_exchange(
+                    sim, frontier_w, fr, fr_axis, fr_pmax_axes, fr_shards,
+                    ici_axis=fr_ici_axis, n_hosts=fr_hosts)
+            seen_g = fr.replica_w
+            if not fused:
+                g_alive = gather(alive_w)
+                g_byz = fr.byz_g    # static draw, gathered at carry init
+                if defer_w is not None:
+                    g_defer = gather(defer_w)
 
-    if fused:
-        # the in-kernel send mask: -1 where the source is alive and
-        # honest (dead peers don't send; byzantine peers never relay);
-        # the push pass additionally drops deferred relayers, while the
-        # pull pass keeps serving them (delay is a relay fault, not a
-        # state fault)
-        src_ok = gather(alive_w & ~state.byz_w)
-        src_ok_push = (gather(alive_w & ~state.byz_w & ~defer_w)
-                       if defer_w is not None else src_ok)
-    # In-kernel seen-update (sim.fuse_update): the FINAL pass of the
-    # round takes the receiver's seen planes + receive mask and emits
-    # (new, seen') straight from its VMEM-resident accumulator — plus
-    # the round CENSUS as per-block partial-popcount tiles (deliveries
-    # bits of ``new``, coverage bits of ``seen' & ok & hmask``), so the
-    # XLA-side 2W-plane metrics re-read does not exist on this path; in
-    # pushpull the push receive seeds the pull accumulator.  Dead peers
-    # don't receive either way (the link is gone — gossip.py:_advance).
-    fin = sim.fuse_update
-    rmask_w = (topo.valid_w & alive_w) if fin else None
-    # ok = live, honest, valid — the coverage row filter (edge engine's
-    # coverage_of); feeds the in-kernel census and the n_ok denominator.
-    ok_w = alive_w & ~state.byz_w & topo.valid_w
-    new = seen = None
-    dpb = cpb = None
-    deferred_w = None
-    if defer_w is not None:
-        # The would-have-been relays a deferred peer holds back: they
-        # re-enter the frontier below, so the transfer lands one round
-        # late instead of never (flood-once would otherwise drop it).
-        deferred_w = (frontier_w & alive_w[None] & ~state.byz_w[None]
-                      & defer_w[None])
+    with jax.named_scope("prep"):
+        if fused:
+            # the in-kernel send mask: -1 where the source is alive and
+            # honest (dead peers don't send; byzantine peers never relay);
+            # the push pass additionally drops deferred relayers, while the
+            # pull pass keeps serving them (delay is a relay fault, not a
+            # state fault)
+            src_ok = gather(alive_w & ~state.byz_w)
+            src_ok_push = (gather(alive_w & ~state.byz_w & ~defer_w)
+                           if defer_w is not None else src_ok)
+        # In-kernel seen-update (sim.fuse_update): the FINAL pass of the
+        # round takes the receiver's seen planes + receive mask and emits
+        # (new, seen') straight from its VMEM-resident accumulator — plus
+        # the round CENSUS as per-block partial-popcount tiles (deliveries
+        # bits of ``new``, coverage bits of ``seen' & ok & hmask``), so the
+        # XLA-side 2W-plane metrics re-read does not exist on this path; in
+        # pushpull the push receive seeds the pull accumulator.  Dead peers
+        # don't receive either way (the link is gone — gossip.py:_advance).
+        fin = sim.fuse_update
+        rmask_w = (topo.valid_w & alive_w) if fin else None
+        # ok = live, honest, valid — the coverage row filter (edge engine's
+        # coverage_of); feeds the in-kernel census and the n_ok denominator.
+        ok_w = alive_w & ~state.byz_w & topo.valid_w
+        new = seen = None
+        dpb = cpb = None
+        deferred_w = None
+        if defer_w is not None:
+            # The would-have-been relays a deferred peer holds back: they
+            # re-enter the frontier below, so the transfer lands one round
+            # late instead of never (flood-once would otherwise drop it).
+            deferred_w = (frontier_w & alive_w[None] & ~state.byz_w[None]
+                          & defer_w[None])
     if sim.mode in ("push", "pushpull"):
         # Dead peers don't send; byzantine peers never relay (suppression,
         # models/gossip.py:50-58) — both masked at the source words.
-        if fr is not None:
-            if fused:
-                y = F_g
+        with jax.named_scope("prep"):
+            if fr is not None:
+                if fused:
+                    y = F_g
+                else:
+                    send_g = F_g & g_alive[None] & ~g_byz[None]
+                    if g_defer is not None:
+                        send_g = send_g & ~g_defer[None]
+                    y = prow(send_g)
+            elif fused:
+                y = gather(frontier_w)
             else:
-                send_g = F_g & g_alive[None] & ~g_byz[None]
-                if g_defer is not None:
-                    send_g = send_g & ~g_defer[None]
-                y = prow(send_g)
-        elif fused:
-            y = gather(frontier_w)
-        else:
-            send = frontier_w & alive_w[None] & ~state.byz_w[None]
-            if defer_w is not None:
-                send = send & ~defer_w[None]
-            y = prow(gather(send))
-        # Compute-hidden exchange (round 10): the self/remote split
-        # engages only sharded, fused, and with the knob resolved on —
-        # pass A's plan depends on nothing gathered, so the collective
-        # that produced ``y`` overlaps it on hardware.
-        split = sim._overlap and n_shards > 1 and fused
-        yidx = yact = None
-        yidx_a = yact_a = None
-        if split:
-            (yidx_a, yact_a), (yidx, yact) = _overlap_plans(
-                frontier_w, y, topo.rowblk, t_off, ytab_local,
-                skip=sim._frontier_skip)
-        elif sim._frontier_skip:
-            # in-kernel block skipping: y blocks with no send bits this
-            # round are gated off and never streamed — exact however
-            # sparse or dense the frontier is (dead blocks OR in zero)
-            yidx, yact = _skip_plan(
-                y, topo.rowblk, state.seen_w.shape[1] // topo.rowblk,
-                rolls_off=rolls_off,
-                ytab_local=ytab_local if fused else None)
-        if sim.fanout > 0:
-            # Rumor mongering: each peer listens on a random fanout-slot
-            # window this round (shard-invariant per-row draw, same
-            # discipline as the pull contact below).
-            u = row_randint(k_fan, grows, (LANES,), 0, 1 << 30, jnp.int32)
-            deg32 = topo.deg.astype(jnp.int32)
-            shift = (u % jnp.maximum(deg32, 1)).astype(jnp.int8)
-        else:
-            shift = None
-        push_final = fin and sim.mode == "push"
-        acc0 = None
-        if split:
-            # Pass A: the self-shard contribution, from purely LOCAL
-            # operands (raw local send planes + the ungathered send
-            # mask) — traced with no dependency on the exchange, which
-            # is the whole overlap.  Remote steps are gated off; pass B
-            # gates the local ones off and OR-seeds from here.
-            ok_self = alive_w & ~state.byz_w
-            if defer_w is not None:
-                ok_self = ok_self & ~defer_w
-            acc0 = gossip_pass(frontier_w, topo.colidx, topo.deg,
-                               rolls_off, topo.subrolls, pull=False,
-                               fanout=sim.fanout, shift=shift,
-                               ytab=ytab_local, src_ok=ok_self,
+                send = frontier_w & alive_w[None] & ~state.byz_w[None]
+                if defer_w is not None:
+                    send = send & ~defer_w[None]
+                y = prow(gather(send))
+            # Compute-hidden exchange (round 10): the self/remote split
+            # engages only sharded, fused, and with the knob resolved on —
+            # pass A's plan depends on nothing gathered, so the collective
+            # that produced ``y`` overlaps it on hardware.
+            split = sim._overlap and n_shards > 1 and fused
+            yidx = yact = None
+            yidx_a = yact_a = None
+            if split:
+                (yidx_a, yact_a), (yidx, yact) = _overlap_plans(
+                    frontier_w, y, topo.rowblk, t_off, ytab_local,
+                    skip=sim._frontier_skip)
+            elif sim._frontier_skip:
+                # in-kernel block skipping: y blocks with no send bits this
+                # round are gated off and never streamed — exact however
+                # sparse or dense the frontier is (dead blocks OR in zero)
+                yidx, yact = _skip_plan(
+                    y, topo.rowblk, state.seen_w.shape[1] // topo.rowblk,
+                    rolls_off=rolls_off,
+                    ytab_local=ytab_local if fused else None)
+            if sim.fanout > 0:
+                # Rumor mongering: each peer listens on a random fanout-slot
+                # window this round (shard-invariant per-row draw, same
+                # discipline as the pull contact below).
+                u = row_randint(k_fan, grows, (LANES,), 0, 1 << 30, jnp.int32)
+                deg32 = topo.deg.astype(jnp.int32)
+                shift = (u % jnp.maximum(deg32, 1)).astype(jnp.int8)
+            else:
+                shift = None
+            push_final = fin and sim.mode == "push"
+            acc0 = None
+        with jax.named_scope("push"):
+            if split:
+                # Pass A: the self-shard contribution, from purely LOCAL
+                # operands (raw local send planes + the ungathered send
+                # mask) — traced with no dependency on the exchange, which
+                # is the whole overlap.  Remote steps are gated off; pass B
+                # gates the local ones off and OR-seeds from here.
+                ok_self = alive_w & ~state.byz_w
+                if defer_w is not None:
+                    ok_self = ok_self & ~defer_w
+                acc0 = gossip_pass(frontier_w, topo.colidx, topo.deg,
+                                   rolls_off, topo.subrolls, pull=False,
+                                   fanout=sim.fanout, shift=shift,
+                                   ytab=ytab_local, src_ok=ok_self,
+                                   fault_meta=fmeta_push if kf else None,
+                                   gbase=gbase_f if kf else None,
+                                   yidx=yidx_a, yact=yact_a,
+                                   prefetch_depth=sim._prefetch,
+                                   rowblk=topo.rowblk, name="push_self",
+                                   interpret=sim.interpret)
+            recv = gossip_pass(y, topo.colidx, topo.deg, rolls_off,
+                               topo.subrolls, pull=False, fanout=sim.fanout,
+                               shift=shift,
+                               ytab=ytab_local if fused else None,
+                               src_ok=src_ok_push if fused else None,
+                               acc_init=acc0,
+                               seen=seen_w if push_final else None,
+                               rmask=rmask_w if push_final else None,
+                               census_ok=ok_w if push_final else None,
+                               census_hmask=hmask if push_final else None,
                                fault_meta=fmeta_push if kf else None,
                                gbase=gbase_f if kf else None,
-                               yidx=yidx_a, yact=yact_a,
+                               yidx=yidx, yact=yact,
                                prefetch_depth=sim._prefetch,
-                               rowblk=topo.rowblk,
+                               rowblk=topo.rowblk, name="push",
                                interpret=sim.interpret)
-        recv = gossip_pass(y, topo.colidx, topo.deg, rolls_off,
-                           topo.subrolls, pull=False, fanout=sim.fanout,
-                           shift=shift,
-                           ytab=ytab_local if fused else None,
-                           src_ok=src_ok_push if fused else None,
-                           acc_init=acc0,
-                           seen=seen_w if push_final else None,
-                           rmask=rmask_w if push_final else None,
-                           census_ok=ok_w if push_final else None,
-                           census_hmask=hmask if push_final else None,
-                           fault_meta=fmeta_push if kf else None,
-                           gbase=gbase_f if kf else None,
-                           yidx=yidx, yact=yact,
-                           prefetch_depth=sim._prefetch,
-                           rowblk=topo.rowblk,
-                           interpret=sim.interpret)
         if push_final:
             new, seen, dpb, cpb = recv
     elif not fin:               # pure anti-entropy pull
@@ -2542,101 +2578,106 @@ def aligned_round(sim: AlignedSimulator, state: AlignedState,
         # group only and the pass runs a Dw-slot grid (one shared block
         # roll -> ONE seen-plane stream); Dw == n_slots when off, which
         # reproduces the unrestricted draw and grid exactly.
-        if fr is not None:
-            # the per-chip replica IS gather(seen) bitwise — the dense
-            # seen gather does not exist on this path at all
-            ys = (seen_g if fused
-                  else prow(seen_g & g_alive[None] & ~g_byz[None]))
-        elif fused:
-            ys = gather(state.seen_w)
-        else:
-            ys = prow(gather(
-                state.seen_w & alive_w[None] & ~state.byz_w[None]))
-        Dw = sim._pull_slots
-        u = row_randint(k_pull, grows, (LANES,), 0, 1 << 30, jnp.int32)
-        deg32 = jnp.minimum(topo.deg.astype(jnp.int32), Dw)
-        delta = (u % jnp.maximum(deg32, 1)).astype(jnp.int8)
-        delta = jnp.where(deg32 > 0, delta,
-                          jnp.int8(Dw))                # no contact
-        pulled = gossip_pass(ys, topo.colidx[:Dw], delta, rolls_off[:Dw],
-                             topo.subrolls[:Dw], pull=True,
-                             ytab=ytab_local[:Dw] if fused else None,
-                             src_ok=src_ok if fused else None,
-                             acc_init=(recv if fin and
-                                       sim.mode == "pushpull" else None),
-                             seen=seen_w if fin else None,
-                             rmask=rmask_w,
-                             census_ok=ok_w if fin else None,
-                             census_hmask=hmask if fin else None,
-                             fault_meta=fmeta_pull if kf else None,
-                             gbase=gbase_f if kf else None,
-                             prefetch_depth=sim._prefetch,
-                             rowblk=topo.rowblk,
-                             interpret=sim.interpret)
-        if fin:
-            new, seen, dpb, cpb = pulled
-        else:
-            recv = recv | pulled
+        with jax.named_scope("prep"):
+            if fr is not None:
+                # the per-chip replica IS gather(seen) bitwise — the dense
+                # seen gather does not exist on this path at all
+                ys = (seen_g if fused
+                      else prow(seen_g & g_alive[None] & ~g_byz[None]))
+            elif fused:
+                ys = gather(state.seen_w)
+            else:
+                ys = prow(gather(
+                    state.seen_w & alive_w[None] & ~state.byz_w[None]))
+            Dw = sim._pull_slots
+            u = row_randint(k_pull, grows, (LANES,), 0, 1 << 30, jnp.int32)
+            deg32 = jnp.minimum(topo.deg.astype(jnp.int32), Dw)
+            delta = (u % jnp.maximum(deg32, 1)).astype(jnp.int8)
+            delta = jnp.where(deg32 > 0, delta,
+                              jnp.int8(Dw))                # no contact
+        with jax.named_scope("pull"):
+            pulled = gossip_pass(ys, topo.colidx[:Dw], delta, rolls_off[:Dw],
+                                 topo.subrolls[:Dw], pull=True,
+                                 ytab=ytab_local[:Dw] if fused else None,
+                                 src_ok=src_ok if fused else None,
+                                 acc_init=(recv if fin and
+                                           sim.mode == "pushpull" else None),
+                                 seen=seen_w if fin else None,
+                                 rmask=rmask_w,
+                                 census_ok=ok_w if fin else None,
+                                 census_hmask=hmask if fin else None,
+                                 fault_meta=fmeta_pull if kf else None,
+                                 gbase=gbase_f if kf else None,
+                                 prefetch_depth=sim._prefetch,
+                                 rowblk=topo.rowblk, name="pull",
+                                 interpret=sim.interpret)
+        with jax.named_scope("update"):
+            if fin:
+                new, seen, dpb, cpb = pulled
+            else:
+                recv = recv | pulled
 
-    if not fin:
-        recv = recv & topo.valid_w[None] & alive_w[None]
-        new = recv & ~seen_w
-        seen = seen_w | new
-        # Receipts of already-seen messages — the degradation metric
-        # link faults inflate (every redundant transfer still landed).
-        # The fused path never materializes recv (its kernel emits
-        # (new, seen') straight from VMEM), so it reports 0 there.
-        redeliveries = _pair_total(msg_reduce(_popcount_pair(
-            recv & seen_w)))
-    else:
-        redeliveries = jnp.float32(0)
-    # In this engine deliveries == frontier bits by construction (every
-    # first receipt enters the next frontier); both keys are kept for
-    # surface parity with sim.Simulator's metric dict.  Totals ride the
-    # exact [hi, lo] int pair through the cross-shard reduction (a flat
-    # int32 popcount wraps at the 10M x 256 scale) and become one
-    # float32 only after it — bitwise-identical on every sharding.  On
-    # the fused path both censuses come from the kernel's per-block
-    # partial tiles instead of a 2W-plane re-read; _pair_total's
-    # canonical normalization makes the two decompositions produce the
-    # bit-identical float at any scale.
-    deliveries = _pair_total(msg_reduce(
-        _census_pair(dpb) if fin else _popcount_pair(new)))
-    # Coverage over honest columns of LIVE HONEST peers (ok_w above) —
-    # the edge engine's coverage_of (sim.py:33-43).  Each ok peer
-    # contributes 32 bits to popcount(ok_w), hence the >> 5 peer count.
-    # 32 bits per ok peer, so a flat int32 popcount wraps at exactly
-    # 2^26 peers (the 64M probe: n_ok collapsed to 1, coverage 8.0).
-    # The [hi, lo] pair rides the cross-shard reduce exactly; the final
-    # float32 /32 is within +/-4 peers at 67M — invisible to any
-    # coverage threshold.
-    n_ok = jnp.maximum(
-        _pair_total(reduce(_popcount_pair(ok_w))) / 32.0, 1.0)
-    if sim.message_stagger > 0:
-        # mean over the columns GENERATED so far (sim.py:coverage_of has
-        # the rationale: a rumor that doesn't exist — not yet scheduled,
-        # or lost to a pre-activation source death — can't count against
-        # coverage).  Generated derives from the seen planes themselves:
-        # OR over rows+lanes leaves one word per plane whose set bits
-        # are the nonempty columns; cross-shard, the OR rides a psum of
-        # the unpacked bits.
-        or_w = jax.lax.reduce(seen, jnp.int32(0), jax.lax.bitwise_or,
-                              (1, 2))
-        shifts = jnp.arange(WORD_BITS, dtype=jnp.int32)
-        bits = (or_w[:, None] >> shifts) & 1
-        hbits = (hmask[:, None] >> shifts) & 1
-        gen = (reduce(bits) > 0) & (hbits > 0)
-        n_cols = jnp.maximum(
-            msg_only_reduce(jnp.sum(gen, dtype=jnp.int32)),
-            1).astype(jnp.float32)
-    else:
-        n_cols = jnp.float32(sim._n_honest)
-    coverage = _div_rn(_pair_total(msg_reduce(
-        _census_pair(cpb) if fin else _popcount_pair(
-            seen & ok_w[None] & hmask[:, None, None]))), n_ok * n_cols)
-    live = _pair_total(reduce(_popcount_pair(
-        alive_w & topo.valid_w))) / 32.0
-    frontier = new if deferred_w is None else new | deferred_w
+    with jax.named_scope("update"):
+        if not fin:
+            recv = recv & topo.valid_w[None] & alive_w[None]
+            new = recv & ~seen_w
+            seen = seen_w | new
+            # Receipts of already-seen messages — the degradation metric
+            # link faults inflate (every redundant transfer still landed).
+            # The fused path never materializes recv (its kernel emits
+            # (new, seen') straight from VMEM), so it reports 0 there.
+            redeliveries = _pair_total(msg_reduce(_popcount_pair(
+                recv & seen_w)))
+        else:
+            redeliveries = jnp.float32(0)
+        frontier = new if deferred_w is None else new | deferred_w
+    with jax.named_scope("census"):
+        # In this engine deliveries == frontier bits by construction (every
+        # first receipt enters the next frontier); both keys are kept for
+        # surface parity with sim.Simulator's metric dict.  Totals ride the
+        # exact [hi, lo] int pair through the cross-shard reduction (a flat
+        # int32 popcount wraps at the 10M x 256 scale) and become one
+        # float32 only after it — bitwise-identical on every sharding.  On
+        # the fused path both censuses come from the kernel's per-block
+        # partial tiles instead of a 2W-plane re-read; _pair_total's
+        # canonical normalization makes the two decompositions produce the
+        # bit-identical float at any scale.
+        deliveries = _pair_total(msg_reduce(
+            _census_pair(dpb) if fin else _popcount_pair(new)))
+        # Coverage over honest columns of LIVE HONEST peers (ok_w above) —
+        # the edge engine's coverage_of (sim.py:33-43).  Each ok peer
+        # contributes 32 bits to popcount(ok_w), hence the >> 5 peer count.
+        # 32 bits per ok peer, so a flat int32 popcount wraps at exactly
+        # 2^26 peers (the 64M probe: n_ok collapsed to 1, coverage 8.0).
+        # The [hi, lo] pair rides the cross-shard reduce exactly; the final
+        # float32 /32 is within +/-4 peers at 67M — invisible to any
+        # coverage threshold.
+        n_ok = jnp.maximum(
+            _pair_total(reduce(_popcount_pair(ok_w))) / 32.0, 1.0)
+        if sim.message_stagger > 0:
+            # mean over the columns GENERATED so far (sim.py:coverage_of has
+            # the rationale: a rumor that doesn't exist — not yet scheduled,
+            # or lost to a pre-activation source death — can't count against
+            # coverage).  Generated derives from the seen planes themselves:
+            # OR over rows+lanes leaves one word per plane whose set bits
+            # are the nonempty columns; cross-shard, the OR rides a psum of
+            # the unpacked bits.
+            or_w = jax.lax.reduce(seen, jnp.int32(0), jax.lax.bitwise_or,
+                                  (1, 2))
+            shifts = jnp.arange(WORD_BITS, dtype=jnp.int32)
+            bits = (or_w[:, None] >> shifts) & 1
+            hbits = (hmask[:, None] >> shifts) & 1
+            gen = (reduce(bits) > 0) & (hbits > 0)
+            n_cols = jnp.maximum(
+                msg_only_reduce(jnp.sum(gen, dtype=jnp.int32)),
+                1).astype(jnp.float32)
+        else:
+            n_cols = jnp.float32(sim._n_honest)
+        coverage = _div_rn(_pair_total(msg_reduce(
+            _census_pair(cpb) if fin else _popcount_pair(
+                seen & ok_w[None] & hmask[:, None, None]))), n_ok * n_cols)
+        live = _pair_total(reduce(_popcount_pair(
+            alive_w & topo.valid_w))) / 32.0
     state = AlignedState(seen_w=seen, frontier_w=frontier, alive_b=alive_b,
                          byz_w=state.byz_w, strikes=strikes, key=key,
                          round=state.round + 1)

@@ -1,8 +1,9 @@
 """The device a run executes on, and where its compiled programs are kept.
 
 Entry points (``cli.main``, ``bench.main``, ``chip_smoke.py``) call
-:func:`enable_compile_cache` before their first compile; importing this
-module does nothing.
+:func:`enable_compile_cache` before their first compile;
+``engines.build_simulator`` calls :func:`install_trace_mirror`.
+Importing this module does nothing.
 """
 
 from __future__ import annotations
@@ -33,6 +34,19 @@ def enable_compile_cache() -> str | None:
 
     jax.config.update("jax_compilation_cache_dir", CACHE_DIR)
     return CACHE_DIR
+
+
+def install_trace_mirror() -> None:
+    """Mirror every ``telemetry.span`` into the profiler's trace: under
+    a ``jax.profiler`` session each span is a host event named
+    ``gossip.<name>``, on the device ops' clock; outside one the marker
+    costs a no-op TraceMe.  The telemetry package never imports jax, so
+    the JAX side hands it the marker here.  Idempotent."""
+    import jax
+
+    from p2p_gossipprotocol_tpu import telemetry
+
+    telemetry.recorder().set_mirror(jax.profiler.TraceAnnotation)
 
 
 def device_label() -> str:

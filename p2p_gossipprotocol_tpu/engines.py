@@ -136,16 +136,21 @@ def build_simulator(cfg, *, n_peers: int | None = None,
     hier/overlap illegal combos, engine ceilings) emits exactly one
     typed ``clamp`` event through the telemetry ledger (telemetry.record_clamps), whether or not the
     caller passed its own ``clamps`` list — one queryable stream
-    instead of N scattered strings.
+    instead of N scattered strings.  It also installs the telemetry
+    trace mirror (:func:`device.install_trace_mirror`), so the build
+    and every later span appear in a ``jax.profiler`` trace.
     """
     from p2p_gossipprotocol_tpu import telemetry
+    from p2p_gossipprotocol_tpu.device import install_trace_mirror
 
+    install_trace_mirror()
     clamps = [] if clamps is None else clamps
     n0 = len(clamps)
     try:
-        return _build_simulator(cfg, n_peers=n_peers,
-                                mesh_devices=mesh_devices,
-                                msg_shards=msg_shards, clamps=clamps)
+        with telemetry.span("build_simulator"):
+            return _build_simulator(cfg, n_peers=n_peers,
+                                    mesh_devices=mesh_devices,
+                                    msg_shards=msg_shards, clamps=clamps)
     finally:
         telemetry.record_clamps(clamps[n0:], scope="build_simulator")
 

@@ -387,6 +387,7 @@ def gossip_pass(y: jax.Array, colidx: jax.Array, gate: jax.Array,
                 press: bool = False,
                 prefetch_depth: int = 0,
                 rowblk: int = 512,
+                name: str = "gossip",
                 interpret: bool = False):
     """One OR-accumulated D-slot pass over W message planes.
 
@@ -477,6 +478,9 @@ def gossip_pass(y: jax.Array, colidx: jax.Array, gate: jax.Array,
                 remapped dead steps, issue nothing).  0/1 = the legacy
                 BlockSpec-pipelined stream.  Bitwise-identical by
                 construction: the same blocks reach the same compute.
+    ``name``  — the kernel's name on the device (the profiler's op
+                name): the round passes ``push``, ``pull`` or
+                ``push_self``, so a trace tells the passes apart.
     Returns int32[W, R, 128]: words each peer hears this pass — the
     pair ``(new, seen')`` when ``seen`` is given, or the pair
     ``(words, pressure)`` when ``press`` is set.
@@ -645,6 +649,7 @@ def gossip_pass(y: jax.Array, colidx: jax.Array, gate: jax.Array,
         grid_spec=grid_spec,
         out_shape=out_shape,
         interpret=interpret,
+        name=name,
     )(*prefetch, *operands)
     return tuple(out) if (finalize or press) else out[0]
 
@@ -702,6 +707,7 @@ def count_pass(y: jax.Array, colidx: jax.Array, gate: jax.Array,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((R, C), jnp.int32),
         interpret=interpret,
+        name="count",
     )(rolls, subrolls, y, colidx, gate)
 
 
@@ -891,6 +897,7 @@ def liveness_pass(y_alive: jax.Array, colidx: jax.Array,
             jax.ShapeDtypeStruct((D, R, C), jnp.int8),
         ],
         interpret=interpret,
+        name="liveness",
     )(*prefetch, y_alive, colidx, strikes, gate)
 
 

@@ -4,7 +4,9 @@ One low-overhead, host-side-only observability layer shared by every
 engine, the serving plane, and the supervisor:
 
 * :mod:`~p2p_gossipprotocol_tpu.telemetry.recorder` — process-wide
-  spans (``run`` > ``chunk`` > ``exchange``, serve ``request``), the
+  spans (``run`` > ``chunk``, ``init_state``, ``run_to_coverage``,
+  serve ``request``), mirrored into a ``jax.profiler`` trace as
+  ``gossip.<name>`` when the JAX side installs the mirror, the
   always-on typed event ledger (clamps, fallbacks, deaths), counters/
   gauges, the bounded flight-recorder ring with atomic dumps, and the
   Prometheus-style ``/metrics`` renderer;
@@ -12,8 +14,9 @@ engine, the serving plane, and the supervisor:
   reconciliation of the in-kernel census against ``traffic_model()``:
   a live ``roofline_frac`` and modeled-vs-achieved drift;
 * :mod:`~p2p_gossipprotocol_tpu.telemetry.traceview` — the
-  ``jax.profiler`` trace summarizer (top ops by device time) behind
-  both ``benchmarks/trace_top.py`` and the serve ``profile`` document.
+  ``jax.profiler`` trace summarizer (top leaf ops by device time, and
+  the device's idle time inside each ``gossip.`` span) behind both
+  ``benchmarks/trace_top.py`` and the serve ``profile`` document.
 
 Observational by contract: this package never imports jax, telemetry
 is off by default (``telemetry=1`` / ``--telemetry`` /

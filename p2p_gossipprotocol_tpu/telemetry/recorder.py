@@ -5,12 +5,17 @@ shared by every layer — the solo/sharded chunk runner
 (utils/checkpoint.run_chunked), the fleet engine, the serving loop, the
 supervisor, and the CLI.  Three instruments:
 
-* **Spans** — nested host-side timing scopes (``run`` > ``chunk`` >
-  ``exchange``; serve ``request`` with its enqueue→admit→converge→
-  result ledger).  Span ids are stable: ``<name>:<seq>`` by default,
-  caller-chosen for identities that must survive a resume (a served
-  request's span id is ``request:<rid>``).  Completed spans land in
-  the bounded ring.
+* **Spans** — nested host-side timing scopes (``run`` > ``chunk``;
+  ``init_state`` and ``run_to_coverage`` with their steps; serve
+  ``request`` with its enqueue→admit→converge→result ledger).  Span
+  ids are stable: ``<name>:<seq>`` by default, caller-chosen for
+  identities that must survive a resume (a served request's span id is
+  ``request:<rid>``).  Completed spans land in the bounded ring.  Every
+  span is also handed to the *trace mirror* when one is installed
+  (:meth:`Recorder.set_mirror`): the JAX side installs
+  ``jax.profiler.TraceAnnotation``, so under a profiler session each
+  span appears in the device trace as ``gossip.<name>``, on the same
+  clock as the device's ops, whether or not the recorder is enabled.
 * **Events** — the typed ledger that absorbs the repo's scattered
   "recorded clamp" strings (auto-select degrades, frontier/hier/
   overlap illegal combos, probe fallback, spmd fallback) into one
@@ -31,14 +36,16 @@ worker death, and on demand (the serve ``flight`` document).
 
 Telemetry is observational BY CONTRACT:
 
-* zero device computation — this module never imports jax; every
+* zero device computation — this module never imports jax (the trace
+  mirror is a plain callable the JAX side hands in); every
   instrument is host-side bookkeeping around already-materialized
   values, so compiled programs (``FleetBucket.trace_count``) and
   results are bit-for-bit identical with telemetry on or off
   (tests/test_telemetry.py);
 * off by default — ``telemetry=1`` (config), ``--telemetry`` (CLI), or
   ``GOSSIP_TELEMETRY=1`` (env) enable it; when off, ``span()`` returns
-  a shared no-op and counters return immediately;
+  the mirror's marker alone (a no-op outside a profiler session) or a
+  shared no-op, and counters return immediately;
 * excluded from checkpoint fingerprints — the ``telemetry_*`` config
   keys never enter ``engines.config_keys``, like ``fuse_update`` and
   the other how-not-what knobs.
@@ -54,6 +61,10 @@ import time
 from collections import Counter, deque
 
 _TRUTHY = ("1", "true", "on", "yes")
+
+#: prefix of a span's name in the profiler's trace (the harness under
+#: ``gossipbench/`` marks its own spans ``gossipbench.``)
+TRACE_PREFIX = "gossip."
 
 #: clamp-site classification: the FIRST matching substring names the
 #: site, so every existing "recorded clamp" string maps to exactly one
@@ -102,26 +113,30 @@ _NOOP = _NoopSpan()
 
 
 class _Span:
-    """One live span: closes into the recorder's ring on ``__exit__``."""
+    """One live span: closes into the recorder's ring on ``__exit__``;
+    ``mark`` is the trace mirror's marker (or the shared no-op)."""
 
-    __slots__ = ("rec", "name", "sid", "parent", "attrs", "t0")
+    __slots__ = ("rec", "name", "sid", "parent", "attrs", "t0", "mark")
 
     def __init__(self, rec: "Recorder", name: str, sid: str,
-                 parent: str | None, attrs: dict):
+                 parent: str | None, attrs: dict, mark):
         self.rec = rec
         self.name = name
         self.sid = sid
         self.parent = parent
         self.attrs = attrs
         self.t0 = 0.0
+        self.mark = mark
 
     def __enter__(self):
         self.rec._push(self.sid)
+        self.mark.__enter__()
         self.t0 = time.perf_counter()
         return self
 
     def __exit__(self, exc_type, exc, tb):
         dur = time.perf_counter() - self.t0
+        self.mark.__exit__(exc_type, exc, tb)
         self.rec._pop()
         self.rec._close_span(self, dur, failed=exc_type is not None)
         return False
@@ -145,6 +160,7 @@ class Recorder:
         self._local = threading.local()
         self._t0 = time.time()
         self._crash_hook_installed = False
+        self._mirror = None
 
     # -- configuration --------------------------------------------------
     def configure(self, enabled: bool | None = None,
@@ -223,16 +239,26 @@ class Recorder:
         if st:
             st.pop()
 
+    def set_mirror(self, mirror) -> None:
+        """Install the trace mirror: ``mirror(trace_name)`` returns a
+        context manager that marks the span on the profiler's clock
+        (``jax.profiler.TraceAnnotation``; ``device.install_trace_mirror``
+        installs it).  ``None`` removes it."""
+        self._mirror = mirror
+
     def span(self, name: str, span_id: str | None = None, **attrs):
-        """Open a nested span (context manager).  No-op when telemetry
-        is off — the returned object is a shared constant, so the off
-        path allocates nothing."""
+        """Open a nested span (context manager).  With telemetry off
+        the span is the trace mirror's marker alone, or a shared no-op
+        constant when no mirror is installed, so the off path records
+        nothing."""
+        mirror = self._mirror
+        mark = _NOOP if mirror is None else mirror(TRACE_PREFIX + name)
         if not self.enabled:
-            return _NOOP
+            return mark
         sid = span_id or f"{name}:{self._next_seq()}"
         st = self._stack()
         parent = st[-1] if st else None
-        return _Span(self, name, sid, parent, attrs)
+        return _Span(self, name, sid, parent, attrs, mark)
 
     def _close_span(self, sp: _Span, dur: float, failed: bool) -> None:
         rec = {"span": sp.sid, "name": sp.name, "parent": sp.parent,
@@ -246,11 +272,8 @@ class Recorder:
     def span_record(self, name: str, dur_s: float,
                     span_id: str | None = None, **attrs) -> None:
         """Record a span retroactively from an externally measured
-        duration — for scopes whose instants were stamped elsewhere
-        (a served request's enqueue→result ledger) or that the host
-        cannot observe directly (the in-jit ``exchange`` phase, whose
-        duration is model-attributed; the span carries
-        ``modeled=True`` when so)."""
+        duration — for scopes whose instants were stamped elsewhere (a
+        served request's enqueue→result ledger)."""
         if not self.enabled:
             return
         rec = {"span": span_id or f"{name}:{self._next_seq()}",

@@ -20,12 +20,6 @@ cumulative counters and two headline gauges:
   (``traffic_model(frontier_fill=live fill)``), i.e. how far reality
   has drifted below the model's upper bound.  0 while the frontier is
   dense, growing as the run enters the sparse regime.
-
-The per-chunk ``exchange`` span is model-attributed: the host cannot
-observe in-jit phases, so the span's duration is the chunk wall scaled
-by the exchange terms' share of modeled bytes, and it carries
-``modeled=True`` — documented, never passed off as a measurement
-(docs/OBSERVABILITY.md "Span taxonomy").
 """
 
 from __future__ import annotations
@@ -198,21 +192,6 @@ class RooflineTracker:
             drift = 1.0 - self.census_bytes / self.model_bytes
             rec.gauge_set("model_drift_frac", round(drift, 6))
             self._check_drift(drift, rec)
-
-        # model-attributed exchange span (docs/OBSERVABILITY.md): the
-        # chunk wall scaled by the exchange terms' share of bytes
-        try:
-            terms = self._model_fn(fill)
-        except Exception:  # noqa: BLE001
-            terms = {}
-        ex = float(terms.get("delta_gather", 0) or 0)
-        total = float(terms.get("total", 0) or 0)
-        if ex > 0 and total > 0:
-            rec.span_record(
-                "exchange", wall_s * ex / total, modeled=True,
-                bytes_round=int(ex),
-                ici_bytes=int(terms.get("ici_gather", 0) or 0),
-                dcn_bytes=int(terms.get("dcn_gather", 0) or 0))
 
     # ------------------------------------------------------------------
     def _check_drift(self, drift: float, rec) -> None:

@@ -40,11 +40,13 @@ def _fresh_recorder():
     """Every test starts from a clean, DISABLED recorder and leaves
     one behind — telemetry state must never leak across tests."""
     rec = telemetry.recorder()
+    mirror = rec._mirror
     rec.configure(enabled=False)
     rec.reset()
     yield rec
     rec.configure(enabled=False)
     rec.reset()
+    rec.set_mirror(mirror)
 
 
 def _write_cfg(tmp_path, extra: str = "", name: str = "net.txt") -> str:
@@ -89,6 +91,7 @@ def test_spans_nest_with_stable_ids(_fresh_recorder):
 def test_disabled_recorder_is_inert_but_ledger_stays_on(
         _fresh_recorder):
     rec = _fresh_recorder
+    rec.set_mirror(None)
     assert not rec.enabled
     with rec.span("run") as sp:
         assert sp is None            # the shared no-op
@@ -98,6 +101,54 @@ def test_disabled_recorder_is_inert_but_ledger_stays_on(
     # events are the post-mortem ledger: ALWAYS recorded
     rec.event("clamp", site="auto_select", detail="d")
     assert len(rec.events("clamp")) == 1
+
+
+class _Marks:
+    """A stand-in trace mirror: logs each marker's enter and exit."""
+
+    def __init__(self):
+        self.log = []
+
+    def __call__(self, name):
+        log = self.log
+
+        class Mark:
+            def __enter__(self):
+                log.append(("enter", name))
+
+            def __exit__(self, *exc):
+                log.append(("exit", name))
+                return False
+
+        return Mark()
+
+
+def test_disabled_span_calls_only_the_mirror(_fresh_recorder):
+    rec = _fresh_recorder
+    marks = _Marks()
+    rec.set_mirror(marks)
+    with rec.span("dispatch", rounds=3):
+        pass
+    assert marks.log == [("enter", "gossip.dispatch"),
+                         ("exit", "gossip.dispatch")]
+    assert rec.spans() == []
+
+
+def test_enabled_spans_record_parent_and_mirror(_fresh_recorder):
+    rec = _fresh_recorder
+    marks = _Marks()
+    rec.set_mirror(marks)
+    rec.configure(enabled=True)
+    with rec.span("run_to_coverage") as outer:
+        with rec.span("fetch") as inner:
+            assert inner.parent == outer.sid
+    fetch, run = rec.spans()
+    assert (fetch["name"], fetch["parent"]) == ("fetch", run["span"])
+    assert run["parent"] is None
+    assert marks.log == [("enter", "gossip.run_to_coverage"),
+                         ("enter", "gossip.fetch"),
+                         ("exit", "gossip.fetch"),
+                         ("exit", "gossip.run_to_coverage")]
 
 
 def test_ring_is_bounded(_fresh_recorder):
@@ -387,6 +438,111 @@ def test_roofline_counters_live(tmp_path):
     assert 0.0 <= c["model_drift_frac"] <= 1.0
     expected = sim.traffic_model()["total"] * 6
     assert c["model_bytes_total"] == pytest.approx(expected)
+
+
+# ----------------------------------------------------------------------
+# The coverage path on the profiler's clock: spans, kernel names, scopes.
+
+_LOOP_CFG = ("engine=aligned\nmode=pushpull\nchurn_rate=0.05\n"
+             "liveness_every=3\n")
+
+
+def _aligned(tmp_path):
+    from p2p_gossipprotocol_tpu.engines import build_simulator
+
+    sim, engine = build_simulator(NetworkConfig(
+        _write_cfg(tmp_path, _LOOP_CFG)))
+    assert engine == "aligned" and sim._liveness
+    return sim
+
+
+def _to_coverage(sim):
+    st, tp, rounds, _ = sim.run_to_coverage(
+        target=0.99, max_rounds=32, state=sim.init_state(), warmup=False)
+    return jax.device_get((st, tp)), rounds
+
+
+def test_coverage_path_spans(_fresh_recorder, tmp_path):
+    """build_simulator, init_state with its steps, and run_to_coverage
+    with compile (first call only, counted), dispatch and fetch."""
+    rec = _fresh_recorder
+    rec.configure(enabled=True)
+    sim = _aligned(tmp_path)
+    _to_coverage(sim)
+    _to_coverage(sim)
+    by = {}
+    for sp in rec.spans():
+        by.setdefault(sp["name"], []).append(sp)
+    assert len(by["build_simulator"]) == 1
+    assert len(by["init_state"]) == 2 and len(by["run_to_coverage"]) == 2
+    inits = {sp["span"] for sp in by["init_state"]}
+    for step in ("key", "message_plan", "seed", "strikes"):
+        assert {sp["parent"] for sp in by[step]} == inits
+    runs = {sp["span"] for sp in by["run_to_coverage"]}
+    for step in ("dispatch", "fetch"):
+        assert {sp["parent"] for sp in by[step]} == runs
+    assert len(by["compile"]) == 1 and by["compile"][0]["parent"] in runs
+    assert rec.counters()["coverage_loop_compiles_total"] == 1
+
+
+def _pallas_names(jaxpr, out):
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            out.append(eqn.params["name"])
+            continue
+        for v in eqn.params.values():
+            for sub in v if isinstance(v, (list, tuple)) else [v]:
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    _pallas_names(inner, out)
+    return out
+
+
+def test_coverage_loop_names_passes_and_scopes(tmp_path):
+    """The lowered coverage loop names its kernels after their passes
+    and carries the round's phase scopes (the census among them)."""
+    from p2p_gossipprotocol_tpu.state import build_coverage_loop
+
+    sim = _aligned(tmp_path)
+    looped = build_coverage_loop(sim.step, target=0.99, max_rounds=32,
+                                 check_every=1, sched_end=0)
+    args = (sim.init_state(), sim.topo)
+    names = _pallas_names(jax.make_jaxpr(looped)(*args).jaxpr, [])
+    assert sorted(names) == ["liveness", "pull", "push"]
+    text = jax.jit(looped).lower(*args).as_text(debug_info=True)
+    for scope in ("churn", "liveness", "prep", "push", "pull", "update",
+                  "census"):
+        assert f"/{scope}/" in text, scope
+
+
+def test_names_and_spans_change_no_result(_fresh_recorder, tmp_path,
+                                          monkeypatch):
+    """Scopes, kernel names and spans are metadata: a traced run with
+    the recorder on, and a run with every scope taken out, end in the
+    same state, bit for bit."""
+    import contextlib
+
+    sim = _aligned(tmp_path)
+    ref = _to_coverage(sim)
+    _fresh_recorder.configure(enabled=True)
+    # compiled before the capture: a CPU compile's own trace events
+    # fill the profiler's buffer
+    with jax.profiler.trace(str(tmp_path / "trace")):
+        traced = _to_coverage(sim)
+    _fresh_recorder.configure(enabled=False)
+    monkeypatch.setattr(jax, "named_scope",
+                        lambda name: contextlib.nullcontext())
+    bare = _to_coverage(_aligned(tmp_path))
+    for other in (traced, bare):
+        assert other[1] == ref[1]
+        for a, b in zip(jax.tree.leaves(other[0]), jax.tree.leaves(ref[0])):
+            assert np.array_equal(a, b)
+
+    from p2p_gossipprotocol_tpu.telemetry import traceview
+
+    spans = {r["span"] for r in traceview.program_spans(
+        traceview.find_trace(str(tmp_path / "trace")))}
+    assert {"init_state", "run_to_coverage", "dispatch", "fetch"} <= spans
 
 
 # ----------------------------------------------------------------------

@@ -64,3 +64,109 @@ def test_fallback_without_op_lanes(tmp_path):
     lines = [json.loads(ln) for ln in proc.stdout.strip().splitlines()]
     rows = {r["op"]: r for r in lines[1:]}
     assert rows == {"PjitFunction(f)": rows["PjitFunction(f)"]}
+
+
+def _device_lane(pid=1, tid=10):
+    return [{"ph": "M", "name": "process_name", "pid": pid,
+             "args": {"name": "/device:TPU:0"}},
+            {"ph": "M", "name": "thread_name", "pid": pid, "tid": tid,
+             "args": {"name": "XLA Ops"}}]
+
+
+def test_containers_do_not_count(tmp_path):
+    """A ``while`` on the XLA Ops lane holds the ops of its body: only
+    the two ops count, so device time is not doubled."""
+    from p2p_gossipprotocol_tpu.telemetry.traceview import summarize
+
+    trace = tmp_path / "t.trace.json.gz"
+    _write_trace(str(trace), _device_lane() + [
+        {"ph": "X", "pid": 1, "tid": 10, "name": "while.6",
+         "ts": 0, "dur": 1000},
+        {"ph": "X", "pid": 1, "tid": 10, "name": "push.8",
+         "ts": 0, "dur": 700},
+        {"ph": "X", "pid": 1, "tid": 10, "name": "fusion.3",
+         "ts": 750, "dur": 250},
+    ])
+    rows = {r["op"]: r for r in summarize(str(trace))}
+    assert set(rows) == {"push.8", "fusion.3"}
+    assert rows["push.8"]["total_ms"] == 0.7
+    assert rows["push.8"]["share"] == round(700 / 950, 4)
+
+
+def test_program_spans_charge_idle_to_the_innermost(tmp_path):
+    """The program's ``gossip.`` host spans nest; each idle moment of
+    the device goes to the innermost span open then, and the spans
+    themselves are never ops."""
+    from p2p_gossipprotocol_tpu.telemetry.traceview import (program_spans,
+                                                            summarize)
+
+    host = [{"ph": "M", "name": "process_name", "pid": 7,
+             "args": {"name": "/host:CPU"}}]
+    span = lambda name, ts, dur: {"ph": "X", "pid": 7, "tid": 1,  # noqa
+                                  "name": f"gossip.{name}", "ts": ts,
+                                  "dur": dur}
+    trace = tmp_path / "t.trace.json.gz"
+    _write_trace(str(trace), _device_lane() + host + [
+        span("run_to_coverage", 0, 1000),
+        span("dispatch", 0, 100),      # idle 0-100, all of it
+        span("fetch", 100, 800),       # device busy 300-900: idle 200
+        {"ph": "X", "pid": 1, "tid": 10, "name": "push.8",
+         "ts": 300, "dur": 600},
+        # 900-1000: only run_to_coverage itself is open
+    ])
+    rows = {r["span"]: r for r in program_spans(str(trace))}
+    assert rows["run_to_coverage"] == {"span": "run_to_coverage",
+                                       "count": 1, "total_ms": 1.0,
+                                       "idle_ms": 0.1}
+    assert rows["dispatch"]["idle_ms"] == 0.1
+    assert rows["fetch"]["idle_ms"] == 0.2
+    assert [r["op"] for r in summarize(str(trace))] == ["push.8"]
+
+
+def test_no_program_spans(tmp_path):
+    from p2p_gossipprotocol_tpu.telemetry.traceview import program_spans
+
+    trace = tmp_path / "t.trace.json.gz"
+    _write_trace(str(trace), _device_lane() + [
+        {"ph": "X", "pid": 1, "tid": 10, "name": "fusion.1",
+         "ts": 0, "dur": 10}])
+    assert program_spans(str(trace)) == []
+
+
+def test_scopes_group_leaf_time_by_phase(tmp_path):
+    """A chip trace gives each op its name stack (``tf_op``): leaf time
+    groups by the phase under the loop body; the loop's own control
+    and eager ops keep their outermost name."""
+    from p2p_gossipprotocol_tpu.telemetry.traceview import scopes
+
+    op = lambda name, ts, dur, tf_op=None: {  # noqa: E731
+        "ph": "X", "pid": 1, "tid": 10, "name": name, "ts": ts,
+        "dur": dur, "args": {"tf_op": tf_op} if tf_op else {}}
+    body = "jit(looped)/while/body"
+    trace = tmp_path / "t.trace.json.gz"
+    _write_trace(str(trace), _device_lane() + [
+        op("while.1", 0, 100, "jit(looped)/while"),
+        op("push.8", 0, 40, f"{body}/push/push/pallas_call:"),
+        op("popcnt_reduce_fusion.5", 40, 10, f"{body}/census/reduce_sum:"),
+        op("compare_fusion.2", 50, 5, f"{body}/census/eq:"),
+        op("liveness.2", 55, 20,
+           f"{body}/liveness/cond/branch_1_fun/liveness/pallas_call:"),
+        op("copy.86", 75, 5),
+        op("lt.3", 80, 1, "jit(looped)/while/lt:"),
+        op("ne", 200, 2, "jit(ne)/ne:"),
+    ])
+    rows = {r["scope"]: (r["calls"], r["total_ms"])
+            for r in scopes(str(trace))}
+    assert rows == {"push": (1, 0.04), "census": (2, 0.015),
+                    "liveness": (1, 0.02), "(none)": (1, 0.005),
+                    "jit(looped)": (1, 0.001), "jit(ne)": (1, 0.002)}
+
+
+def test_no_scopes_without_name_stacks(tmp_path):
+    from p2p_gossipprotocol_tpu.telemetry.traceview import scopes
+
+    trace = tmp_path / "t.trace.json.gz"
+    _write_trace(str(trace), _device_lane() + [
+        {"ph": "X", "pid": 1, "tid": 10, "name": "fusion.1",
+         "ts": 0, "dur": 10}])
+    assert scopes(str(trace)) == []
