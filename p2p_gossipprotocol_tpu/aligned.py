@@ -1201,6 +1201,41 @@ def churn_rows(key: jax.Array, grows: jax.Array, alive_b: jax.Array,
     return ((alive_b & ~dies) | (~alive_b & revives)) & valid_b
 
 
+def _init_program(valid_w, byz_b, src, key, *, n_words: int, n_slots: int,
+                  n_msgs: int, n_honest: int, seeded: bool, liveness: bool):
+    """The round-0 state's arrays as one program: (seen, byz_w, alive_b,
+    strikes, key, round).  Every array comes in as an argument, so sims
+    of one shape share the executable and no plane is baked into it as a
+    constant; ``key`` passes through so the state owns a buffer of its
+    own."""
+    rows = valid_w.shape[0]
+    byz_w = jnp.where(byz_b, jnp.int32(-1), jnp.int32(0))
+    # Columns >= n_honest start empty (the adversary's injection budget);
+    # with staggered generation NO columns are seeded here — column m is
+    # injected at round m*k by aligned_round.
+    m = jnp.arange(n_msgs)
+    place = (m < n_honest) & seeded
+    # Seed words with scatter-ADD: distinct message bits add like OR (so
+    # colliding sources keep every rumor — every message is a distinct
+    # (plane, bit) pair, so no addition carries), and bit 31, the sign
+    # bit, adds without overflow where a max-combiner would drop it.  The
+    # scatter writes the [W, rows, 128] planes in place: a flat plane
+    # reshaped afterwards costs the chip two more passes (a relayout).
+    bits = jnp.where(place, jnp.left_shift(jnp.int32(1), m % WORD_BITS), 0)
+    pos = jnp.where(place, src, 0)
+    seen = jnp.zeros((n_words, rows, LANES), jnp.int32).at[
+        m // WORD_BITS, pos // LANES, pos % LANES].add(bits)
+    strikes = (jnp.zeros((n_slots, rows, LANES), jnp.int8) if liveness
+               else None)
+    return seen, byz_w, valid_w != 0, strikes, key, jnp.int32(0)
+
+
+_init_jit = jax.jit(_init_program, static_argnames=(
+    "n_words", "n_slots", "n_msgs", "n_honest", "seeded", "liveness"))
+#: compiled round-0 programs, keyed by statics and argument layouts
+_INIT_PROGRAMS: dict = {}
+
+
 @dataclass
 class AlignedSimulator:
     """Same surface as sim.Simulator (step / run / run_to_coverage, same
@@ -1498,8 +1533,9 @@ class AlignedSimulator:
                            & ~self._honest_mask)
         self._run_cache: dict = {}
         self._loop_cache: dict = {}
-        if self.message_stagger > 0:
-            self._message_plan()   # eager: a traced cache would leak
+        # eager, once: a traced cache would leak, and init_state then
+        # does no eager work of its own
+        self._plan_cache, self._init_key = self._seed_plan()
 
     # ------------------------------------------------------------------
     @classmethod
@@ -1981,20 +2017,18 @@ class AlignedSimulator:
         return self.traffic_model()["total"]
 
     # ------------------------------------------------------------------
-    def _message_plan(self):
-        """(byz_b, src) — the byzantine draw and per-column source
-        positions (flat ``row*128 + lane`` ids), deterministic in the
-        seed so init_state and the staggered in-round generation
-        (aligned_round) place every rumor identically.
+    def _seed_plan(self):
+        """((byz_b, src), key) — the byzantine draw, the per-column
+        source positions (flat ``row*128 + lane`` ids) and the state's
+        round-0 key, deterministic in the seed so init_state and the
+        staggered in-round generation (aligned_round) place every rumor
+        identically.
 
         Honest rumors must originate at honest peers (a byzantine source
         never relays — state.py:message_sources has the same rule);
         sources spread evenly over the honest population."""
-        if getattr(self, "_plan_cache", None) is not None:
-            return self._plan_cache
         rows = self.topo.rows
-        key = jax.random.PRNGKey(self.seed)
-        k_byz, key = jax.random.split(key)
+        k_byz, key = jax.random.split(jax.random.PRNGKey(self.seed))
         valid_b = self.topo.valid_w != 0
         if self.byzantine_fraction > 0.0:
             byz_b = (jax.random.uniform(k_byz, (rows, LANES))
@@ -2004,52 +2038,37 @@ class AlignedSimulator:
         from p2p_gossipprotocol_tpu.state import sources_from_mask
 
         ok_flat = (valid_b & ~byz_b).reshape(-1)
-        self._plan_cache = (byz_b, sources_from_mask(
-            ok_flat, self.n_msgs, self._n_honest))
+        return (byz_b, sources_from_mask(ok_flat, self.n_msgs,
+                                         self._n_honest)), key
+
+    def _message_plan(self):
+        """(byz_b, src), computed once at construction."""
         return self._plan_cache
 
     def init_state(self) -> AlignedState:
-        """The round-0 state, in eager steps; each group of steps is a
-        telemetry span inside ``init_state`` (docs/OBSERVABILITY.md)."""
+        """The round-0 state from one compiled program over the seed
+        plan; ``compile`` (first call for a shape) and ``dispatch`` are
+        telemetry spans inside ``init_state`` (docs/OBSERVABILITY.md)."""
         with telemetry.span("init_state"):
-            rows = self.topo.rows
-            with telemetry.span("key"):
-                key = jax.random.PRNGKey(self.seed)
-                _, key = jax.random.split(key)  # k_byz consumed by the plan
-                valid_b = self.topo.valid_w != 0
-            with telemetry.span("message_plan"):
-                byz_b, src = self._message_plan()
-            with telemetry.span("seed"):
-                byz_w = jnp.where(byz_b, jnp.int32(-1), jnp.int32(0))
-                # Columns >= n_honest start empty (the adversary's
-                # injection budget); with staggered generation NO columns
-                # are seeded here — column m is injected at round m*k by
-                # aligned_round.
-                place = ((jnp.arange(self.n_msgs) < self._n_honest)
-                         & (self.message_stagger <= 0))
-                # Seed words in uint32 with scatter-ADD: distinct message
-                # bits add like OR (so colliding sources keep every rumor
-                # — every message is a distinct (plane, bit) pair), and
-                # bit 31 survives (an int32 `1 << 31` would wrap negative
-                # and be dropped by a max-combiner).  Bitcast back to the
-                # engine's int32 words.
-                m = jnp.arange(self.n_msgs)
-                bits = jnp.where(
-                    place,
-                    jnp.uint32(1) << (m % WORD_BITS).astype(jnp.uint32), 0)
-                bits_u = jnp.zeros((self.n_words, rows * LANES),
-                                   jnp.uint32).at[
-                    m // WORD_BITS, jnp.where(place, src, 0)].add(bits)
-                seen = jax.lax.bitcast_convert_type(
-                    bits_u, jnp.int32).reshape(self.n_words, rows, LANES)
-            with telemetry.span("strikes"):
-                strikes = (jnp.zeros((self.topo.n_slots, rows, LANES),
-                                     jnp.int8)
-                           if self._liveness else None)
+            byz_b, src = self._plan_cache
+            args = (self.topo.valid_w, byz_b, src, self._init_key)
+            statics = dict(n_words=self.n_words, n_slots=self.topo.n_slots,
+                           n_msgs=self.n_msgs, n_honest=self._n_honest,
+                           seeded=self.message_stagger <= 0,
+                           liveness=self._liveness)
+            cache_key = (tuple(statics.values()),
+                         tuple((a.shape, a.dtype, a.sharding) for a in args))
+            fn = _INIT_PROGRAMS.get(cache_key)
+            if fn is None:
+                with telemetry.span("compile"):
+                    fn = _init_jit.lower(*args, **statics).compile()
+                _INIT_PROGRAMS[cache_key] = fn
+                telemetry.counter_add("init_state_compiles_total")
+            with telemetry.span("dispatch"):
+                seen, byz_w, alive_b, strikes, key, round0 = fn(*args)
             return AlignedState(seen_w=seen, frontier_w=seen,
-                                alive_b=valid_b, byz_w=byz_w,
-                                strikes=strikes, key=key,
-                                round=jnp.int32(0))
+                                alive_b=alive_b, byz_w=byz_w,
+                                strikes=strikes, key=key, round=round0)
 
     # ------------------------------------------------------------------
     def step(self, state: AlignedState, topo: AlignedTopology | None = None

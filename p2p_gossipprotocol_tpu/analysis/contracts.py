@@ -120,6 +120,8 @@ PACKER_EXEMPT = {
     "_honest_mask": "derived from n_msgs/_n_honest, both in the signature",
     "_junk_mask": "derived from n_msgs/_n_honest, both in the signature",
     "_plan_cache": "host-side byzantine-plan cache, rebuilt per sim",
+    "_init_key": "round-0 PRNG key drawn from sim.seed, an argument of "
+                 "the init program, rebuilt per sim",
     "_run_cache": "jit cache, not a static",
     "_coverage_cache": "jit cache, not a static",
     "_scan_cache": "jit cache, not a static",

@@ -505,6 +505,102 @@ def test_multiword_byzantine_junk_confined():
     assert not (junk_seen & ~np.where(byz_b, -1, 0)[None]).any()
 
 
+def _numpy_init(sim):
+    """The round-0 state built in numpy from the sim's cached plan: each
+    honest column's bit at its source unless generation is staggered,
+    the byzantine marks, every valid peer alive, zero strikes."""
+    byz_b, src = (np.asarray(a) for a in sim._message_plan())
+    seen = np.zeros((sim.n_words, sim.topo.rows * LANES), np.uint32)
+    if sim.message_stagger <= 0:
+        for m in range(sim._n_honest):
+            seen[m // 32, src[m]] |= np.uint32(1) << np.uint32(m % 32)
+    seen = seen.view(np.int32).reshape(sim.n_words, sim.topo.rows, LANES)
+    strikes = (np.zeros((sim.topo.n_slots, sim.topo.rows, LANES), np.int8)
+               if sim._liveness else None)
+    key = np.asarray(jax.random.split(jax.random.PRNGKey(sim.seed))[1])
+    return dict(seen_w=seen, frontier_w=seen,
+                alive_b=np.asarray(sim.topo.valid_w) != 0,
+                byz_w=np.where(byz_b, -1, 0).astype(np.int32),
+                strikes=strikes, key=key, round=np.int32(0))
+
+
+@pytest.mark.parametrize("n,kw", [
+    (100, dict(n_msgs=128)),                    # 128 rumors on 100 peers
+    (1024, dict(n_msgs=80)),                    # W=3, bit 31 of each word
+    (1024, dict(n_msgs=32, message_stagger=2)),  # nothing seeded
+    (2048, dict(n_msgs=40, byzantine_fraction=0.2, n_honest_msgs=30)),
+    (1024, dict(n_msgs=8, churn_rate=0.0)),     # no liveness: no strikes
+], ids=["colliding", "w3", "stagger", "byzantine", "no_churn"])
+def test_init_state_exact(n, kw):
+    """The compiled round-0 program builds, leaf for leaf, the state the
+    plan describes."""
+    from p2p_gossipprotocol_tpu.liveness import ChurnConfig
+
+    rate = kw.pop("churn_rate", 0.05)
+    topo = build_aligned(seed=4, n=n, n_slots=6, n_msgs=kw["n_msgs"])
+    sim = AlignedSimulator(topo=topo, churn=ChurnConfig(rate=rate), seed=7,
+                           **kw)
+    src = np.asarray(sim._message_plan()[1])[:sim._n_honest]
+    if n == 100:
+        assert len(set(src.tolist())) < len(src)    # sources collide
+    if sim.byzantine_fraction:
+        assert np.asarray(sim._message_plan()[0]).any()
+    st = sim.init_state()
+    want = _numpy_init(sim)
+    assert (st.strikes is None) == (rate == 0.0)
+    for name, ref in want.items():
+        got = getattr(st, name)
+        if ref is None:
+            assert got is None, name
+            continue
+        assert got.dtype == ref.dtype, name
+        np.testing.assert_array_equal(np.asarray(got), ref, err_msg=name)
+
+
+def test_init_state_compiles_once_per_shape(monkeypatch):
+    """Two calls, and a second sim of the same shape with another seed,
+    share one compiled init program."""
+    from p2p_gossipprotocol_tpu import aligned, telemetry
+
+    monkeypatch.setattr(aligned, "_INIT_PROGRAMS", {})
+    rec = telemetry.recorder()
+    mirror = rec._mirror
+    rec.configure(enabled=False)
+    rec.reset()
+    try:
+        rec.configure(enabled=True)
+        topo = build_aligned(seed=5, n=1024, n_slots=4)
+        a = AlignedSimulator(topo=topo, n_msgs=8, seed=1)
+        b = AlignedSimulator(topo=topo, n_msgs=8, seed=2)
+        a.init_state()
+        a.init_state()
+        st = b.init_state()
+        assert rec.counters()["init_state_compiles_total"] == 1
+        assert len(rec.spans("compile")) == 1
+        assert len(rec.spans("dispatch")) == 3
+        np.testing.assert_array_equal(np.asarray(st.seen_w),
+                                      _numpy_init(b)["seen_w"])
+    finally:
+        rec.configure(enabled=False)
+        rec.reset()
+        rec.set_mirror(mirror)
+
+
+def test_init_state_traced_then_real():
+    """jax.eval_shape of init_state caches no tracer: the real call after
+    it returns concrete arrays."""
+    topo = build_aligned(seed=6, n=1024, n_slots=4)
+    sim = AlignedSimulator(topo=topo, n_msgs=40, message_stagger=1, seed=3)
+    shapes = jax.eval_shape(sim.init_state)
+    st = sim.init_state()
+    for s, x in zip(jax.tree.leaves(shapes), jax.tree.leaves(st)):
+        assert not isinstance(x, jax.core.Tracer)
+        assert (s.shape, s.dtype) == (x.shape, x.dtype)
+    for x in jax.tree.leaves(sim._message_plan()):
+        assert not isinstance(x, jax.core.Tracer)
+    np.testing.assert_array_equal(np.asarray(st.seen_w), 0)
+
+
 def test_vmem_budget_guard():
     """Wide message sets must shrink the kernel row block; an over-budget
     (rowblk, W) combination fails at construction with the fix named, not

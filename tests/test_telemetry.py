@@ -462,9 +462,12 @@ def _to_coverage(sim):
     return jax.device_get((st, tp)), rounds
 
 
-def test_coverage_path_spans(_fresh_recorder, tmp_path):
-    """build_simulator, init_state with its steps, and run_to_coverage
-    with compile (first call only, counted), dispatch and fetch."""
+def test_coverage_path_spans(_fresh_recorder, tmp_path, monkeypatch):
+    """build_simulator, init_state and run_to_coverage, each with
+    compile (first call only, counted) and dispatch; fetch in the run."""
+    from p2p_gossipprotocol_tpu import aligned
+
+    monkeypatch.setattr(aligned, "_INIT_PROGRAMS", {})
     rec = _fresh_recorder
     rec.configure(enabled=True)
     sim = _aligned(tmp_path)
@@ -476,12 +479,14 @@ def test_coverage_path_spans(_fresh_recorder, tmp_path):
     assert len(by["build_simulator"]) == 1
     assert len(by["init_state"]) == 2 and len(by["run_to_coverage"]) == 2
     inits = {sp["span"] for sp in by["init_state"]}
-    for step in ("key", "message_plan", "seed", "strikes"):
-        assert {sp["parent"] for sp in by[step]} == inits
     runs = {sp["span"] for sp in by["run_to_coverage"]}
-    for step in ("dispatch", "fetch"):
-        assert {sp["parent"] for sp in by[step]} == runs
-    assert len(by["compile"]) == 1 and by["compile"][0]["parent"] in runs
+    assert {sp["parent"] for sp in by["dispatch"]} == inits | runs
+    assert {sp["parent"] for sp in by["fetch"]} == runs
+    assert len(by["dispatch"]) == 4 and len(by["fetch"]) == 2
+    assert len(by["compile"]) == 2
+    assert {sp["parent"] in inits for sp in by["compile"]} == {True, False}
+    assert {sp["parent"] for sp in by["compile"]} <= inits | runs
+    assert rec.counters()["init_state_compiles_total"] == 1
     assert rec.counters()["coverage_loop_compiles_total"] == 1
 
 
