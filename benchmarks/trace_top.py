@@ -12,7 +12,8 @@ Accepts a trace directory (finds the newest *.trace.json.gz under it)
 or a direct file path.  Prints one JSON line per leaf op: name, calls,
 total ms, share of the traced device time.  On a chip trace, one JSON
 line per phase of the round follows (the ``jax.named_scope`` each op
-ran under: churn, liveness, prep, push, pull, update, census, ...).
+ran under: churn, liveness, prep, push, pull, update, census, ...; under
+a fault plan also faults, the plane's work inside those phases).
 When the trace holds the program's own spans (``gossip.<name>``, as a
 CLI ``--profile-dir`` capture does), one JSON line per span name
 follows: count, total ms, and the ms in which the device was idle with

@@ -1568,6 +1568,9 @@ class AlignedSimulator:
         from p2p_gossipprotocol_tpu import faults as faults_lib
 
         plan = faults_lib.plan_from_config(cfg)
+        if plan is not None:
+            telemetry.event("fault_plan", spec=plan.to_spec(),
+                            kernel_gate=plan.kernel_active())
         # The plan's byzantine knob routes into the existing adversary
         # machinery (sim.Simulator.from_config has the same merge rule).
         byz = max(cfg.byzantine_fraction, plan.byzantine if plan else 0.0)
@@ -2332,9 +2335,10 @@ def aligned_round(sim: AlignedSimulator, state: AlignedState,
             # Scheduled crash/recovery: real deaths/revivals — the liveness
             # strikes below observe them, unlike partitions, which sever
             # transfers only.  Padding rows can never revive (& valid_b).
-            alive_b = faults_lib.schedule_step(
-                plan, fkey, alive_b, valid_b, state.round,
-                lambda k: row_uniform(k, grows, (LANES,)))
+            with jax.named_scope("faults"):
+                alive_b = faults_lib.schedule_step(
+                    plan, fkey, alive_b, valid_b, state.round,
+                    lambda k: row_uniform(k, grows, (LANES,)))
         defer_w = None
         if (plan is not None and plan.delay > 0.0
                 and sim.mode in ("push", "pushpull")):
@@ -2342,10 +2346,12 @@ def aligned_round(sim: AlignedSimulator, state: AlignedState,
             # (sender-side, per-peer — the synchronous-round model's delayed
             # delivery); pull serves are unaffected (the peer's state is
             # intact, only its relay is late).
-            u = row_uniform(jax.random.fold_in(fkey, faults_lib.TAG_DEFER),
-                            grows, (LANES,))
-            defer_w = jnp.where((u < plan.delay) & alive_b,
-                                jnp.int32(-1), jnp.int32(0))
+            with jax.named_scope("faults"):
+                u = row_uniform(
+                    jax.random.fold_in(fkey, faults_lib.TAG_DEFER), grows,
+                    (LANES,))
+                defer_w = jnp.where((u < plan.delay) & alive_b,
+                                    jnp.int32(-1), jnp.int32(0))
         kf = plan is not None and plan.kernel_active()
         if kf:
             # Per-link drop + partition gates, evaluated in-register inside
@@ -2476,8 +2482,10 @@ def aligned_round(sim: AlignedSimulator, state: AlignedState,
             # pull pass keeps serving them (delay is a relay fault, not a
             # state fault)
             src_ok = gather(alive_w & ~state.byz_w)
-            src_ok_push = (gather(alive_w & ~state.byz_w & ~defer_w)
-                           if defer_w is not None else src_ok)
+            src_ok_push = src_ok
+            if defer_w is not None:
+                with jax.named_scope("faults"):
+                    src_ok_push = gather(alive_w & ~state.byz_w & ~defer_w)
         # In-kernel seen-update (sim.fuse_update): the FINAL pass of the
         # round takes the receiver's seen planes + receive mask and emits
         # (new, seen') straight from its VMEM-resident accumulator — plus
@@ -2498,8 +2506,9 @@ def aligned_round(sim: AlignedSimulator, state: AlignedState,
             # The would-have-been relays a deferred peer holds back: they
             # re-enter the frontier below, so the transfer lands one round
             # late instead of never (flood-once would otherwise drop it).
-            deferred_w = (frontier_w & alive_w[None] & ~state.byz_w[None]
-                          & defer_w[None])
+            with jax.named_scope("faults"):
+                deferred_w = (frontier_w & alive_w[None]
+                              & ~state.byz_w[None] & defer_w[None])
     if sim.mode in ("push", "pushpull"):
         # Dead peers don't send; byzantine peers never relay (suppression,
         # models/gossip.py:50-58) — both masked at the source words.
@@ -2649,7 +2658,10 @@ def aligned_round(sim: AlignedSimulator, state: AlignedState,
                 recv & seen_w)))
         else:
             redeliveries = jnp.float32(0)
-        frontier = new if deferred_w is None else new | deferred_w
+        frontier = new
+        if deferred_w is not None:
+            with jax.named_scope("faults"):
+                frontier = new | deferred_w
     with jax.named_scope("census"):
         # In this engine deliveries == frontier bits by construction (every
         # first receipt enters the next frontier); both keys are kept for

@@ -123,11 +123,14 @@ def summarize(trace_file: str, top_n: int = 20) -> list[dict]:
 def _scope(op_name: str) -> str:
     """The phase an op ran in: the name-stack component under the loop
     body (``push`` of ``jit(looped)/while/body/push/push/pallas_call``),
-    else the outermost component (``jit(looped)`` for the loop's own
-    control, ``jit(ne)`` for an eager op)."""
+    except that the fault plane's work, which nests inside the phase it
+    runs in (``.../body/churn/faults/lt``), is ``faults``; else the
+    outermost component (``jit(looped)`` for the loop's own control,
+    ``jit(ne)`` for an eager op)."""
     parts = op_name.rstrip(":").split("/")
     if "body" in parts[:-1]:
-        return parts[parts.index("body") + 1]
+        below = parts[parts.index("body") + 1:]
+        return "faults" if "faults" in below[:-1] else below[0]
     return parts[0] or "?"
 
 

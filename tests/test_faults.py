@@ -327,6 +327,117 @@ def test_from_config_builds_faulted_engines(tmp_path):
     assert res.coverage[-1] >= 0.99
 
 
+# -- the program against the benchmark's plain reference ---------------
+# gossipbench/references/aligned_faults.py writes the fault plane from
+# the documented rules and imports nothing of the program; the timed
+# path (build_simulator -> init_state -> run_to_coverage) must end in
+# its state exactly.  The schedule sits early (split 1-4, crash at 1,
+# recovery at 3) so that every fault acts in a 16k-peer scenario.
+
+_PLAN_KEYS = {
+    "drop": {"fault_link_drop": "0.2"},
+    "delay": {"fault_delay": "0.1"},
+    "partition": {"fault_partition": "1:4", "fault_partition_groups": "2"},
+    "crash_recover": {"fault_crash": "1:0.3", "fault_recover": "3:0.5"},
+}
+_PLAN_KEYS["full"] = {k: v for kv in _PLAN_KEYS.values() for k, v in kv.items()}
+#: W=1 on the row-perm overlay; W=4 on the fused block-perm overlay
+_LAYOUTS = {"w1_rowperm": (32, False), "w4_fused": (128, True)}
+
+
+def _cell(tmp_path, n_peers, n_msgs, plan_keys, fault_seed=0):
+    """A faulted deployment as the benchmark holds one: dht-16m's keys
+    at ``n_peers``, the plan, burst traffic.  Returns (kv, config)."""
+    import os
+
+    from gossipbench import ROOT, spec
+    from p2p_gossipprotocol_tpu.config import NetworkConfig
+
+    with open(os.path.join(ROOT, "gossipbench/configs/dht-16m.txt")) as f:
+        text = f.read().replace("n_peers=16777216", f"n_peers={n_peers}")
+    keys = {**plan_keys, "fault_seed": str(fault_seed),
+            "n_messages": str(n_msgs), "message_stagger": "0",
+            "coverage_target": "0.85", "max_rounds": "32"}
+    path = tmp_path / "cell.txt"
+    path.write_text(text + "".join(f"{k}={v}\n" for k, v in keys.items()))
+    return spec.read_kv(str(path)), NetworkConfig(str(path))
+
+
+def _against_reference(kv, cfg, seed):
+    import jax.numpy as jnp
+
+    from gossipbench import check, window
+    from gossipbench.references import aligned_faults as ref
+    from p2p_gossipprotocol_tpu.aligned import aligned_coverage
+    from p2p_gossipprotocol_tpu.engines import build_simulator
+
+    sim, engine = build_simulator(cfg)
+    assert engine == "aligned" and sim.faults is not None
+    key = window.scenario_key(seed, 0)
+    st = sim.init_state().replace(key=jnp.asarray(key))
+    st, tp, rounds, _ = sim.run_to_coverage(
+        target=float(kv["coverage_target"]), max_rounds=int(kv["max_rounds"]),
+        state=st, warmup=False)
+    prog = check.flatten(st, tp, rounds, aligned_coverage(sim, st, tp))
+    scen = ref.Scenario(ref.deployment(kv))
+    numbers, parts = check.compare(prog, scen.run(key), scen.ov)
+    return sim, rounds, numbers, parts
+
+
+@pytest.mark.parametrize("layout", sorted(_LAYOUTS))
+@pytest.mark.parametrize("kind", sorted(_PLAN_KEYS))
+def test_program_matches_fault_reference(tmp_path, kind, layout):
+    n_msgs, fused = _LAYOUTS[layout]
+    kv, cfg = _cell(tmp_path, 16384, n_msgs, _PLAN_KEYS[kind])
+    sim, rounds, numbers, parts = _against_reference(kv, cfg, 2_400_000_029)
+    assert (sim.topo.ytab is not None) == fused
+    assert rounds > 3, "the scenario ended before every fault acted"
+    assert numbers == {"mismatch": 0, "coverage_gap": 0.0}, parts
+
+
+def test_skipped_fault_gate_is_not_correct(tmp_path, monkeypatch):
+    """A program whose kernels skip the link gate (a zero drop threshold
+    and no partition, whatever the plan says) reads not correct through
+    the harness, on a tiny faulted cell added to BENCHMARK.json as data."""
+    import argparse
+    import json
+    import os
+
+    from gossipbench import ROOT, run
+    from p2p_gossipprotocol_tpu import faults as faults_lib
+
+    real = faults_lib.kernel_meta
+
+    def ungated(plan, round_idx, pass_tag):
+        return real(plan, round_idx, pass_tag).at[2:].set(0)
+
+    monkeypatch.setattr(faults_lib, "kernel_meta", ungated)
+    root = tmp_path / "bench"
+    os.makedirs(root / "gossipbench/configs")
+    os.makedirs(root / "gossipbench/traffic")
+    with open(os.path.join(ROOT, "gossipbench/configs/dht-16m-faults.txt")) as f:
+        conf = f.read().replace("n_peers=16777216", "n_peers=8192")
+    (root / "gossipbench/configs/tiny-8k-faults.txt").write_text(conf)
+    (root / "gossipbench/traffic/burst64.txt").write_text(
+        "n_messages=64\nmessage_stagger=0\ncoverage_target=0.85\n"
+        "max_rounds=32\n")
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        b = json.load(f)
+    b["configs"].append({"name": "tiny-8k-faults", "source": "test",
+                         "file": "gossipbench/configs/tiny-8k-faults.txt",
+                         "reduced": ["n_peers"], "why": "test"})
+    b["workloads"].append({"name": "tiny-8k-faults.burst64",
+                           "config": "tiny-8k-faults", "traffic": "burst64",
+                           "chips": 1, "why": "test"})
+    (root / "BENCHMARK.json").write_text(json.dumps(b))
+    args = argparse.Namespace(workload="tiny-8k-faults.burst64",
+                              seed=2_400_000_031, seconds=0.5, trace=0)
+    line, err = run.execute(args, require_tpu=False,
+                            bench_path=str(root / "BENCHMARK.json"))
+    assert line["correct"] is False, err
+    assert line["checks"]["mismatch"]["value"] > 0
+
+
 def test_sir_rejects_fault_plan(tmp_path):
     from p2p_gossipprotocol_tpu.config import NetworkConfig
     from p2p_gossipprotocol_tpu.engines import build_simulator

@@ -226,6 +226,26 @@ def test_each_clamp_site_emits_one_typed_event(tmp_path, extra, site):
     assert hits[0]["kind"] == "clamp" and hits[0]["detail"]
 
 
+@pytest.mark.parametrize("extra,spec,gate", [
+    ("", None, None),
+    ("fault_link_drop=0.2\nfault_seed=3\n", "drop=0.2,seed=3", True),
+    ("fault_delay=0.1\nfault_crash=2:0.3\n", "delay=0.1,crash=2:0.3",
+     False),
+])
+def test_fault_plan_event_once_per_resolved_plan(tmp_path, extra, spec,
+                                                 gate):
+    from p2p_gossipprotocol_tpu.engines import build_simulator
+
+    cfg = NetworkConfig(_write_cfg(tmp_path, "engine=aligned\n" + extra))
+    telemetry.recorder().reset()
+    sim, _ = build_simulator(cfg)
+    evs = telemetry.recorder().events("fault_plan")
+    if spec is None:
+        assert sim.faults is None and evs == []
+    else:
+        assert [(e["spec"], e["kernel_gate"]) for e in evs] == [(spec, gate)]
+
+
 def test_classify_covers_every_known_clamp_string():
     for text, site in [
         ("block_perm with roll_groups=1 -> row-perm overlay", "auto_select"),
@@ -447,11 +467,11 @@ _LOOP_CFG = ("engine=aligned\nmode=pushpull\nchurn_rate=0.05\n"
              "liveness_every=3\n")
 
 
-def _aligned(tmp_path):
+def _aligned(tmp_path, extra=""):
     from p2p_gossipprotocol_tpu.engines import build_simulator
 
     sim, engine = build_simulator(NetworkConfig(
-        _write_cfg(tmp_path, _LOOP_CFG)))
+        _write_cfg(tmp_path, _LOOP_CFG + extra)))
     assert engine == "aligned" and sim._liveness
     return sim
 
@@ -518,6 +538,26 @@ def test_coverage_loop_names_passes_and_scopes(tmp_path):
     for scope in ("churn", "liveness", "prep", "push", "pull", "update",
                   "census"):
         assert f"/{scope}/" in text, scope
+
+
+@pytest.mark.parametrize("faulted", [False, True])
+def test_fault_plane_runs_under_its_scope(tmp_path, faulted):
+    """A faulted round's crash/recover schedule, defer draw and deferred
+    re-entry carry the ``faults`` scope inside their phases; an
+    unfaulted round has none, and its kernels keep their names."""
+    from p2p_gossipprotocol_tpu.state import build_coverage_loop
+
+    plan = ("fault_link_drop=0.2\nfault_delay=0.1\nfault_crash=2:0.3\n"
+            "fault_recover=4:0.5\n") if faulted else ""
+    sim = _aligned(tmp_path, plan)
+    looped = build_coverage_loop(sim.step, target=0.99, max_rounds=32,
+                                 check_every=1, sched_end=0)
+    args = (sim.init_state(), sim.topo)
+    names = _pallas_names(jax.make_jaxpr(looped)(*args).jaxpr, [])
+    assert sorted(names) == ["liveness", "pull", "push"]
+    text = jax.jit(looped).lower(*args).as_text(debug_info=True)
+    for phase in ("churn", "prep", "update"):
+        assert (f"/{phase}/faults/" in text) == faulted, phase
 
 
 def test_names_and_spans_change_no_result(_fresh_recorder, tmp_path,

@@ -78,20 +78,31 @@ def _overlay(sh, rowblk, words=1):
         table=_sds((SLOTS, T), jnp.int32, sh), T=T)
 
 
-@pytest.mark.parametrize("prefetch", [0, 2])
-def test_gossip_pass_w1_compiles(one_chip, prefetch):
+def _fault_gate(sh, T):
+    """The fault plane's scalar prefetch (faults.kernel_meta, and the
+    global row id of each row block)."""
+    return _sds((5,), jnp.int32, sh), _sds((T,), jnp.int32, sh)
+
+
+@pytest.mark.parametrize("prefetch,faulty", [(0, False), (2, False),
+                                             (2, True)],
+                         ids=["0", "2", "2-faulty"])
+def test_gossip_pass_w1_compiles(one_chip, prefetch, faulty):
     """The W=1 push pass of the chip's default round (row-perm overlay,
     frontier block-skip tables), with and without the manual DMA
-    prefetch ring."""
+    prefetch ring, and with the fault plane's link gate."""
     o = _overlay(one_chip, 2048)
+    gate_args = _fault_gate(one_chip, o["T"]) if faulty else ()
 
-    def push(y, colidx, gate, rolls, subrolls, yidx, yact):
+    def push(y, colidx, gate, rolls, subrolls, yidx, yact, *fault):
+        meta, gbase = fault or (None, None)
         return gossip_pass(y, colidx, gate, rolls, subrolls, yidx=yidx,
-                           yact=yact, prefetch_depth=prefetch,
-                           rowblk=2048, interpret=False)
+                           yact=yact, fault_meta=meta, gbase=gbase,
+                           prefetch_depth=prefetch, rowblk=2048,
+                           interpret=False)
 
     _compile(push, o["y"], o["colidx"], o["gate"], o["rolls"],
-             o["subrolls"], o["table"], o["table"])
+             o["subrolls"], o["table"], o["table"], *gate_args)
 
 
 def test_gossip_pass_w8_fused_compiles(one_chip):
@@ -108,6 +119,38 @@ def test_gossip_pass_w8_fused_compiles(one_chip):
     _compile(push, o["y"], o["colidx"], o["gate"], o["rolls"],
              o["subrolls"], o["table"], o["plane"], o["table"],
              o["table"])
+
+
+@pytest.mark.parametrize("pass_", ["push", "pull"])
+def test_gossip_pass_w8_fused_faulted_compiles(one_chip, pass_):
+    """The W=8 fused passes with the fault plane's in-register link gate
+    (fault_meta + gbase after the skip tables), as a faulted 256-message
+    deployment runs them: the push pass with the skip tables, the pull
+    pass over the first roll group's 4 slots."""
+    o = _overlay(one_chip, 512, words=8)
+    meta, gbase = _fault_gate(one_chip, o["T"])
+    if pass_ == "push":
+        def run(y, colidx, gate, rolls, subrolls, ytab, src_ok, meta,
+                gbase, yidx, yact):
+            return gossip_pass(y, colidx, gate, rolls, subrolls, ytab=ytab,
+                               src_ok=src_ok, fault_meta=meta, gbase=gbase,
+                               yidx=yidx, yact=yact, prefetch_depth=2,
+                               rowblk=512, interpret=False, name="push")
+
+        _compile(run, o["y"], o["colidx"], o["gate"], o["rolls"],
+                 o["subrolls"], o["table"], o["plane"], meta, gbase,
+                 o["table"], o["table"])
+        return
+    Dw = 4
+
+    def run(y, colidx, delta, rolls, subrolls, ytab, src_ok, meta, gbase):
+        return gossip_pass(y, colidx[:Dw], delta, rolls[:Dw], subrolls[:Dw],
+                           pull=True, ytab=ytab[:Dw], src_ok=src_ok,
+                           fault_meta=meta, gbase=gbase, prefetch_depth=2,
+                           rowblk=512, interpret=False, name="pull")
+
+    _compile(run, o["y"], o["colidx"], o["gate"], o["rolls"], o["subrolls"],
+             o["table"], o["plane"], meta, gbase)
 
 
 def test_count_pass_compiles(one_chip):

@@ -135,8 +135,9 @@ def test_no_program_spans(tmp_path):
 
 def test_scopes_group_leaf_time_by_phase(tmp_path):
     """A chip trace gives each op its name stack (``tf_op``): leaf time
-    groups by the phase under the loop body; the loop's own control
-    and eager ops keep their outermost name."""
+    groups by the phase under the loop body, the fault plane's nested
+    scope on its own; the loop's own control and eager ops keep their
+    outermost name."""
     from p2p_gossipprotocol_tpu.telemetry.traceview import scopes
 
     op = lambda name, ts, dur, tf_op=None: {  # noqa: E731
@@ -151,6 +152,7 @@ def test_scopes_group_leaf_time_by_phase(tmp_path):
         op("compare_fusion.2", 50, 5, f"{body}/census/eq:"),
         op("liveness.2", 55, 20,
            f"{body}/liveness/cond/branch_1_fun/liveness/pallas_call:"),
+        op("add_maximum_fusion.3", 85, 3, f"{body}/churn/faults/lt:"),
         op("copy.86", 75, 5),
         op("lt.3", 80, 1, "jit(looped)/while/lt:"),
         op("ne", 200, 2, "jit(ne)/ne:"),
@@ -158,7 +160,8 @@ def test_scopes_group_leaf_time_by_phase(tmp_path):
     rows = {r["scope"]: (r["calls"], r["total_ms"])
             for r in scopes(str(trace))}
     assert rows == {"push": (1, 0.04), "census": (2, 0.015),
-                    "liveness": (1, 0.02), "(none)": (1, 0.005),
+                    "liveness": (1, 0.02), "faults": (1, 0.003),
+                    "(none)": (1, 0.005),
                     "jit(looped)": (1, 0.001), "jit(ne)": (1, 0.002)}
 
 
