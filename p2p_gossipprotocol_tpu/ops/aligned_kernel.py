@@ -55,6 +55,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 LANES = 128
+#: most vreg loads a rolled read emits for one ref (see _rolled_reader)
+_MAX_LOADS = 64
 
 
 def interpret_for(platform: str) -> bool:
@@ -105,6 +107,82 @@ def _fold8(x):
     tot = jnp.sum(x, axis=0, keepdims=True)
     row = jax.lax.broadcasted_iota(jnp.int32, (8, C), 0)
     return jnp.where(row == 0, jnp.broadcast_to(tot, (8, C)), 0)
+
+
+def _rolled_reader(s, blk: int):
+    """Reader of VMEM blocks rolled up by ``s`` sublanes: ``read(ref)``
+    takes a ref of shape (..., blk, 128) and returns the value whose row
+    i is row (i + s) % blk of ``ref`` — ``np.roll(x, -s, axis=-2)`` —
+    for 0 <= s < blk.
+
+    A dynamic ``pltpu.roll`` over a block of several vregs lowers to a
+    log2(blk/8)-stage barrel of selects on every vreg.  Here the
+    vreg-granular part of the shift (s // 8) is an address instead:
+    result vreg k reads the 8-aligned source vreg (k + s//8) % (blk/8).
+    Only the residual (s % 8) costs work: one select per vreg, which
+    blends a source vreg with its successor, and one sublane rotate.  A
+    block of more than ``_MAX_LOADS`` vregs loads runs of vregs instead,
+    so the kernel stays small to trace and lower; the part of the shift
+    below a run costs one more select a vreg per doubling of the run.
+    The offsets and masks are built once, so every ref read through one
+    reader (all planes of a grid step) shares them.  A block of one
+    vreg, or of a fractional number of vregs (interpret-mode toy
+    shapes), keeps ``pltpu.roll``, which is one rotate there."""
+    if blk <= 8 or blk % 8:
+        # pltpu.roll(x, k) moves row i to i+k, so out-row i reads row
+        # i-k: rolling by blk-s reads row i+s
+        def read(ref):
+            x = ref[...]
+            return pltpu.roll(x, blk - s, axis=x.ndim - 2)
+
+        return read
+    nv = blk // 8
+    run = 1                      # vregs a load
+    while nv // run > _MAX_LOADS and nv % (2 * run) == 0:
+        run *= 2
+    r = s % 8
+    q = s // 8
+    q_low = q % run
+    base = (q - q_low) * 8
+    offs = []
+    # lax, not jnp: a jnp op on a scalar costs a dispatch at trace time
+    for j in range(nv // run):
+        o = jax.lax.add(base, np.int32(8 * run * j))
+        # one scalar op per load where a mask can wrap the offset
+        o = (jax.lax.bitwise_and(o, np.int32(blk - 1))
+             if blk & (blk - 1) == 0 else
+             jax.lax.rem(o, np.int32(blk)))
+        offs.append(pl.multiple_of(o, 8 * run))
+    # (vregs, taken) for each bit of the shift below a run
+    steps = [(1 << i, (q_low & (1 << i)) != 0)
+             for i in range(run.bit_length() - 1)]
+    rot = (8 - r) % 8
+    keep = jax.lax.broadcasted_iota(jnp.int32, (8, LANES), 0) >= r
+
+    def read(ref):
+        lead = ref.shape[:-2]
+        # x: the block rolled up by 8 * run * (s // (8 * run)) rows
+        x = jnp.concatenate(
+            [ref[..., pl.ds(o, 8 * run), :] for o in offs], axis=-2)
+        for k, taken in steps:   # then up by 8 * (s // 8 % run) rows
+            up = jnp.concatenate([x[..., 8 * k:, :], x[..., :8 * k, :]],
+                                 axis=-2)
+            x = jnp.where(taken, up, x)
+        nxt = jnp.concatenate([x[..., 8:, :], x[..., :8, :]], axis=-2)
+        # vreg k keeps its sublanes >= r and takes the rest from vreg
+        # k+1; rotating each vreg up by r then puts row i + s at row i
+        v = lead + (nv, 8, LANES)
+        b = jnp.where(keep, x.reshape(v), nxt.reshape(v))
+        return pltpu.roll(b, rot, axis=len(v) - 2).reshape(ref.shape)
+
+    return read
+
+
+def _gather_lanes(x, idx):
+    """Row-wise lane gather ``x[r, idx[r, c]]``: one ``tpu.dynamic_gather``
+    per vreg.  Lane indices (overlay lanes, rewire candidates) lie in
+    [0, 128) by construction, so no negative-index wrap is emitted."""
+    return jnp.take_along_axis(x, idx, axis=1, mode="promise_in_bounds")
 
 
 def _pass_kernel(pull: bool, n_planes: int, fanout: int, fused: bool,
@@ -198,9 +276,6 @@ def _pass_kernel(pull: bool, n_planes: int, fanout: int, fused: bool,
     # peer's D slots see D distinct source rows even when the grid has a
     # single row block (otherwise all slots would read perm[r] and rumors
     # would be trapped inside that one permutation's cycles).
-    # pltpu.roll(x, s) moves row i to i+s, i.e. out-row i sees row i-s —
-    # so rolling by -s_d would READ row i+s_d; jnp.roll has the same
-    # convention but its dynamic-shift form doesn't lower on Mosaic.
     blk = col_ref.shape[1]       # y_ref is a whole HBM ref under pref2
     if pref2:
         # Double-buffered prefetch: the y (and src_ok) block for the
@@ -300,28 +375,28 @@ def _pass_kernel(pull: bool, n_planes: int, fanout: int, fused: bool,
         # STALE block (the remap pinned the index), so the gate — not
         # the data — makes the contribution zero
         mask = mask & (yact_ref[d, pl.program_id(0)] != 0)
+    read = _rolled_reader(subrolls_ref[d], blk)
+    # The slot mask (and the source's send mask) fold into one word mask
+    # per step, so each plane pays one AND for them.
     if masked:
-        ok_words = okbuf[slot] if pref2 else ok_ref[:]
-        okv = jnp.take_along_axis(
-            pltpu.roll(ok_words, blk - subrolls_ref[d], axis=0),
-            col, axis=1)
+        okv = _gather_lanes(read(okbuf.at[slot] if pref2 else ok_ref), col)
+        m = jnp.where(mask, okv, 0)
+    else:
+        m = jnp.where(mask, -1, 0)
     # Static unroll over message planes: col/gate/ok stay resident, each
-    # plane costs one sublane roll + one lane-wise dynamic_gather.
+    # plane costs one rolled read + one lane-wise dynamic_gather.
     n_slots = pl.num_programs(1)
     pz = None
+    ys = read(ybuf.at[slot] if pref2 else y_ref)
     for w in range(n_planes):
-        yw = ybuf[slot, w] if pref2 else y_ref[w]
-        y = pltpu.roll(yw, blk - subrolls_ref[d], axis=0)
-        zw = jnp.take_along_axis(y, col, axis=1)
+        zw = _gather_lanes(ys[w], col)
         if press and w == 0:
             # infectious-neighbor pressure: plane 0 is a flag plane
             # (-1 transmitting / 0), so the gathered word's low bit IS
             # the count contribution — the solo count_pass's z, from
             # the gather this pass already paid for
             pz = jnp.where(mask, zw & 1, 0)
-        if masked:
-            zw = zw & okv
-        z = jnp.where(mask, zw, 0)
+        z = zw & m
 
         @pl.when(d == 0)
         def _(w=w, z=z):
@@ -657,10 +732,9 @@ def gossip_pass(y: jax.Array, colidx: jax.Array, gate: jax.Array,
 def _count_kernel(rolls_ref, subrolls_ref, y_ref, col_ref, gate_ref,
                   acc_ref):
     d = pl.program_id(1)
-    blk = y_ref.shape[0]
-    y = pltpu.roll(y_ref[:], blk - subrolls_ref[d], axis=0)
+    y = _rolled_reader(subrolls_ref[d], y_ref.shape[0])(y_ref)
     col = col_ref[0].astype(jnp.int32)
-    z = jnp.take_along_axis(y, col, axis=1) & 1   # -1 mask -> 1, 0 -> 0
+    z = _gather_lanes(y, col) & 1   # -1 mask -> 1, 0 -> 0
     g = gate_ref[:].astype(jnp.int32)
     z = jnp.where(d < g, z, 0)
 
@@ -798,9 +872,9 @@ def _liveness_kernel(max_strikes, n_pref, *refs):
     t = pl.program_id(0)
     d = pl.program_id(1)
     blk = y_ref.shape[0]
-    y = pltpu.roll(y_ref[:], blk - subrolls_ref[d], axis=0)
+    y = _rolled_reader(subrolls_ref[d], blk)(y_ref)
     col = col_ref[0].astype(jnp.int32)
-    nbr_alive = jnp.take_along_axis(y, col, axis=1) != 0
+    nbr_alive = _gather_lanes(y, col) != 0
     g = gate_ref[:].astype(jnp.int32)
     is_edge = d < g
     s = strikes_ref[0].astype(jnp.int32)
@@ -813,7 +887,7 @@ def _liveness_kernel(max_strikes, n_pref, *refs):
             * LANES
             + jax.lax.broadcasted_iota(jnp.int32, (blk, LANES), 1))
     cand = _rewire_hash(flat, d, meta_ref[0], meta_ref[1])
-    cand_alive = jnp.take_along_axis(y, cand, axis=1) != 0
+    cand_alive = _gather_lanes(y, cand) != 0
     take = evict & cand_alive
     col_out[0] = jnp.where(take, cand, col).astype(jnp.int8)
     strikes_out[0] = jnp.where(take, 0, s_new).astype(jnp.int8)

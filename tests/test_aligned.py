@@ -7,6 +7,8 @@ dissemination behavior is validated statistically against the exact
 edge-list engine on a comparable random graph.
 """
 
+import functools
+
 import numpy as np
 import pytest
 
@@ -280,6 +282,163 @@ def test_liveness_pass_matches_ground_truth():
     np.testing.assert_array_equal(np.asarray(col_k), col_n)
     np.testing.assert_array_equal(np.asarray(s_k), s_n)
     np.testing.assert_array_equal(np.asarray(ev_k), ev_n)
+
+
+# ----------------------------------------------------------------------
+# The rolled read: blocks of several vregs (the toy tests above use one)
+
+@functools.cache
+def _rolled_outputs(shape, n, ring):
+    """``_rolled_reader`` over one ``shape`` = (..., blk, 128) block,
+    once per each of ``n`` shifts (a grid step each): read from the
+    BlockSpec buffer, or from a slot of a two-slot VMEM ring filled by
+    DMA, as the prefetch_depth=2 stream fills it."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    from p2p_gossipprotocol_tpu.ops.aligned_kernel import _rolled_reader
+
+    blk = shape[-2]
+    zeros = (0,) * len(shape)
+
+    def body(s_ref, x_ref, o_ref, *scratch):
+        i = pl.program_id(0)
+        src = x_ref
+        if ring:
+            buf, sem = scratch
+            slot = i % 2
+            cp = pltpu.make_async_copy(x_ref, buf.at[slot], sem.at[slot])
+            cp.start()
+            cp.wait()
+            src = buf.at[slot]
+        o_ref[0] = _rolled_reader(s_ref[i], blk)(src)
+
+    spec = (pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY) if ring
+            else pl.BlockSpec(shape, lambda i, s: zeros))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1, grid=(n,), in_specs=[spec],
+        out_specs=pl.BlockSpec((1,) + shape, lambda i, s: (i,) + zeros),
+        scratch_shapes=([pltpu.VMEM((2,) + shape, jnp.int32),
+                         pltpu.SemaphoreType.DMA((2,))] if ring else []))
+    return jax.jit(pl.pallas_call(
+        body, grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((n,) + shape, jnp.int32),
+        interpret=True))
+
+
+@pytest.mark.parametrize("source", ["block", "ring"])
+@pytest.mark.parametrize("blk", [8, 24, 64, 512, 1552, 2048])
+def test_rolled_reader_matches_np_roll(blk, source):
+    """Row i of the read is row (i + s) % blk of the block, for every
+    shift (a sample of them past 512 rows, where loads take runs of
+    vregs): the addressed loads, the per-vreg rotate and the blend,
+    against ``np.roll``.  The BlockSpec case reads a single plane, the
+    ring case two planes of one slot."""
+    rng = np.random.default_rng(blk)
+    planes = (2,) if source == "ring" else ()
+    x = rng.integers(-2**31, 2**31, size=planes + (blk, LANES),
+                     dtype=np.int64).astype(np.int32)
+    if blk > 512:
+        shifts = np.unique(np.concatenate([
+            [0, 1, 7, 8, 9, 31, 32, 33, blk // 2, blk - 8, blk - 1],
+            rng.integers(0, blk, size=21)]))
+    else:
+        shifts = np.arange(blk)
+    run = _rolled_outputs(x.shape, 64, source == "ring")
+    for lo in range(0, len(shifts), 64):
+        chunk = shifts[lo:lo + 64]
+        got = np.asarray(run(jnp.asarray(np.resize(chunk, 64), jnp.int32),
+                             jnp.asarray(x)))
+        for s, g in zip(chunk, got):
+            np.testing.assert_array_equal(g, np.roll(x, -s, axis=-2),
+                                          err_msg=f"shift {s}")
+
+
+@pytest.mark.parametrize("prefetch", [0, 2])
+@pytest.mark.parametrize("pull", [False, True], ids=["push", "pull"])
+def test_pass_matches_ground_truth_multi_vreg(pull, prefetch):
+    """gossip_pass at 64-row blocks (8 vregs, 2 row blocks), with and
+    without the prefetch ring, against the numpy neighbor map."""
+    rng = np.random.default_rng(17)
+    R, D, W, blk = 128, 6, 3, 64
+    y = rng.integers(0, 2**31, size=(W, R, LANES), dtype=np.int32)
+    colidx = rng.integers(0, LANES, size=(D, R, LANES), dtype=np.int8)
+    gate = rng.integers(0, D + 1, size=(R, LANES), dtype=np.int8)
+    rolls = rng.integers(0, 2, size=D, dtype=np.int32)
+    subrolls = np.array([0, 7, 8, 9, 63, 36], np.int32)
+    out = gossip_pass(jnp.asarray(y), jnp.asarray(colidx), jnp.asarray(gate),
+                      jnp.asarray(rolls), jnp.asarray(subrolls), pull=pull,
+                      prefetch_depth=prefetch, rowblk=blk, interpret=True)
+    ref = _numpy_pass(y, colidx, gate, rolls, subrolls, rowblk=blk,
+                      pull=pull)
+    np.testing.assert_array_equal(np.asarray(out), ref)
+
+
+@pytest.mark.parametrize("prefetch", [0, 2])
+def test_fused_pass_matches_ground_truth_multi_vreg(prefetch):
+    """The block-perm pass at 64-row blocks: y blocks by the ytab table,
+    the source's send mask ANDed per gathered lane (the folded slot
+    mask), against numpy."""
+    rng = np.random.default_rng(19)
+    R, D, W, blk = 256, 5, 2, 64
+    T = R // blk
+    y = rng.integers(0, 2**31, size=(W, R, LANES), dtype=np.int32)
+    ok = np.where(rng.uniform(size=(R, LANES)) < 0.7, -1, 0).astype(np.int32)
+    colidx = rng.integers(0, LANES, size=(D, R, LANES), dtype=np.int8)
+    gate = rng.integers(0, D + 1, size=(R, LANES), dtype=np.int8)
+    ytab = rng.integers(0, T, size=(D, T), dtype=np.int32)
+    subrolls = rng.integers(0, blk, size=D, dtype=np.int32)
+    out = gossip_pass(jnp.asarray(y), jnp.asarray(colidx), jnp.asarray(gate),
+                      jnp.zeros(D, jnp.int32), jnp.asarray(subrolls),
+                      ytab=jnp.asarray(ytab), src_ok=jnp.asarray(ok),
+                      prefetch_depth=prefetch, rowblk=blk, interpret=True)
+    r = np.arange(R)
+    ref = np.zeros((W, R, LANES), np.int32)
+    for d in range(D):
+        src = ytab[d, r // blk] * blk + (r % blk + subrolls[d]) % blk
+        lanes = colidx[d].astype(np.int64)
+        sent = ok[src[:, None], lanes]
+        for w in range(W):
+            ref[w] |= np.where(d < gate, y[w][src[:, None], lanes] & sent, 0)
+    np.testing.assert_array_equal(np.asarray(out), ref)
+
+
+def test_liveness_and_count_match_ground_truth_multi_vreg():
+    """liveness_pass and count_pass at 64-row blocks against numpy."""
+    from p2p_gossipprotocol_tpu.ops.aligned_kernel import (
+        count_pass, liveness_pass, rewire_candidates)
+
+    rng = np.random.default_rng(23)
+    R, D, blk, max_strikes = 128, 4, 64, 3
+    y_alive = np.where(rng.uniform(size=(R, LANES)) < 0.6, -1,
+                       0).astype(np.int32)
+    colidx = rng.integers(0, LANES, size=(D, R, LANES), dtype=np.int8)
+    strikes = rng.integers(0, max_strikes + 2, size=(D, R, LANES),
+                           dtype=np.int8)
+    deg = rng.integers(0, D + 1, size=(R, LANES), dtype=np.int8)
+    rolls = rng.integers(0, 2, size=D, dtype=np.int32)
+    subrolls = np.array([5, 8, 0, 61], np.int32)
+    grows = jnp.arange(R, dtype=jnp.int32)
+    args = [jnp.asarray(a) for a in (rolls, subrolls)]
+    got = liveness_pass(
+        jnp.asarray(y_alive), jnp.asarray(colidx), jnp.asarray(strikes),
+        jnp.asarray(deg), *args, gbase=grows[::blk], round_idx=5,
+        hash_seed=9, max_strikes=max_strikes, rowblk=blk, interpret=True)
+    rand = np.asarray(rewire_candidates(grows, D, 5, 9))
+    want = _numpy_liveness(y_alive, colidx, strikes, rand, deg, rolls,
+                           subrolls, rowblk=blk, max_strikes=max_strikes)
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(g), w)
+
+    counts = count_pass(jnp.asarray(y_alive), jnp.asarray(colidx),
+                        jnp.asarray(deg), *args, rowblk=blk, interpret=True)
+    r = np.arange(R)
+    ref = np.zeros((R, LANES), np.int32)
+    for d in range(D):
+        src = ((r // blk + rolls[d]) % 2) * blk + (r % blk + subrolls[d]) % blk
+        z = y_alive[src[:, None], colidx[d].astype(np.int64)] & 1
+        ref += np.where(d < deg, z, 0)
+    np.testing.assert_array_equal(np.asarray(counts), ref)
 
 
 def test_churn_kills_then_network_recovers():

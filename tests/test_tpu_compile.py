@@ -179,6 +179,24 @@ def test_liveness_pass_compiles(one_chip):
                                              one_chip), scalar, scalar)
 
 
+def test_liveness_pass_blk512_fused_compiles(one_chip):
+    """The liveness sweep at the 512-row blocks a 256-message deployment
+    runs it at, on the fused block-perm overlay (ytab index map)."""
+    o = _overlay(one_chip, 512, words=8)
+
+    def sweep(y_alive, colidx, strikes, gate, rolls, subrolls, ytab, gbase,
+              round_idx, seed):
+        return liveness_pass(y_alive, colidx, strikes, gate, rolls,
+                             subrolls, gbase=gbase, round_idx=round_idx,
+                             hash_seed=seed, ytab=ytab, rowblk=512,
+                             interpret=False)
+
+    scalar = _sds((), jnp.int32, one_chip)
+    _compile(sweep, o["plane"], o["colidx"], o["colidx"], o["gate"],
+             o["rolls"], o["subrolls"], o["table"],
+             _sds((o["T"],), jnp.int32, one_chip), scalar, scalar)
+
+
 def _scale_sim(n_shards=1):
     from p2p_gossipprotocol_tpu.aligned import AlignedSimulator
     from p2p_gossipprotocol_tpu.config import NetworkConfig
