@@ -3,7 +3,7 @@
 
 PY ?= python
 
-.PHONY: lint test native tune
+.PHONY: lint test native
 
 # gossip-lint: the AST contract checker (docs/STATIC_ANALYSIS.md).
 # Exit 0 = every finding baselined-with-justification, no stale
@@ -18,11 +18,3 @@ test:
 
 native:
 	$(MAKE) -C native
-
-# Closed-loop autotuner (docs/PERFORMANCE.md "Round 14"): sweep the
-# legal static space for network.txt on this machine's backend and
-# persist the winner in the tuning cache (GOSSIP_TUNING_CACHE, default
-# benchmarks/results/tuning_cache.json).  TUNE_ARGS passes extra flags
-# (e.g. TUNE_ARGS="--force --serve").
-tune:
-	$(PY) -m p2p_gossipprotocol_tpu.tuning network.txt $(TUNE_ARGS)

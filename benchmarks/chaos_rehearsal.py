@@ -19,7 +19,7 @@ promise of runtime/supervisor.py end to end:
 
     python benchmarks/chaos_rehearsal.py                 # seed 0
     python benchmarks/chaos_rehearsal.py --seed 3 --kill sigstop
-    python benchmarks/chaos_rehearsal.py --out benchmarks/results/round9_cpu.jsonl
+    python benchmarks/chaos_rehearsal.py --out chaos.jsonl
 
 Exit 0 iff the run self-healed AND parity held.  The driver process
 never runs device code itself — the workers are real subprocesses, the
@@ -274,9 +274,7 @@ def main() -> int:
                          "model; the rehearsal pins a small one so "
                          "SIGSTOP detection is test-speed)")
     ap.add_argument("--out", default=None, metavar="JSONL",
-                    help="append the result row here (the "
-                         "measure_round9 driver points this at "
-                         "benchmarks/results/round9_cpu.jsonl)")
+                    help="append the result row here")
     ap.add_argument("--keep-dir", default=None,
                     help="run under this directory (kept); default: "
                          "a fresh temp dir")

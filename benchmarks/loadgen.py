@@ -1,6 +1,5 @@
 """Seed-deterministic multi-tenant load shapes for the serving-plane
-harnesses (round 18; consumed by measure_round18.py, importable by any
-later driver).
+harnesses (round 18), for the served benchmark cell to drive.
 
 The round-12/17 sweeps drove homogeneous Poisson arrivals — the right
 null model for a latency knee, but not the shape that stresses a
@@ -22,8 +21,8 @@ against a trickle).  Two generators, both pure functions of their
 
 :func:`tenant_stream` deals tenants over an arrival stream by weight,
 deterministically.  :func:`synth` composes the three into the
-``(overrides, gaps)`` pair the measure_round* drivers feed their wire
-loops — every request dict carries its ``tenant`` tag (the round-18
+``(overrides, gaps)`` pair a driver feeds its wire loop — every
+request dict carries its ``tenant`` tag (the round-18
 SLO field) and cycles the same six program-signature families the
 round-17 sweep served, so bucket lifecycle stays in play.
 

@@ -1,12 +1,14 @@
 """Summarize a jax.profiler trace: top ops by total device time.
 
-Feeds the traffic-model reconciliation (round-4 verdict item 2): point
-it at the `.trace.json.gz` a capture wrote (e.g. by
-benchmarks/measure_round4.py into benchmarks/profiles/) and compare the
-dominant kernels' share of the round against hbm_bytes_per_round's
-per-term accounting.
+Point it at the `.trace.json.gz` a capture wrote — the CLI's
+``--profile-dir`` keeps the same profiler trace that
+``python3 -m gossipbench --trace 1`` takes (the benchmark reads its own
+in place and removes it) — and compare the dominant kernels' share of
+the round against the traffic model's per-term accounting:
 
-    python benchmarks/trace_top.py benchmarks/profiles/r4_10m [N]
+    python -m p2p_gossipprotocol_tpu.cli examples/scale.txt \
+        --backend jax --profile-dir chiprun_out/prof
+    python benchmarks/trace_top.py chiprun_out/prof [N]
 
 Accepts a trace directory (finds the newest *.trace.json.gz under it)
 or a direct file path.  Prints one JSON line per leaf op: name, calls,

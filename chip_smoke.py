@@ -20,8 +20,8 @@ Every phase prints one JSON line; the last line of stdout is
 ``{"ok": true, "device": {"platform": "tpu", "kind": ..., "count": N}}``.
 Any failure — no TPU, a missing checkout, a mismatch — exits non-zero
 before that line.  The script reads only committed files (the overlays
-are built from the config's seed; the tuning cache is off) and starts no
-process: a chip belongs to one process.
+are built from the config's seed) and starts no process: a chip
+belongs to one process.
 """
 
 from __future__ import annotations
@@ -235,8 +235,6 @@ def main(argv: list[str] | None = None) -> int:
               f"(missing {SCALE_CFG} or the package)", file=sys.stderr)
         return 2
     sys.path.insert(0, HERE)
-    # only committed inputs: the tuning cache holds interpret-keyed rows
-    os.environ["GOSSIP_TUNING_CACHE"] = "off"
     # the host CPU device is the reference: keep it next to the chip
     platforms = os.environ.get("JAX_PLATFORMS", "")
     if platforms and "cpu" not in platforms.split(","):

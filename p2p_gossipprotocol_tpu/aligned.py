@@ -38,6 +38,7 @@ import jax
 import jax.numpy as jnp
 from flax import struct
 
+from p2p_gossipprotocol_tpu import autostatics as auto
 from p2p_gossipprotocol_tpu import faults as faults_lib
 from p2p_gossipprotocol_tpu import telemetry
 from p2p_gossipprotocol_tpu.liveness import ChurnConfig
@@ -77,11 +78,9 @@ Y_REUSE_LEAK = 0.43
 # an index CHANGE, the same dedup rule stream_plan replays).  Charging
 # 0 here is the CONSERVATIVE direction for everything the model feeds:
 # fewer modeled bytes -> lower achieved_gb_s and roofline_frac, so the
-# prefetch path can only under-report its own win.  The on-chip
-# recalibration microbench (kernel-only rolls 16-vs-4 under prefetch
-# on/off) ships in benchmarks/measure_round10.py for the next TPU
-# window; a measured nonzero leak would land here with its derivation
-# (docs/PERFORMANCE.md "Round 10").
+# prefetch path can only under-report its own win.  A measured nonzero
+# leak (kernel-only rolls 16-vs-4 under prefetch on/off, on the chip)
+# would land here with its derivation (docs/PERFORMANCE.md "Round 10").
 Y_REUSE_LEAK_PREFETCH = 0.0
 
 # from_config auto-selects the block-perm fused overlay at this message
@@ -100,7 +99,7 @@ AUTO_BLOCK_PERM_MIN_WORDS = 4
 # transfer, far enough below breakeven that the compaction/scatter
 # overhead can't erase the win, while post-peak rounds (typically
 # <0.1% of words changed) fit with orders of magnitude to spare.
-FRONTIER_THRESHOLD_DEFAULT = 1.0 / 64.0
+FRONTIER_THRESHOLD_DEFAULT = auto.FRONTIER_THRESHOLD_DEFAULT
 
 # from_config's VMEM-budget row-block cap: at small W the budget admits
 # blocks far wider than the legacy 512 (W=1 -> 2048 rows/block), which
@@ -1467,62 +1466,65 @@ class AlignedSimulator:
                     "cycle — use pushpull, or a row-perm overlay")
         else:
             self._pull_slots = self.topo.n_slots
-        # Frontier-sparse resolution (after ``interpret`` is known —
-        # auto keys off it): block skipping needs a push pass to skip
-        # in; the delta exchange engages only when a sharded engine
-        # passes its FrontierCarry into the round.  The auto rules live
-        # in tuning/resolve.py — THE chokepoint every -1-auto static
-        # resolves through (gossip-lint tuning-chokepoint), so the
-        # autotuner and the direct-constructor path share one rule set.
-        from p2p_gossipprotocol_tpu.tuning import resolve as tuning_resolve
-
+        # The schedule statics, each resolved HERE and only here (after
+        # ``interpret`` is known — auto keys off it): the public field
+        # takes its rule's concrete value (autostatics.py, the one home
+        # of every -1 rule) and the private flags derive from it.  All
+        # are bitwise-identical either way.  Frontier-sparse rounds:
+        # block skipping needs a push pass to skip in; the delta
+        # exchange engages only when a sharded engine passes its
+        # FrontierCarry into the round.
         if self.frontier_mode not in (-1, 0, 1):
             raise ValueError("frontier_mode must be -1 (auto), 0, or 1")
+        self.frontier_threshold = auto.heuristic_frontier_threshold(
+            self.frontier_threshold)
         if not 0.0 < self.frontier_threshold <= 1.0:
             raise ValueError("frontier_threshold must be in (0, 1]")
-        fr_on = tuning_resolve.heuristic_on(self.frontier_mode,
-                                            self.interpret)
+        self.frontier_mode = int(auto.heuristic_on(self.frontier_mode,
+                                                   self.interpret))
+        fr_on = bool(self.frontier_mode)
         self._frontier_skip = fr_on and self.mode in ("push", "pushpull")
         self._frontier_delta = fr_on
         # Sparse-allreduce execution of the delta exchange (round 16):
-        # resolved here like frontier_mode; the per-tier power-of-two
-        # legality is structural (_frontier_exchange / halving_steps),
-        # so the resolved flag means "halving wherever the mesh can".
+        # the per-tier power-of-two legality is structural
+        # (_frontier_exchange / halving_steps), so the resolved flag
+        # means "halving wherever the mesh can".
         if self.frontier_algo not in (-1, 0, 1):
             raise ValueError("frontier_algo must be -1 (auto), 0 "
                              "(gather), or 1 (halving)")
-        self._frontier_algo = tuning_resolve.heuristic_on(
-            self.frontier_algo, self.interpret)
-        # Round-10 schedule knobs (both bitwise-identical, both keyed
-        # off interpret on auto like frontier_mode): the manual
-        # double-buffered DMA stream, and the self/remote split that
-        # hides the sharded exchange behind the self-shard kernel.
+        self.frontier_algo = int(auto.heuristic_on(self.frontier_algo,
+                                                   self.interpret))
+        self._frontier_algo = bool(self.frontier_algo)
+        # Round-10 schedule knobs: the manual double-buffered DMA
+        # stream, and the self/remote split that hides the sharded
+        # exchange behind the self-shard kernel.
         if self.prefetch_depth not in (-1, 0, 2):
             raise ValueError("prefetch_depth must be -1 (auto), 0, or 2")
-        self._prefetch = tuning_resolve.heuristic_prefetch(
-            self.prefetch_depth, self.interpret)
+        self.prefetch_depth = auto.heuristic_prefetch(self.prefetch_depth,
+                                                      self.interpret)
+        self._prefetch = self.prefetch_depth
         if self.overlap_mode not in (-1, 0, 1):
             raise ValueError("overlap_mode must be -1 (auto), 0, or 1")
+        self.overlap_mode = int(auto.heuristic_on(self.overlap_mode,
+                                                  self.interpret))
         # the split needs a push pass to split and the block-perm
         # overlay's block-granular locality (a row-granular permutation
         # scatters every y block's rows across all shards); it engages
         # only when aligned_round actually runs sharded (n_shards > 1)
-        self._overlap = (tuning_resolve.heuristic_on(self.overlap_mode,
-                                                     self.interpret)
+        self._overlap = (bool(self.overlap_mode)
                          and self.topo.ytab is not None
                          and self.mode in ("push", "pushpull"))
         # Hierarchical two-tier exchange (round 11): resolved here so
         # the fleet packer and the traffic model read one static; the
         # sharded engines additionally require their mesh to carry the
-        # factorization.  Auto keys off interpret like frontier_mode
-        # (the staged exchange only adds XLA work on the CPU path).
+        # factorization.
         if self.hier_mode not in (-1, 0, 1):
             raise ValueError("hier_mode must be -1 (auto), 0, or 1")
         if self.hier_hosts < 0 or self.hier_devs < 0:
             raise ValueError("hier_hosts/hier_devs must be >= 0")
-        self._hier = (self.hier_hosts > 1
-                      and tuning_resolve.heuristic_on(self.hier_mode,
-                                                      self.interpret))
+        self.hier_mode = int(auto.heuristic_on(self.hier_mode,
+                                               self.interpret))
+        self._hier = self.hier_hosts > 1 and bool(self.hier_mode)
         # Liveness (strikes/rewire) runs whenever peers can die — without
         # churn no neighbor is ever observed dead, so the pass is skipped
         # statically and the strike plane is never allocated.
@@ -1596,16 +1598,10 @@ class AlignedSimulator:
         # rolls).  An EXPLICIT block_perm=0/1 is honored, except that
         # illegal combinations degrade with a recorded clamp instead of
         # erroring the run — same seam as every other engine ceiling.
-        # The rule itself lives in tuning/resolve.py (the -1-auto
-        # chokepoint); block_perm is NOT cache-tunable — the permuted
-        # overlay changes the trajectory, so it keys the tuning
-        # signature instead.
-        from p2p_gossipprotocol_tpu.tuning import resolve as \
-            tuning_resolve
-
+        # The rule itself lives in autostatics.py.
         W = n_msg_words(n_msgs)
         groups = cfg.roll_groups or None
-        block_perm = tuning_resolve.heuristic_block_perm(
+        block_perm = auto.heuristic_block_perm(
             cfg.block_perm, W, cfg.mode, n_slots, groups,
             min_words=AUTO_BLOCK_PERM_MIN_WORDS)
         if block_perm and groups is not None and groups <= 1 \
@@ -1693,7 +1689,7 @@ class AlignedSimulator:
         # — e.g. 129 messages: 258 msgs -> 9 words -> rowblk 448, but 5
         # words x 448 busts the 2048 budget).
         budget = MAX_WORDS_X_ROWBLK // (2 if cfg.fuse_update else 1)
-        rowblk = tuning_resolve.heuristic_rowblk(
+        rowblk = auto.heuristic_rowblk(
             n_msg_words(n_msgs), budget, MAX_CONFIG_ROWBLK)
         topo = build_aligned(seed=cfg.prng_seed, n=n, n_slots=n_slots,
                              degree_law=law,
@@ -1702,101 +1698,39 @@ class AlignedSimulator:
                              rowblk=rowblk,
                              roll_groups=cfg.roll_groups or None,
                              block_perm=block_perm)
-        # The tuning chokepoint (round 14, docs/ARCHITECTURE.md "The
-        # tuning seam"): every remaining -1 auto resolves HERE — a
-        # cache hit for this build's signature (topology shape, W,
-        # mode/fanout, backend, statics family) wins over the
-        # heuristic, a miss falls back to the exact open-coded rules
-        # (registered in tuning/resolve.py), and every substitution is
-        # a typed ``tuned`` ledger event.  Only the bitwise-identical
-        # statics are substitutable, so a tuned run equals the untuned
-        # run bit-for-bit (tests/test_tuning.py).  Explicit configured
-        # values are honored unconditionally.
-        interpret = interpret_for(jax.default_backend())
-        sig = tuning_resolve.signature(
-            rows=topo.rows, rowblk=topo.rowblk, n_slots=n_slots,
-            n_words=W, mode=cfg.mode, fanout=cfg.fanout,
-            backend="interpret" if interpret else "compiled",
-            n_shards=n_shards, block_perm=block_perm,
-            roll_groups=topo.roll_groups or 0,
-            fuse_update=int(bool(cfg.fuse_update)),
-            pull_window=int(pull_window),
-            hier=(hier_hosts, hier_devs))
-        tuned = tuning_resolve.resolve_statics(
-            sig,
-            requested={
-                "frontier_mode": cfg.frontier_mode,
-                "frontier_threshold": cfg.frontier_threshold,
-                "frontier_algo": cfg.frontier_algo,
-                "prefetch_depth": cfg.prefetch_depth,
-                "overlap_mode": cfg.overlap_mode,
-                "hier_mode": cfg.hier_mode,
-            },
-            heuristics={
-                "frontier_mode": int(tuning_resolve.heuristic_on(
-                    cfg.frontier_mode, interpret)),
-                "frontier_threshold":
-                    tuning_resolve.heuristic_frontier_threshold(
-                        cfg.frontier_threshold),
-                "frontier_algo": int(tuning_resolve.heuristic_on(
-                    cfg.frontier_algo, interpret)),
-                "prefetch_depth": tuning_resolve.heuristic_prefetch(
-                    cfg.prefetch_depth, interpret),
-                "overlap_mode": int(tuning_resolve.heuristic_on(
-                    cfg.overlap_mode, interpret)),
-                "hier_mode": int(tuning_resolve.heuristic_on(
-                    cfg.hier_mode, interpret)),
-            },
-            legal={
-                "frontier_mode": lambda v: v in (0, 1),
-                "frontier_threshold":
-                    lambda v: isinstance(v, (int, float))
-                    and 0.0 < v <= 1.0,
-                # bitwise either way; non-power-of-two tiers degrade
-                # structurally inside the exchange, so any cached 0/1
-                # is legal on any mesh
-                "frontier_algo": lambda v: v in (0, 1),
-                "prefetch_depth": lambda v: v in (0, 2),
-                # the self/remote split needs the block-perm overlay's
-                # block-granular locality and a push pass — the same
-                # rule the explicit-knob clamp above records
-                "overlap_mode": lambda v: v in (0, 1) and (
-                    v == 0 or (block_perm and cfg.mode != "pull")),
-                "hier_mode": lambda v: v in (0, 1),
-            })
-        st = tuned.statics
-        sim = cls(topo=topo, n_msgs=n_msgs, mode=cfg.mode,
-                  fanout=cfg.fanout,
-                  churn=ChurnConfig(rate=cfg.churn_rate),
-                  byzantine_fraction=byz,
-                  n_honest_msgs=n_honest,
-                  max_strikes=cfg.max_missed_pings,
-                  # probe cadence from the config's own intervals: one
-                  # liveness sweep per ping_interval of message rounds
-                  # (reference defaults 13 s / 5 s → every 3rd round).
-                  # Sub-second message intervals keep their real ratio
-                  # (ping=13, message=0.5 → every 26th round); only a
-                  # zero/negative denominator falls back to 1:1.
-                  liveness_every=max(1, round(
-                      cfg.get_ping_interval()
-                      / (cfg.get_message_interval()
-                         if cfg.get_message_interval() > 0
-                         else cfg.get_ping_interval()))),
-                  message_stagger=cfg.message_stagger,
-                  fuse_update=bool(cfg.fuse_update),
-                  pull_window=pull_window,
-                  faults=(plan if plan and plan.engine_active()
-                          else None),
-                  frontier_mode=int(st["frontier_mode"]),
-                  frontier_threshold=float(st["frontier_threshold"]),
-                  frontier_algo=int(st["frontier_algo"]),
-                  prefetch_depth=int(st["prefetch_depth"]),
-                  overlap_mode=int(st["overlap_mode"]),
-                  hier_hosts=hier_hosts, hier_devs=hier_devs,
-                  hier_mode=int(st["hier_mode"]),
-                  seed=cfg.prng_seed)
-        sim._tuning = tuned
-        return sim
+        # Every remaining -1 auto passes through as configured:
+        # __post_init__ resolves each schedule static once, by its rule
+        # in autostatics.py.
+        return cls(topo=topo, n_msgs=n_msgs, mode=cfg.mode,
+                   fanout=cfg.fanout,
+                   churn=ChurnConfig(rate=cfg.churn_rate),
+                   byzantine_fraction=byz,
+                   n_honest_msgs=n_honest,
+                   max_strikes=cfg.max_missed_pings,
+                   # probe cadence from the config's own intervals: one
+                   # liveness sweep per ping_interval of message rounds
+                   # (reference defaults 13 s / 5 s → every 3rd round).
+                   # Sub-second message intervals keep their real ratio
+                   # (ping=13, message=0.5 → every 26th round); only a
+                   # zero/negative denominator falls back to 1:1.
+                   liveness_every=max(1, round(
+                       cfg.get_ping_interval()
+                       / (cfg.get_message_interval()
+                          if cfg.get_message_interval() > 0
+                          else cfg.get_ping_interval()))),
+                   message_stagger=cfg.message_stagger,
+                   fuse_update=bool(cfg.fuse_update),
+                   pull_window=pull_window,
+                   faults=(plan if plan and plan.engine_active()
+                           else None),
+                   frontier_mode=cfg.frontier_mode,
+                   frontier_threshold=cfg.frontier_threshold,
+                   frontier_algo=cfg.frontier_algo,
+                   prefetch_depth=cfg.prefetch_depth,
+                   overlap_mode=cfg.overlap_mode,
+                   hier_hosts=hier_hosts, hier_devs=hier_devs,
+                   hier_mode=cfg.hier_mode,
+                   seed=cfg.prng_seed)
 
     # ------------------------------------------------------------------
     def traffic_model(self, frontier_fill: float | None = None,
@@ -2015,8 +1949,8 @@ class AlignedSimulator:
         return terms
 
     def hbm_bytes_per_round(self) -> int:
-        """Total of :meth:`traffic_model` — the single number bench.py
-        divides wall-clock by for ``achieved_gb_s``."""
+        """Total of :meth:`traffic_model` — the bytes the live
+        roofline divides wall-clock into for ``achieved_gb_s``."""
         return self.traffic_model()["total"]
 
     # ------------------------------------------------------------------

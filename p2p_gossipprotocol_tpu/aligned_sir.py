@@ -35,6 +35,7 @@ import jax
 import jax.numpy as jnp
 from flax import struct
 
+from p2p_gossipprotocol_tpu import autostatics as auto
 from p2p_gossipprotocol_tpu.aligned import (AlignedTopology,
                                             Y_REUSE_LEAK_PREFETCH,
                                             churn_rows, row_uniform)
@@ -107,21 +108,18 @@ class AlignedSIRSimulator:
                 f"aligned SIR on TPU needs >= 8 rows of {LANES} peers and "
                 f"an 8-aligned row block (this overlay: {self.topo.rows} "
                 f"rows, rowblk {self.topo.rowblk})")
-        # the -1 auto rules live in tuning/resolve.py — the one
-        # chokepoint every auto static resolves through (gossip-lint
-        # tuning-chokepoint)
-        from p2p_gossipprotocol_tpu.tuning import resolve as \
-            tuning_resolve
-
+        # the schedule statics resolve here, once, by their rules in
+        # autostatics.py (the public field takes the concrete value)
         if self.sir_fuse not in (-1, 0, 1):
             raise ValueError("sir_fuse must be -1 (auto), 0, or 1")
-        self._fuse = tuning_resolve.heuristic_sir_fuse(
-            self.sir_fuse, self.interpret,
-            self.topo.ytab is not None)
+        self.sir_fuse = int(auto.heuristic_sir_fuse(
+            self.sir_fuse, self.interpret, self.topo.ytab is not None))
+        self._fuse = bool(self.sir_fuse)
         if self.prefetch_depth not in (-1, 0, 2):
             raise ValueError("prefetch_depth must be -1 (auto), 0, or 2")
-        self._prefetch = tuning_resolve.heuristic_prefetch(
-            self.prefetch_depth, self.interpret)
+        self.prefetch_depth = auto.heuristic_prefetch(self.prefetch_depth,
+                                                      self.interpret)
+        self._prefetch = self.prefetch_depth
         self._scan_cache: dict = {}
 
     # ------------------------------------------------------------------
@@ -162,41 +160,11 @@ class AlignedSIRSimulator:
                 "sir_fuse 1 on a row-perm overlay -> fused count only "
                 "(the permute prep stays host-side without block_perm; "
                 "the pass itself still fuses, bitwise-identically)")
-        # The tuning chokepoint (round 14): the SIR engine's two -1
-        # autos resolve like the gossip engine's — cache hit for this
-        # signature wins, heuristic fallback otherwise, substitutions
-        # typed into the ledger.  Both are bitwise-identical statics
-        # (tests/test_sir_fuse.py, test_prefetch.py).
-        from p2p_gossipprotocol_tpu.tuning import resolve as \
-            tuning_resolve
-
-        interpret = interpret_for(jax.default_backend())
-        has_ytab = topo.ytab is not None
-        sig = tuning_resolve.signature(
-            rows=topo.rows, rowblk=topo.rowblk, n_slots=n_slots,
-            n_words=1, mode="sir", fanout=0,
-            backend="interpret" if interpret else "compiled",
-            n_shards=n_shards, block_perm=has_ytab,
-            roll_groups=topo.roll_groups or 0, fuse_update=0,
-            pull_window=0)
-        tuned = tuning_resolve.resolve_statics(
-            sig,
-            requested={"sir_fuse": cfg.sir_fuse,
-                       "prefetch_depth": cfg.prefetch_depth},
-            heuristics={
-                "sir_fuse": int(tuning_resolve.heuristic_sir_fuse(
-                    cfg.sir_fuse, interpret, has_ytab)),
-                "prefetch_depth": tuning_resolve.heuristic_prefetch(
-                    cfg.prefetch_depth, interpret)},
-            legal={"sir_fuse": lambda v: v in (0, 1),
-                   "prefetch_depth": lambda v: v in (0, 2)})
-        sim = cls(topo=topo, beta=cfg.sir_beta, gamma=cfg.sir_gamma,
-                  churn=ChurnConfig(rate=cfg.churn_rate),
-                  sir_fuse=int(tuned.statics["sir_fuse"]),
-                  prefetch_depth=int(tuned.statics["prefetch_depth"]),
-                  seed=cfg.prng_seed)
-        sim._tuning = tuned
-        return sim
+        return cls(topo=topo, beta=cfg.sir_beta, gamma=cfg.sir_gamma,
+                   churn=ChurnConfig(rate=cfg.churn_rate),
+                   sir_fuse=cfg.sir_fuse,
+                   prefetch_depth=cfg.prefetch_depth,
+                   seed=cfg.prng_seed)
 
     # ------------------------------------------------------------------
     def init_state(self) -> AlignedSIRState:

@@ -200,19 +200,19 @@ TELEMETRY_PKG = "p2p_gossipprotocol_tpu/telemetry/"
 TELEMETRY_BANNED_IMPORTS = ("jax",)
 
 # ---------------------------------------------------------------------
-# Rule: tuning-chokepoint (PR 12: the closed-loop autotuner routes
-# every -1-auto performance static through tuning/resolve.py — one
-# seam for cache substitution, one registry of heuristic fallbacks.
-# An auto-sentinel test (``X == -1`` / ``X < 0``) on a known auto
-# static anywhere else re-opens the open-coded-heuristic scatter the
-# resolver chokepoint deleted: the cache can no longer substitute
-# there, and the heuristic forks.  Validation guards — membership
-# tests like ``not in (-1, 0, 1)`` and raise-only branches — are not
-# resolution and stay exempt).
+# Rule: auto-statics (every -1-auto performance static is decided by
+# one heuristic_* rule in autostatics.py, called from one place per
+# engine.  An auto-sentinel test (``X == -1`` / ``X < 0``) on a known
+# auto static anywhere else is a second, drifting rule for it.
+# Validation guards — membership tests like ``not in (-1, 0, 1)`` and
+# raise-only branches — are not resolution and stay exempt).
 
-#: statics whose -1 spelling means "auto" — each resolves through
-#: tuning/resolve.py (the file defining ``resolve_statics``; its
-#: registered heuristic_* fallbacks included)
+#: the one module allowed to test the auto sentinels (package-relative,
+#: so the fixture trees can mimic it)
+AUTO_RULES_MODULE = "p2p_gossipprotocol_tpu/autostatics.py"
+
+#: statics whose -1 spelling means "auto" — each decided by its
+#: heuristic_* rule in AUTO_RULES_MODULE
 AUTO_STATICS = {
     "block_perm", "frontier_mode", "frontier_threshold",
     "frontier_algo", "prefetch_depth", "overlap_mode", "hier_mode",

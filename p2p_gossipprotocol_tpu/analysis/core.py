@@ -23,9 +23,9 @@ from pathlib import Path
 _SKIP_DIRS = {"__pycache__", ".git", "native", "peer_network",
               "fixtures", ".claude"}
 
-#: analysis scope relative to the repo root: the package itself, the
-#: benchmark drivers (write-discipline territory), and bench.py
-_SCOPE = ("p2p_gossipprotocol_tpu", "benchmarks", "bench.py")
+#: analysis scope relative to the repo root: the package itself and
+#: the benchmark scripts (write-discipline territory)
+_SCOPE = ("p2p_gossipprotocol_tpu", "benchmarks")
 
 
 @dataclass(frozen=True)
@@ -71,11 +71,10 @@ class Tree:
 
     def package_sources(self) -> list[Source]:
         """Sources inside the python package (engine/runtime code) —
-        the scope of the semantic rules; benchmarks/bench.py join only
-        the write-discipline sweep."""
+        the scope of the semantic rules; benchmarks/ joins only the
+        write-discipline sweep."""
         return [s for s in self.sources
-                if s.rel.split("/")[0] not in ("benchmarks",)
-                and s.rel != "bench.py"]
+                if s.rel.split("/")[0] != "benchmarks"]
 
     def defining(self, symbol: str, kind=(ast.FunctionDef, ast.ClassDef)
                  ) -> list[tuple[Source, ast.AST]]:

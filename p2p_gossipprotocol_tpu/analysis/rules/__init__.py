@@ -4,8 +4,6 @@ One module per contract family; the catalog with each rule's origin
 PR/doc lives in docs/STATIC_ANALYSIS.md.
 """
 
-from p2p_gossipprotocol_tpu.analysis.rules import (clamps,  # noqa: F401
-                                                   configsurface,
-                                                   fingerprint, imports,
-                                                   locks, tracing,
-                                                   tuningseam, writes)
+from p2p_gossipprotocol_tpu.analysis.rules import (  # noqa: F401
+    autostatics, clamps, configsurface, fingerprint, imports, locks,
+    tracing, writes)

@@ -1,13 +1,12 @@
-"""Rule ``tuning-chokepoint``: -1-auto statics resolve in one place.
+"""Rule ``auto-statics``: each -1-auto static has one rule, in one module.
 
-PR 12 closed the tuning loop: every ``-1``-auto performance static
-(``contracts.AUTO_STATICS`` — frontier_mode, prefetch_depth,
-block_perm, serve_chunk, ...) resolves through ``tuning/resolve.py``,
-where a tuning-cache hit can substitute a measured-best value and the
-open-coded heuristics live as registered fallbacks.  An auto-sentinel
-test on one of those statics anywhere else — ``X == -1`` or ``X < 0``
-— is the seam rotting: a fresh open-coded resolution the cache can
-never reach and the heuristic registry no longer owns.
+Every ``-1``-auto performance static (``contracts.AUTO_STATICS`` —
+frontier_mode, prefetch_depth, block_perm, serve_chunk, ...) is
+decided by one ``heuristic_*`` function of ``autostatics.py``
+(``contracts.AUTO_RULES_MODULE``), called from one place per engine.
+An auto-sentinel test on one of those statics anywhere else —
+``X == -1`` or ``X < 0`` — is a second rule for the same static: the
+two forks drift, and the engines stop agreeing on what auto means.
 
 Exempt, because they are validation rather than resolution:
 
@@ -15,17 +14,17 @@ Exempt, because they are validation rather than resolution:
   shape, never matched;
 * comparisons inside an ``if`` whose body only raises (the
   fail-fast-on-bad-value idiom);
-* the resolver module itself — located by its defining symbol
-  ``resolve_statics`` (fixtures mimic the layout), so the registered
-  ``heuristic_*`` fallbacks that legitimately test the sentinel are
-  where the contract says they belong.
+* the rule module itself, located by its package-relative path
+  (fixtures mimic the layout), where the ``heuristic_*`` functions
+  legitimately test the sentinel.
 """
 
 from __future__ import annotations
 
 import ast
 
-from p2p_gossipprotocol_tpu.analysis.contracts import AUTO_STATICS
+from p2p_gossipprotocol_tpu.analysis.contracts import (AUTO_RULES_MODULE,
+                                                       AUTO_STATICS)
 from p2p_gossipprotocol_tpu.analysis.core import Finding, rule
 
 _FUNC = (ast.FunctionDef, ast.AsyncFunctionDef)
@@ -95,20 +94,13 @@ def _raise_only_tests(tree: ast.Module) -> set[int]:
     return out
 
 
-def _resolver_files(tree) -> set[str]:
-    return {src.rel
-            for src, _fn in tree.defining("resolve_statics",
-                                          kind=_FUNC)}
-
-
-@rule("tuning-chokepoint",
-      "-1-auto performance statics resolve through tuning/resolve.py "
-      "(its registered heuristic fallbacks included), nowhere else")
+@rule("auto-statics",
+      "-1-auto performance statics are decided only by the heuristic_* "
+      "rules of autostatics.py, nowhere else")
 def check(tree):
     findings = []
-    resolver = _resolver_files(tree)
     for src in tree.package_sources():
-        if src.rel in resolver:
+        if src.rel == AUTO_RULES_MODULE:
             continue
         exempt = _raise_only_tests(src.tree)
         for node in ast.walk(src.tree):
@@ -118,10 +110,8 @@ def check(tree):
             if name is None:
                 continue
             findings.append(Finding(
-                "tuning-chokepoint", src.rel, node.lineno,
+                "auto-statics", src.rel, node.lineno,
                 f"auto sentinel of {name!r} resolved outside "
-                "tuning/resolve.py — route the -1 decision through "
-                "tuning.resolve (resolve_statics for cache-eligible "
-                "statics, a registered heuristic_* fallback "
-                "otherwise) so the autotuner keeps one seam"))
+                "autostatics.py — call its heuristic_* rule (or add "
+                "one there) so each static keeps one rule"))
     return findings

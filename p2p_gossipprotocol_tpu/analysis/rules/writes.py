@@ -13,8 +13,8 @@ is only legal
   tmp+rename idiom (heartbeats, flight dumps).
 
 Everything else writes an artifact a crash can tear — flagged.  Scope
-includes ``benchmarks/`` and ``bench.py``: their runs write the
-results tables the docs quote.
+includes ``benchmarks/``: its runs write the results tables the docs
+quote.
 """
 
 from __future__ import annotations

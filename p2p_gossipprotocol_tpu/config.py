@@ -164,8 +164,8 @@ _SIM_INT_KEYS = {
     "sir_fuse": "sir_fuse",
     # realgraph engine: pack-width cap (power of two) for the degree-
     # bucketed SpMV blocks, and the gather/scatter delivery choice —
-    # both -1 = AUTO via the tuning chokepoint; both bitwise-safe
-    # (they pick HOW the same boolean OR executes).
+    # both -1 = AUTO (autostatics.py); both bitwise-safe (they pick
+    # HOW the same boolean OR executes).
     "realgraph_pack_width": "realgraph_pack_width",
     "realgraph_scatter": "realgraph_scatter",
     "rounds": "rounds",
@@ -407,10 +407,9 @@ class NetworkConfig:
         self.graph_file = ""             # edge list / artifact dir
         self.realgraph_format = "auto"   # auto | ws | csv | snap
         # SpMV pack width cap (power of two) and gather/scatter
-        # delivery choice — both -1 = AUTO via the tuning chokepoint
-        # (cache hit wins, else the resolver heuristics; both pick HOW
-        # the same boolean OR is computed, so they are bitwise-safe
-        # and therefore tunable — tuning/resolve.py).
+        # delivery choice — both -1 = AUTO, each by its rule in
+        # autostatics.py (both pick HOW the same boolean OR is
+        # computed, so they are bitwise-safe).
         self.realgraph_pack_width = -1
         self.realgraph_scatter = -1
         self.n_peers = 0
@@ -429,8 +428,8 @@ class NetworkConfig:
         # measured -43% ms/round at 1M x 256 and a wash at W=1, so
         # from_config picks it at wide message widths and keeps the
         # row-perm family narrow.  fuse_update stays opt-in (measured
-        # negative pre-census; re-A/B'd with the in-kernel census by
-        # benchmarks/measure_round6.py).
+        # negative pre-census; the A/B with the in-kernel census is
+        # not measured on the chip — ROADMAP Queue 1 item 2).
         self.roll_groups = 4           # aligned engine; 0 = per-slot rolls
         # aligned engine: -1 = AUTO (the default — from_config selects
         # the fused block-perm overlay whenever it is measured-best and
@@ -452,11 +451,10 @@ class NetworkConfig:
         # gather must be well under the dense plane transfer to pay for
         # its bitmap+scatter overhead; 2*K words of idx+val vs L words
         # dense -> a 1/64 cap bounds the sparse gather at ~3% of dense).
-        # -1 (the default) = AUTO: the tuning chokepoint resolves it —
-        # a tuning-cache hit for this shape wins, else the 1/64 rule
-        # (tuning/resolve.py; any explicit value in (0, 1] is honored).
-        # The capacity is bitwise-safe at any value (sparse == dense by
-        # seen-set monotonicity), which is what makes it tunable.
+        # -1 (the default) = AUTO: the 1/64 rule (autostatics.py; any
+        # explicit value in (0, 1] is honored).  The capacity is
+        # bitwise-safe at any value (sparse == dense by seen-set
+        # monotonicity).
         self.frontier_threshold = -1.0
         # HOW the sparse regime moves its delta tables cross-chip —
         # -1 = AUTO (the recursive-halving sparse allreduce on the
@@ -517,11 +515,10 @@ class NetworkConfig:
         self.serve_slots = 8             # slots per resident bucket
         self.serve_queue_max = 64        # bounded admission queue
         self.serve_max_buckets = 4       # resident signature buckets
-        # rounds per admission boundary; -1 (default) = AUTO via the
-        # tuning chokepoint (cache hit wins, else the classic 8 —
-        # tuning/resolve.py; explicit values >= 1 honored).  Chunking
-        # only paces admission: every served scenario is bitwise its
-        # solo run at any chunk (tests/test_serve.py), so it is tunable.
+        # rounds per admission boundary; -1 (default) = AUTO, the
+        # classic 8 (autostatics.py; explicit values >= 1 honored).
+        # Chunking only paces admission: every served scenario is
+        # bitwise its solo run at any chunk (tests/test_serve.py).
         self.serve_chunk = -1
         self.serve_rounds = 0            # per-scenario cap; 0 = rounds/64
         self.serve_target = 0.99         # retirement coverage target
@@ -709,7 +706,7 @@ class NetworkConfig:
                 "(the slot-width band the autoscaler moves within)")
         if self.serve_chunk != -1 and self.serve_chunk < 1:
             raise ConfigError(
-                "serve_chunk must be >= 1, or -1 (auto-tuned)")
+                "serve_chunk must be >= 1, or -1 (auto)")
         if not (0.0 < self.serve_target < 1.0):
             raise ConfigError(
                 "serve_target must be in (0, 1) — a served scenario "
@@ -794,8 +791,7 @@ class NetworkConfig:
         if self.frontier_threshold != -1.0 and \
                 not (0.0 < self.frontier_threshold <= 1.0):
             raise ConfigError(
-                "frontier_threshold must be in (0, 1], or -1 "
-                "(auto-tuned)")
+                "frontier_threshold must be in (0, 1], or -1 (auto)")
         if self.frontier_algo not in (-1, 0, 1):
             raise ConfigError(
                 "frontier_algo must be -1 (auto), 0 (gather), or 1 "

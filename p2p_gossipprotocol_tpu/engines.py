@@ -236,14 +236,11 @@ def _build_simulator(cfg, *, n_peers, mesh_devices, msg_shards, clamps):
                 from p2p_gossipprotocol_tpu.parallel import (
                     AlignedShardedSIRSimulator, make_mesh)
 
-                tuned = getattr(sim, "_tuning", None)
                 sim = AlignedShardedSIRSimulator(
                     mesh=make_mesh(n_shards), topo=sim.topo,
                     beta=sim.beta, gamma=sim.gamma, n_seeds=sim.n_seeds,
                     churn=sim.churn, sir_fuse=sim.sir_fuse,
                     prefetch_depth=sim.prefetch_depth, seed=sim.seed)
-                if tuned is not None:
-                    sim._tuning = tuned
                 return sim, f"aligned-sharded-{n_shards}"
             return sim, "aligned"
         if n_shards > 1:
@@ -266,12 +263,8 @@ def _build_simulator(cfg, *, n_peers, mesh_devices, msg_shards, clamps):
         if n_shards <= 1:
             return sim, "aligned"
         # Same scenario over the mesh: from_config resolved every knob
-        # (the tuning chokepoint included — the resolved statics below
-        # are already cache-substituted where a signature hit, and the
-        # provenance record rides onto the wrapper so bench/fleet/serve
-        # rows and the live roofline read one `tuned_from`);
-        # lift them onto the drop-in multi-chip simulator.
-        tuned = getattr(sim, "_tuning", None)
+        # (the schedule statics already concrete); lift them onto the
+        # drop-in multi-chip simulator.
         lifted = dict(
             topo=sim.topo, n_msgs=sim.n_msgs, mode=sim.mode,
             fanout=sim.fanout, churn=sim.churn,
@@ -305,8 +298,6 @@ def _build_simulator(cfg, *, n_peers, mesh_devices, msg_shards, clamps):
             sim = Aligned2DShardedSimulator(
                 mesh=make_mesh_2d(msg_shards, peer_shards, n_hosts=hh),
                 **lifted)
-            if tuned is not None:
-                sim._tuning = tuned
             name = f"aligned-2d-{msg_shards}x{peer_shards}"
             return sim, (name + f"-hier{hh}" if hh else name)
         from p2p_gossipprotocol_tpu.parallel import (
@@ -319,13 +310,9 @@ def _build_simulator(cfg, *, n_peers, mesh_devices, msg_shards, clamps):
         if sim.hier_hosts > 1:
             mesh = make_hier_mesh(sim.hier_hosts, sim.hier_devs)
             sim = AlignedShardedSimulator(mesh=mesh, **lifted)
-            if tuned is not None:
-                sim._tuning = tuned
             return (sim, f"aligned-hier-{sim.n_hosts}x"
                     f"{sim.devs_per_host}")
         sim = AlignedShardedSimulator(mesh=make_mesh(n_shards), **lifted)
-        if tuned is not None:
-            sim._tuning = tuned
         return sim, f"aligned-sharded-{n_shards}"
 
     from p2p_gossipprotocol_tpu.sim import Simulator

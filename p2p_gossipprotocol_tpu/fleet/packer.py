@@ -24,16 +24,10 @@ is why the spec layer pads peer counts up to powers of two by default —
 heterogeneous sweeps then collapse into few buckets instead of
 singletons.
 
-The round-14 tuning cache (tuning/resolve.signature) keys on this
-signature's SHAPE — topology shape, message width, mode/fanout,
-backend, statics family — but deliberately coarser: per-scenario
-arrays (seeds, churn schedules, fault plans) change what a round
-computes, never which schedule moves the same blocks fastest, so
-scenarios in different buckets share one tuning entry.  Because the
-resolved statics below (``_prefetch``/``_overlap``/``_frontier_skip``)
-are read POST-resolution, a cache-substituted build routes into its
-own bucket automatically — tuned and untuned scenarios never share a
-compiled program unless their schedules really are identical.
+The schedule statics below (``_prefetch``/``_overlap``/
+``_frontier_skip``) are read POST-resolution, so two scenarios share a
+compiled program only when their resolved schedules really are
+identical.
 
 The serving plane's SLOT COUNT is deliberately absent: a bucket's
 width is the leading batch axis the engine vmaps over, not a static of

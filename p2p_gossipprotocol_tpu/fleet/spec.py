@@ -134,17 +134,6 @@ class ScenarioSpec:
             out["n_peers_requested"] = self.n_peers_requested
         if self.clamps:
             out["clamped"] = list(self.clamps)
-        # tuning provenance (round 14): which seam resolved this
-        # scenario's auto statics — and, on a cache hit that changed
-        # anything, exactly what was substituted.  Values are bitwise-
-        # safe by the tuner's contract, so this is provenance, not a
-        # different scenario.
-        tuned = getattr(self.sim, "_tuning", None)
-        if tuned is not None:
-            out["tuned_from"] = tuned.source
-            if tuned.substituted:
-                out["tuned"] = {k: tuned.statics[k]
-                                for k in tuned.substituted}
         return out
 
 

@@ -20,11 +20,11 @@ The gather path's one obligation is STATIC ``dst``: the packed tables
 pre-resolve each vertex's in-edge ids, so they stay valid only while
 ``strike_and_rewire`` cannot rewrite ``dst`` (it mutates ``dst`` only
 when ``rewire=True`` AND peers can die — churn or scheduled
-crash/recovery).  ``realgraph_scatter`` resolves that choice through
-the tuning chokepoint: auto picks the gather whenever ``dst`` is
+crash/recovery).  ``realgraph_scatter`` resolves that choice by its
+rule in autostatics.py: auto picks the gather whenever ``dst`` is
 static and falls back to the inherited edge scatter otherwise (loudly,
 through the clamp ledger, when a gather was forced on a dst-mutating
-build).  Both paths are bitwise-identical, so the knob is TUNABLE.
+build).  Both paths are bitwise-identical.
 
 Frontier-compaction regime + traffic model: the PR 5/14/16 frontier
 machinery (``aligned.frontier_capacity`` / ``halving_steps`` /
@@ -50,6 +50,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from p2p_gossipprotocol_tpu import autostatics as auto
 from p2p_gossipprotocol_tpu import faults as faults_lib
 from p2p_gossipprotocol_tpu import graph as graph_lib
 from p2p_gossipprotocol_tpu.fleet.engine import FleetBucket
@@ -61,7 +62,6 @@ from p2p_gossipprotocol_tpu.realgraph.pack import (PACK_WIDTH_DEFAULT,
                                                    shard_partition)
 from p2p_gossipprotocol_tpu.sim import Simulator
 from p2p_gossipprotocol_tpu.transport.jax_transport import JaxTransport
-from p2p_gossipprotocol_tpu.tuning import resolve as tuning_resolve
 
 #: the edges-family metric dtypes, exactly as the solo scan emits them
 #: (sim.Simulator.step: coverage is the one float; every count is an
@@ -124,8 +124,8 @@ class PackedTransport(JaxTransport):
     ceil(W/8) bytes instead of W bool bytes and the row OR is a
     log2(width) halving over uint8 words — byte-level OR of exact bit
     patterns, so the unpacked result is the bool computation bit for
-    bit (the round-19 A/B's 1M-edge CPU row measures the packed gather
-    ~2x the bool one; benchmarks/measure_round19.py).
+    bit (the round-19 A/B's 1M-edge CPU row measured the packed gather
+    ~2x the bool one).
 
     With ``use_gather=False`` the transport IS its base class — the
     scatter fallback for dst-mutating builds costs zero new code."""
@@ -283,8 +283,8 @@ class RealGraphSimulator(Simulator):
     means exactly what it means on :class:`sim.Simulator`.
 
     ``pack_width`` / ``scatter`` are the ``realgraph_pack_width`` /
-    ``realgraph_scatter`` config statics (-1 = auto through the tuning
-    chokepoint; both bitwise-safe, so both TUNABLE).  ``graph_fp`` is
+    ``realgraph_scatter`` config statics (-1 = auto by their rules in
+    autostatics.py; both bitwise-safe).  ``graph_fp`` is
     the graph's array identity — the artifact manifest fingerprint for
     file-loaded graphs, a host CRC otherwise — and enters the bucket
     signature: slots sharing a bucket share the gather tables, so they
@@ -301,46 +301,17 @@ class RealGraphSimulator(Simulator):
             self.graph_fp = host_graph_fingerprint(self.topo)
         dst_static = dst_is_static(self.rewire, self.churn, self.faults)
         self._dst_static = dst_static
-        sig = tuning_resolve.realgraph_signature(
-            n_peers=self.topo.n_peers,
-            edge_capacity=self.topo.edge_capacity,
-            mode=self.mode, fanout=self.fanout,
-            backend="compiled")
-        self._tuning = tuning_resolve.resolve_statics(
-            sig,
-            requested={
-                "realgraph_pack_width": int(self.pack_width),
-                "realgraph_scatter": int(self.scatter),
-            },
-            heuristics={
-                "realgraph_pack_width":
-                    tuning_resolve.heuristic_realgraph_pack_width(
-                        self.pack_width),
-                "realgraph_scatter":
-                    tuning_resolve.heuristic_realgraph_scatter(
-                        self.scatter, dst_static),
-            },
-            legal={
-                "realgraph_pack_width":
-                    lambda v: isinstance(v, int)
-                    and 1 <= v <= 4096 and not (v & (v - 1)),
-                # gather is only legal while dst stays static; any
-                # cached scatter=1 is legal anywhere (it IS the base
-                # engine)
-                "realgraph_scatter":
-                    lambda v: v in (0, 1) and (v == 1 or dst_static),
-            })
-        width = self._tuning.statics["realgraph_pack_width"]
-        scat = self._tuning.statics["realgraph_scatter"]
+        width = auto.heuristic_realgraph_pack_width(self.pack_width)
         if not (isinstance(width, int) and 1 <= width <= 4096
                 and not (width & (width - 1))):
             raise ValueError(
                 f"realgraph_pack_width must be a power of two in "
                 f"[1, 4096], got {width}")
-        if scat not in (0, 1):
+        if self.scatter not in (-1, 0, 1):
             raise ValueError(
                 f"realgraph_scatter must be -1 (auto), 0 (gather) or "
-                f"1 (scatter), got {scat}")
+                f"1 (scatter), got {self.scatter}")
+        scat = auto.heuristic_realgraph_scatter(self.scatter, dst_static)
         if scat == 0 and not dst_static:
             # an explicit gather on a dst-mutating build: the tables
             # would go stale on the first rewire — degrade loudly
@@ -393,7 +364,7 @@ class RealGraphSimulator(Simulator):
         from p2p_gossipprotocol_tpu.aligned import (frontier_capacity,
                                                     halving_steps)
 
-        thr = tuning_resolve.heuristic_frontier_threshold(threshold)
+        thr = auto.heuristic_frontier_threshold(threshold)
         f = np.asarray(frontier_size, np.int64)
         shard_width = -(-self.topo.n_peers // max(1, n_shards))
         cap = frontier_capacity(thr, shard_width)
@@ -403,7 +374,7 @@ class RealGraphSimulator(Simulator):
         for i, w in enumerate(worst.tolist()):
             prev = (w <= cap) if prev else (w <= cap // 2)
             sparse[i] = prev
-        use_halving = tuning_resolve.heuristic_on(algo, False)
+        use_halving = auto.heuristic_on(algo, False)
         return {
             "capacity": int(cap),
             "threshold": float(thr),

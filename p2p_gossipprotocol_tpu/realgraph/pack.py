@@ -37,11 +37,11 @@ import numpy as np
 PACK_VERSION = "rgpack-v1"
 
 #: default cap on a block's padded width (the realgraph_pack_width
-#: auto value — ONE spelling, owned by the resolver chokepoint so the
-#: tuner and the engine cannot drift): wide enough that >99% of a
-#: power-law graph's vertices fit one row, narrow enough that one hub
-#: cannot force a megabyte-wide lane on everyone
-from p2p_gossipprotocol_tpu.tuning.resolve import \
+#: auto value — ONE spelling, owned by autostatics.py with its rule):
+#: wide enough that >99% of a power-law graph's vertices fit one row,
+#: narrow enough that one hub cannot force a megabyte-wide lane on
+#: everyone
+from p2p_gossipprotocol_tpu.autostatics import \
     REALGRAPH_PACK_WIDTH_DEFAULT as PACK_WIDTH_DEFAULT  # noqa: E402
 
 
@@ -68,7 +68,7 @@ class PackedBlock:
 @dataclass(frozen=True)
 class PackedGraph:
     """The packed CSR: blocks in ascending width order + the static
-    signature tuple the bucket/tuning signatures embed."""
+    signature tuple the bucket signature embeds."""
 
     blocks: tuple
     width_cap: int
@@ -141,7 +141,7 @@ def pack_signature(packed: PackedGraph) -> tuple:
     ``(width, nrows)`` histogram.  Everything the compiled SpMV's
     shapes depend on and nothing data-dependent beyond them — the
     compile-reuse key, embedded verbatim in the fleet bucket
-    signature and the tuning signature family."""
+    signature."""
     return packed.signature
 
 

@@ -53,10 +53,7 @@ applying the SAME discipline one level up:
   reason ``SHED_OVER_BUDGET``, so one tenant's burst degrades THAT
   tenant's traffic, not the victim's p50.
 
-docs/ROBUSTNESS.md "The federation" has the failure taxonomy;
-benchmarks/measure_round18.py is the chaos + fairness harness
-(whole-fleet SIGKILL → detect_s, mttr_s, lost=0, dup=0, parity_ok;
-burst tenant vs victim p50).
+docs/ROBUSTNESS.md "The federation" has the failure taxonomy.
 """
 
 from __future__ import annotations
@@ -857,7 +854,7 @@ class FederationService:
 
     def kill_fleet(self, name: str) -> list[int]:
         """CHAOS: SIGKILL every process of fleet ``name`` at once (the
-        whole-fleet-loss injection measure_round18 drives).  Detection
+        whole-fleet-loss injection).  Detection
         and recovery are the health loop's job — this only murders."""
         with self._lock:
             h = next(x for x in self._fleets if x.name == name)

@@ -56,9 +56,7 @@ the traffic-bearing tier:
   would have produced — zero lost, zero duplicated, bit-for-bit.
 
 docs/ROBUSTNESS.md "The serving fleet" has the failure taxonomy and
-the re-admission semantics; benchmarks/measure_round15.py is the chaos
-harness (SIGKILL/SIGSTOP under Poisson load → detect_s, mttr_s,
-lost=0, dup=0, parity_ok).
+the re-admission semantics.
 """
 
 from __future__ import annotations

@@ -53,6 +53,7 @@ import jax
 import jax.numpy as jnp
 
 from p2p_gossipprotocol_tpu import telemetry
+from p2p_gossipprotocol_tpu.autostatics import heuristic_serve_chunk
 from p2p_gossipprotocol_tpu.fleet.engine import (METRIC_KEYS, FleetBucket,
                                                  bucket_class_for)
 from p2p_gossipprotocol_tpu.serve.scheduler import (DONE, FAILED, QUEUED,
@@ -373,18 +374,12 @@ class GossipService:
         self.max_buckets = max_buckets or cfg.serve_max_buckets
         self.target = cfg.serve_target if target is None else target
         self.rounds = rounds or cfg.serve_rounds or cfg.rounds or 64
-        # admission cadence through the tuning chokepoint: -1 (the
-        # config default) = auto — a tuning-cache hit for this loop
-        # shape wins, else the classic 8; explicit values honored.
+        # admission cadence: -1 (the config default) = auto, the rule
+        # in autostatics.py (the classic 8); explicit values honored.
         # Chunking only paces admission boundaries — every served
         # scenario is bitwise its solo run at any chunk.
-        from p2p_gossipprotocol_tpu.tuning import resolve as \
-            tuning_resolve
-
-        self.chunk, self.chunk_source = \
-            tuning_resolve.resolve_serve_chunk(
-                cfg.serve_chunk if chunk is None else int(chunk),
-                slots=self.slots, rounds=self.rounds)
+        self.chunk = heuristic_serve_chunk(
+            cfg.serve_chunk if chunk is None else int(chunk))
         self.checkpoint_dir = checkpoint_dir or cfg.checkpoint_dir or None
         self.results_path = results_path or cfg.serve_results or None
         # telemetry-driven autoscaling (round 17): the control loop

@@ -1,16 +1,15 @@
 """Live roofline: per-chunk reconciliation of census vs traffic model.
 
-``bench.py`` computes ``roofline_frac`` once, offline, from a finished
-run's wall clock.  A resident server and a supervised long run need the
-same number LIVE: after every chunk, this tracker folds the chunk's
+A resident server and a supervised long run need ``roofline_frac``
+LIVE: after every chunk, this tracker folds the chunk's
 already-materialized in-kernel census (coverage, deliveries, frontier
 size — metrics the engines emit anyway, so tracking adds zero device
 work) and the engine's analytic per-term byte accounting
 (``traffic_model()``, the Sparse-Allreduce-style comms-cost model) into
 cumulative counters and two headline gauges:
 
-* ``roofline_frac`` — achieved fraction of the HBM roof, the bench
-  definition exactly: model bytes moved over measured wall, divided by
+* ``roofline_frac`` — achieved fraction of the HBM roof: model bytes
+  moved over measured wall, divided by
   the device's published HBM peak (:data:`HBM_PEAK_GB_S`).  A CPU run
   has no roof and publishes no fraction;
 * ``model_drift_frac`` — modeled-vs-achieved drift: the dense model
@@ -50,32 +49,10 @@ class RooflineTracker:
     """Per-chunk counter aggregation + live roofline for one run (see
     module docstring).  Construct via :meth:`for_sim`, which returns
     None for engines without a traffic model (the edges family) —
-    callers then skip tracking entirely.
-
-    **Online regime adjustment** (round 14, the closed tuning loop):
-    the drift gauge doubles as the retune trigger.  When
-    ``model_drift_frac`` exceeds :data:`DRIFT_RETUNE_THRESHOLD`
-    (0.25) for :data:`DRIFT_RETUNE_SUSTAIN` CONSECUTIVE chunks, the
-    run's regime has departed the one its cached tuning was timed
-    under — the tracker emits one typed ``retune_requested`` ledger
-    event and marks the run's tuning signature STALE in the cache
-    (tuning/cache.mark_stale; lookups then fall back to the heuristics
-    until the next tune sweep rewrites the entry).
-    Hysteresis is sustained-N with reset-below: any below-threshold
-    chunk zeroes the streak AND re-arms the trigger, so a noisy gauge
-    oscillating around 0.25 never fires (it can't sustain N) and a
-    genuinely drifted run fires exactly once per excursion
-    (tests/test_tuning.py pins both)."""
-
-    #: drift gauge level above which a sustained excursion requests a
-    #: retune (the ISSUE-12 contract: > 0.25 sustained over N chunks)
-    DRIFT_RETUNE_THRESHOLD = 0.25
-    #: consecutive over-threshold chunks before the request fires
-    DRIFT_RETUNE_SUSTAIN = 4
+    callers then skip tracking entirely."""
 
     def __init__(self, model_fn, dense_bytes_round: float,
-                 n_peers: int, tuning_sig: tuple | None = None,
-                 roof_gb_s: float | None = None):
+                 n_peers: int, roof_gb_s: float | None = None):
         self._model_fn = model_fn           # frontier_fill -> terms dict
         self.dense_bytes_round = float(dense_bytes_round)
         self.n_peers = max(1, int(n_peers))
@@ -84,12 +61,6 @@ class RooflineTracker:
         self.wall_s = 0.0
         self.model_bytes = 0.0              # dense accounting
         self.census_bytes = 0.0             # fill-informed accounting
-        #: tuning-cache key of the run's simulator (None = unknown —
-        #: drift still emits retune_requested, just can't mark a cache
-        #: entry stale)
-        self.tuning_sig = tuning_sig
-        self._drift_over = 0                # consecutive chunks > thr
-        self._retune_armed = True           # re-arms below threshold
 
     # ------------------------------------------------------------------
     @classmethod
@@ -113,17 +84,7 @@ class RooflineTracker:
             return None    # itself is tracked by spans alone
         topo = getattr(inner, "topo", None)
         n_peers = int(getattr(topo, "n_peers", 0) or 1)
-        # the run's tuning-cache key, for the drift-retune loop
-        # (tuning/resolve is stdlib-only — plain attribute reads, no
-        # jax, so the telemetry contract holds)
-        try:
-            from p2p_gossipprotocol_tpu.tuning.resolve import \
-                signature_for_sim
-
-            sig = signature_for_sim(sim)
-        except Exception:  # noqa: BLE001 — unknown sim shape
-            sig = None
-        return cls(model_fn, dense, n_peers, tuning_sig=sig,
+        return cls(model_fn, dense, n_peers,
                    roof_gb_s=hbm_peak_gb_s(device.platform,
                                            device.device_kind))
 
@@ -191,37 +152,3 @@ class RooflineTracker:
         if self.model_bytes > 0:
             drift = 1.0 - self.census_bytes / self.model_bytes
             rec.gauge_set("model_drift_frac", round(drift, 6))
-            self._check_drift(drift, rec)
-
-    # ------------------------------------------------------------------
-    def _check_drift(self, drift: float, rec) -> None:
-        """Drift-retune hysteresis (class docstring): sustained-N with
-        reset-below-and-re-arm, so the trigger fires at most once per
-        excursion and never on a gauge oscillating around the
-        threshold."""
-        if drift <= self.DRIFT_RETUNE_THRESHOLD:
-            self._drift_over = 0
-            self._retune_armed = True
-            return
-        self._drift_over += 1
-        if not self._retune_armed \
-                or self._drift_over < self.DRIFT_RETUNE_SUSTAIN:
-            return
-        self._retune_armed = False
-        stale_marked = False
-        if self.tuning_sig is not None:
-            # best-effort, never raises (tuning/cache contract): the
-            # stale mark makes lookups fall back to the heuristics
-            # until the next offline sweep rewrites the entry
-            from p2p_gossipprotocol_tpu.tuning.cache import (mark_stale,
-                                                             sig_key)
-
-            stale_marked = mark_stale(self.tuning_sig)
-            sig = sig_key(self.tuning_sig)
-        else:
-            sig = None
-        rec.event("retune_requested", drift=round(drift, 6),
-                  sustained_chunks=self._drift_over,
-                  threshold=self.DRIFT_RETUNE_THRESHOLD,
-                  signature=sig, stale_marked=stale_marked)
-        rec.counter_add("retune_requested_total")

@@ -14,13 +14,6 @@ if "xla_force_host_platform_device_count" not in flags:
         flags + " --xla_force_host_platform_device_count=8"
     ).strip()
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
-# The suite must be deterministic regardless of what the COMMITTED
-# tuning cache holds (benchmarks/results/tuning_cache.json — the
-# round-14 autotuner's artifact, which would otherwise substitute
-# statics for any sim whose signature matches a tuned shape).  Tuning
-# is bitwise-safe by contract, but tests pin schedules and cadences;
-# test_tuning points sims at its own tmp caches explicitly.
-os.environ.setdefault("GOSSIP_TUNING_CACHE", "off")
 # JAX's persistent compile cache stays off for the suite and every
 # subprocess that inherits this environment, even where the shell sets
 # JAX_COMPILATION_CACHE_DIR: a test must never write one (and the TPU
@@ -113,15 +106,13 @@ def _socket_suite_timeout(request):
     mod = getattr(request.module, "__name__", "")
     guarded = "socket" in mod or "preemption" in mod \
         or "supervisor" in mod or "serve" in mod \
-        or "telemetry" in mod or "tuning" in mod \
-        or "federation" in mod
+        or "telemetry" in mod or "federation" in mod
     if not guarded or not hasattr(signal, "SIGALRM"):
         yield
         return
     budget = (SUPERVISOR_TEST_TIMEOUT_S
               if "supervisor" in mod or "serve" in mod
-              or "telemetry" in mod or "tuning" in mod
-              or "federation" in mod
+              or "telemetry" in mod or "federation" in mod
               else SOCKET_TEST_TIMEOUT_S)
 
     def _fire(signum, frame):

@@ -1,8 +1,8 @@
-"""Clean fixture: auto statics delegate to the resolver module's
-registered heuristics; validation guards (membership tests and
+"""Clean fixture: auto statics delegate to the rule module's
+heuristics; validation guards (membership tests and
 raise-only branches) are exempt by contract."""
 
-from p2p_gossipprotocol_tpu.resolver import heuristic_prefetch
+from p2p_gossipprotocol_tpu.autostatics import heuristic_prefetch
 
 
 class Engine:

@@ -1,5 +1,5 @@
 """Violating fixture: -1-auto statics resolved inline, outside the
-tuning/resolve.py chokepoint — the open-coded scatter PR 12 deleted."""
+autostatics.py rule module — a second rule for each static."""
 
 
 class Engine:
@@ -7,8 +7,7 @@ class Engine:
                  interpret=True):
         if prefetch_depth not in (-1, 0, 2):
             raise ValueError("prefetch_depth must be -1, 0, or 2")
-        # VIOLATION: the auto sentinel resolved here, so a tuning-cache
-        # hit can never substitute and the heuristic forks
+        # VIOLATION: the auto sentinel resolved here — a second rule
         self._prefetch = (2 if prefetch_depth == -1 and not interpret
                           else 0)
         # VIOLATION: same scatter, the block_perm < 0 spelling

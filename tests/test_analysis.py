@@ -37,7 +37,7 @@ FIXTURES = Path(__file__).resolve().parent / "fixtures" / "analysis"
 EXPECTED_RULES = {
     "tracing-safety", "lock-discipline", "clamp-chokepoint",
     "fingerprint-exclusion", "packer-signature", "write-discipline",
-    "telemetry-imports", "config-drift", "tuning-chokepoint",
+    "telemetry-imports", "config-drift", "auto-statics",
 }
 
 
@@ -207,10 +207,10 @@ def test_config_drift_quiet_when_reconciled():
 
 def test_tuning_rule_flags_inline_auto_resolution():
     """Both sentinel spellings — ``X == -1`` and ``X < 0`` — on known
-    auto statics are flagged outside the resolver module, while the
+    auto statics are flagged outside the rule module, while the
     ``not in (-1, 0, 2)`` validation guard in the same fixture stays
     quiet."""
-    fs = _fixture("tuning_violation", "tuning-chokepoint")
+    fs = _fixture("tuning_violation", "auto-statics")
     msgs = [f.message for f in fs]
     assert any("'prefetch_depth'" in m for m in msgs), msgs
     assert any("'frontier_mode'" in m for m in msgs), msgs
@@ -219,10 +219,10 @@ def test_tuning_rule_flags_inline_auto_resolution():
 
 
 def test_tuning_rule_quiet_on_resolver_and_validation():
-    """The clean twin: sentinel tests inside the module defining
-    resolve_statics (the registered heuristics) and raise-only
-    validation branches are exempt by contract."""
-    assert _fixture("tuning_clean", "tuning-chokepoint") == []
+    """The clean twin: sentinel tests inside autostatics.py (the
+    heuristic_* rules) and raise-only validation branches are exempt
+    by contract."""
+    assert _fixture("tuning_clean", "auto-statics") == []
 
 
 # ---------------------------------------------------- baseline machine

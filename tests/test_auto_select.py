@@ -14,6 +14,7 @@ unchanged (they lift the resolved fields).
 import numpy as np
 import pytest
 
+from p2p_gossipprotocol_tpu import autostatics as auto
 from p2p_gossipprotocol_tpu.aligned import (AUTO_BLOCK_PERM_MIN_WORDS,
                                             AlignedSimulator,
                                             n_msg_words)
@@ -135,3 +136,98 @@ def test_sharded_engines_follow_the_degrade(tmp_path, devices8):
     sim, _ = build_simulator(cfg, clamps=clamps)
     assert sim.topo.ytab is None
     assert any("block_perm" in c for c in clamps)
+
+
+# ------------------------------------------------------------------
+# Every -1-auto static, through the config surface: an explicit value
+# is honoured, and -1 gives the value of its rule in autostatics.py.
+
+def _aligned(cfg):
+    return AlignedSimulator.from_config(cfg)
+
+
+def _sir(cfg):
+    from p2p_gossipprotocol_tpu.aligned_sir import AlignedSIRSimulator
+
+    return AlignedSIRSimulator.from_config(cfg)
+
+
+def _serve(cfg):
+    from p2p_gossipprotocol_tpu.serve.service import GossipService
+
+    return GossipService(cfg)
+
+
+def _realgraph(cfg):
+    from p2p_gossipprotocol_tpu.realgraph import RealGraphSimulator
+
+    return RealGraphSimulator.from_config(cfg)
+
+
+#: static -> (extra config, builder, reader of the resolved value,
+#: an explicit value and what it resolves to, the rule's -1 value as a
+#: function of the built object)
+AUTO_CASES = {
+    "frontier_mode": (
+        "n_messages=16\nmode=pushpull\n", _aligned,
+        lambda s: (s.frontier_mode, s._frontier_delta), 1, (1, True),
+        lambda s: (int(auto.heuristic_on(-1, s.interpret)),
+                   auto.heuristic_on(-1, s.interpret))),
+    "frontier_algo": (
+        "n_messages=16\n", _aligned,
+        lambda s: (s.frontier_algo, s._frontier_algo), 1, (1, True),
+        lambda s: (int(auto.heuristic_on(-1, s.interpret)),
+                   auto.heuristic_on(-1, s.interpret))),
+    "frontier_threshold": (
+        "n_messages=16\n", _aligned,
+        lambda s: s.frontier_threshold, 0.5, 0.5,
+        lambda s: auto.FRONTIER_THRESHOLD_DEFAULT),
+    "prefetch_depth": (
+        "n_messages=16\n", _aligned,
+        lambda s: (s.prefetch_depth, s._prefetch), 2, (2, 2),
+        lambda s: (auto.heuristic_prefetch(-1, s.interpret),) * 2),
+    "overlap_mode": (
+        "n_messages=256\nmode=pushpull\n", _aligned,
+        lambda s: (s.overlap_mode, s._overlap), 1, (1, True),
+        lambda s: (int(auto.heuristic_on(-1, s.interpret)),
+                   auto.heuristic_on(-1, s.interpret))),
+    "hier_mode": (
+        "n_messages=16\n", _aligned,
+        lambda s: s.hier_mode, 1, 1,
+        lambda s: int(auto.heuristic_on(-1, s.interpret))),
+    "sir_fuse": (
+        "mode=sir\nblock_perm=1\n", _sir,
+        lambda s: (s.sir_fuse, s._fuse), 1, (1, True),
+        lambda s: (int(auto.heuristic_sir_fuse(-1, s.interpret, True)),
+                   auto.heuristic_sir_fuse(-1, s.interpret, True))),
+    "serve_chunk": (
+        "n_messages=8\nrounds=24\nserve_slots=2\n", _serve,
+        lambda s: s.chunk, 5, 5,
+        lambda s: auto.SERVE_CHUNK_DEFAULT),
+    "realgraph_pack_width": (
+        "", _realgraph,
+        lambda s: s._pack_width, 64, 64,
+        lambda s: auto.REALGRAPH_PACK_WIDTH_DEFAULT),
+    "realgraph_scatter": (
+        "", _realgraph,
+        lambda s: s._scatter, 1, 1,
+        lambda s: auto.heuristic_realgraph_scatter(-1, s._dst_static)),
+}
+
+
+@pytest.mark.parametrize("static", sorted(AUTO_CASES))
+def test_auto_static_explicit_wins_and_auto_takes_the_rule(tmp_path,
+                                                          static):
+    extra, build, read, explicit, want, rule = AUTO_CASES[static]
+    if build is _realgraph:
+        from p2p_gossipprotocol_tpu.realgraph import (rmat_edges,
+                                                      write_edge_file)
+
+        gf = str(tmp_path / "graph.txt")
+        write_edge_file(gf, *rmat_edges(7, 800, seed=5))
+        extra = f"n_messages=4\ngraph_file={gf}\n"
+    assert getattr(_cfg(tmp_path, extra), static) == -1   # the default
+    forced = build(_cfg(tmp_path, extra + f"{static}={explicit}\n"))
+    assert read(forced) == want
+    built = build(_cfg(tmp_path, extra + f"{static}=-1\n"))
+    assert read(built) == rule(built)

@@ -302,3 +302,16 @@ def test_supervise_bad_values_are_named_errors(tmp_path):
     with pytest.raises(ConfigError, match="non-negative"):
         NetworkConfig(write(
             tmp_path, "10.0.0.1:9000\nsupervise_grace_s=-1\n"))
+
+
+def test_config_accepts_auto_spellings(tmp_path):
+    cfg = NetworkConfig(write(
+        tmp_path, "127.0.0.1:8000\nbackend=jax\nn_peers=256\n"
+        "serve_chunk=-1\nfrontier_threshold=-1\n"))
+    assert cfg.serve_chunk == -1 and cfg.frontier_threshold == -1.0
+    for bad in ("serve_chunk=0", "frontier_threshold=0",
+                "frontier_threshold=1.5"):
+        with pytest.raises(ConfigError):
+            NetworkConfig(write(
+                tmp_path, f"127.0.0.1:8000\nbackend=jax\nn_peers=256\n"
+                f"{bad}\n"))

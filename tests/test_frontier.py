@@ -99,10 +99,6 @@ def test_solo_block_skip_bitwise(topo8, mode):
     assert_same(dense, sparse)
 
 
-# slow: the broadest solo composition (the PR 5 budget rule, joining
-# the six broadest sharded cases below) — per-feature skip parity
-# stays in tier-1 via the narrower cases above
-@pytest.mark.slow
 def test_solo_skip_composes_with_everything(topo8):
     """Skip x fanout x stagger x faults x fuse_update in one scenario —
     the compositions each add kernel operands next to the skip tables."""
@@ -150,7 +146,6 @@ def test_sharded_delta_bitwise_pushpull_faults(pair8):
     assert sparse.fr_sparse[1:].sum() > 0
 
 
-@pytest.mark.slow
 @pytest.mark.parametrize("mode", ["push", "pull"])
 def test_sharded_delta_bitwise_other_modes(devices8, topo8, mode):
     """Pure push (no replica carried at all) and pure pull (replica is
@@ -166,17 +161,13 @@ def test_sharded_delta_bitwise_other_modes(devices8, topo8, mode):
     assert sparse.fr_sparse[1:].sum() > 0
 
 
-@pytest.mark.slow
 def test_sharded_frontier_equals_solo(pair8, topo8):
     """The frontier-sparse sharded engine still computes the SAME
-    global function as the unsharded engine (the PR 1-4 contract).
-    slow-marked: transitively implied in tier-1 by sparse==dense here
-    plus test_aligned_sharded's dense==solo."""
+    global function as the unsharded engine (the PR 1-4 contract)."""
     solo = AlignedSimulator(topo=topo8, **dict(KW, faults=PLAN)).run(ROUNDS)
     assert_same(solo, pair8[1])
 
 
-@pytest.mark.slow
 def test_sharded_shard_count_invariance(devices8, topo8):
     """Bitwise-invariant to the shard count WITH the frontier path on —
     the regime trajectories may differ (the worst-shard signal depends
@@ -203,7 +194,6 @@ def test_tight_capacity_forces_dense_rounds(pair8, devices8, topo8):
     assert (tight.fr_sparse == 0).any()
 
 
-@pytest.mark.slow
 def test_run_to_coverage_with_frontier(devices8, topo8):
     """The regime hysteresis lives inside the compiled coverage loop
     (build_coverage_loop's extra carry): same rounds, same state."""
@@ -254,7 +244,6 @@ def test_midrun_regime_switch_checkpoint_resume(pair8, devices8, topo8):
 # ------------------------------------------------------------------ 2-D
 
 
-@pytest.mark.slow
 def test_2d_delta_bitwise(devices8):
     topo = build_aligned(seed=5, n=2048, n_slots=6, rowblk=1,
                          n_shards=4, n_msgs=64)
@@ -272,7 +261,6 @@ def test_2d_delta_bitwise(devices8):
 # ---------------------------------------------------------------- fleet
 
 
-@pytest.mark.slow
 def test_fleet_bucket_with_frontier_skip(topo8):
     """Fleet batching composes with the skip tables (per-scenario
     activity -> batched prefetch operands): every scenario in the

@@ -10,9 +10,7 @@ round whose merged total overflows the capacity falls back to the
 gather execution inside the sparse branch).
 
 Budget note (the PR 5/11 rule): the halving-vs-gather sharded pair is
-computed ONCE (module fixture) and shared; the broadest variants
-(other modes, 2-D, 4x2 hier, shard-count invariance) are slow-marked,
-each with a narrower sibling kept in tier-1."""
+computed ONCE (module fixture) and shared."""
 
 import numpy as np
 import pytest
@@ -185,7 +183,6 @@ def test_sharded_halving_overflow_falls_back_to_gather(devices8, topo8):
     assert (~fs).any()
 
 
-@pytest.mark.slow
 @pytest.mark.parametrize("mode", ["push", "pull"])
 def test_sharded_halving_other_modes(devices8, topo8, mode):
     """Pure push (no replica carried) and pure pull (replica the only
@@ -198,7 +195,6 @@ def test_sharded_halving_other_modes(devices8, topo8, mode):
     assert halving.fr_halving.sum() > 0
 
 
-@pytest.mark.slow
 def test_sharded_shard_count_invariance_with_halving(devices8, topo8):
     """Bitwise-invariant to the shard count with halving on: M=1 is
     the structural no-butterfly degenerate, M=8 the real one."""
@@ -272,11 +268,9 @@ def test_hier_halving_bitwise_2x4(devices8, topo8):
     assert halving.fr_halving_ici.sum() > 0
 
 
-@pytest.mark.slow
 def test_hier_halving_bitwise_4x2_equals_flat(devices8, topo8):
     """The other factorization, anchored to the FLAT halving run (the
-    hier == flat contract composed with the algo contract).  Slow: the
-    2x4 sibling covers the two-tier butterfly in tier-1."""
+    hier == flat contract composed with the algo contract)."""
     flat = _sharded(topo8, 1).run(ROUNDS)
     hier = AlignedShardedSimulator(
         topo=topo8, mesh=make_hier_mesh(4, 2), hier_mode=1,
@@ -288,11 +282,9 @@ def test_hier_halving_bitwise_4x2_equals_flat(devices8, topo8):
 # ------------------------------------------------------------------ 2-D
 
 
-@pytest.mark.slow
 def test_2d_halving_bitwise(devices8):
     """The 2-D engine's butterfly runs per message shard over the peer
-    axis, fit census reduced over BOTH axes.  Slow: the broadest
-    engine composition (the 1-D fixture pair is the tier-1 sibling)."""
+    axis, fit census reduced over BOTH axes."""
     topo = build_aligned(seed=5, n=2048, n_slots=6, rowblk=1,
                          n_shards=4, n_msgs=64)
     kw = dict(KW, n_msgs=64, n_honest_msgs=48)
@@ -339,8 +331,8 @@ def test_packer_signature_carries_algo(topo8):
 
 
 def test_from_config_resolves_and_clamps(tmp_path):
-    """The config surface: -1 auto resolves through the tuning
-    chokepoint (gather under interpret); an explicit 1 on a
+    """The config surface: -1 auto resolves by its rule (gather under
+    interpret); an explicit 1 on a
     non-power-of-two shard count is recorded, never silent."""
     from p2p_gossipprotocol_tpu.config import NetworkConfig
 

@@ -10,8 +10,7 @@ This suite pins that as exact equality of the final state AND every
 per-round metric across (hosts x devs) factorizations of the same
 device count, crossed with modes x the full fault plane x churn x
 byzantine x frontier regimes x 2-D meshes x fleet buckets, plus the
-mid-flight elastic migration 2x4 -> 4x2 -> flat.  Broadest cases are
-slow-marked to hold the tier-1 budget (the frontier-suite precedent).
+mid-flight elastic migration 2x4 -> 4x2 -> flat.
 
 Budget note: the sharded runs dominate, so the flat pushpull+faults
 reference run is computed ONCE (module fixture) and shared."""
@@ -173,14 +172,12 @@ def test_hier_equals_flat(flat8, devices8, topo8, hosts, devs):
     assert hier.fr_sparse_ici[1:].sum() > 0
 
 
-@pytest.mark.slow
 def test_hier_equals_flat_8x1(flat8, devices8, topo8):
     """The degenerate every-device-its-own-host factorization: the DCN
     tier carries the whole exchange, the ICI tier is size-1."""
     assert_same(flat8, mk_hier(topo8, 8, 1).run(ROUNDS))
 
 
-@pytest.mark.slow
 @pytest.mark.parametrize("mode", ["push", "pull"])
 def test_hier_other_modes(devices8, topo8, mode):
     """Pure push (no replica carried) and pure pull (replica only) —
@@ -193,7 +190,6 @@ def test_hier_other_modes(devices8, topo8, mode):
     assert_same(flat, hier)
 
 
-@pytest.mark.slow
 def test_tight_capacity_forces_dense_tiers(flat8, devices8, topo8):
     """A capacity the peak frontier cannot fit forces dense rounds on
     BOTH tiers (correctness over savings) — still bitwise, and the DCN
@@ -218,7 +214,6 @@ def test_hier_off_is_the_flat_exchange(flat8, devices8, topo8):
     assert_same(flat8, off.run(ROUNDS))
 
 
-@pytest.mark.slow
 def test_hier_dense_path_without_frontier(devices8, topo8):
     """Frontier OFF on a hier mesh: the legacy dense gathers route
     through the staged _hier_gather — pure data movement, bitwise."""
@@ -268,7 +263,6 @@ def test_midflight_migration_across_factorizations(flat8, devices8,
 # ------------------------------------------------------------- coverage
 
 
-@pytest.mark.slow
 def test_run_to_coverage_with_hier(devices8, topo8):
     """Both tiers' hysteresis lives inside the compiled coverage loop
     (the FrontierCarry extra carry now holds regime_ici too)."""
@@ -288,7 +282,6 @@ def test_run_to_coverage_with_hier(devices8, topo8):
 # ------------------------------------------------------------------ 2-D
 
 
-@pytest.mark.slow
 def test_2d_hier_equals_2d_flat(devices8):
     """The msgs x hosts x devs mesh: the peer sub-axes carry the
     two-tier exchange, the msg axis stays exchange-free."""

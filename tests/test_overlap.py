@@ -41,10 +41,8 @@ _KW = dict(n_msgs=64, mode="pushpull", max_strikes=3, liveness_every=2,
            seed=5)
 
 
-@pytest.mark.parametrize("mode", [
-    "push", pytest.param("pushpull", marks=pytest.mark.slow)])
-@pytest.mark.parametrize("frontier", [
-    0, pytest.param(1, marks=pytest.mark.slow)])
+@pytest.mark.parametrize("mode", ["push", "pushpull"])
+@pytest.mark.parametrize("frontier", [0, 1])
 def test_overlap_bitwise_parity_sharded(devices8, mode, frontier):
     """Split == unsplit == solo, bit for bit, dense AND frontier
     exchange, under churn + liveness + byzantine + stagger."""
@@ -63,7 +61,6 @@ def test_overlap_bitwise_parity_sharded(devices8, mode, frontier):
     _assert_bitwise(off, on, f"{mode}/fr{frontier}:off-vs-on")
 
 
-@pytest.mark.slow          # broadest matrix — outside the tier-1 budget
 def test_overlap_2d_and_faults(devices8):
     """The 2-D mesh splits its peer-axis gather the same way, and the
     in-kernel fault gates (hashed per receiver/slot/round) land

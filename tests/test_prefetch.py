@@ -51,8 +51,7 @@ def test_prefetch_bitwise_parity(bp, mode):
     _assert_bitwise(ra, rb, f"bp={bp} mode={mode}")
 
 
-@pytest.mark.parametrize("bp", [
-    pytest.param(False, marks=pytest.mark.slow), True])
+@pytest.mark.parametrize("bp", [False, True])
 def test_prefetch_composes_with_every_kernel_variant(bp):
     """fanout window + fuse_update finalize/census + link faults +
     frontier block skipping all ride the same prefetched stream."""
@@ -66,7 +65,6 @@ def test_prefetch_composes_with_every_kernel_variant(bp):
     _assert_bitwise(ra, rb, f"variants bp={bp}")
 
 
-@pytest.mark.slow          # broadest matrix — outside the tier-1 budget
 def test_prefetch_sharded_parity(devices8):
     """The sharded engines inherit the prefetched stream through the
     shared aligned_round; 1-D and 2-D meshes stay bitwise-identical to
@@ -88,7 +86,6 @@ def test_prefetch_sharded_parity(devices8):
     _assert_bitwise(base, sh2, "2d-mesh")
 
 
-@pytest.mark.slow          # broadest matrix — outside the tier-1 budget
 def test_prefetch_fleet_parity():
     """vmap composes: a fleet bucket of prefetched scenarios stays
     bitwise-equal to the solo prefetched runs (and to unprefetched)."""
@@ -146,12 +143,9 @@ def test_prefetch_config_key(tmp_path):
     assert sim.prefetch_depth == 2 and sim._prefetch == 2
     p.write_text(base)
     auto = AlignedSimulator.from_config(NetworkConfig(str(p)))
-    # since round 14 from_config resolves the -1 auto through the
-    # tuning chokepoint (cache hit or the registered heuristic), so
-    # the built sim carries the CONCRETE schedule (0 under interpret)
-    # plus the resolution record — the -1 never leaks past the seam
-    assert auto.prefetch_depth == auto._prefetch
-    assert auto._tuning.statics["prefetch_depth"] == auto._prefetch
+    # __post_init__ resolves the -1 auto once, by its rule, so the
+    # built sim carries the CONCRETE schedule (0 under interpret)
+    assert auto.prefetch_depth == auto._prefetch == 0
     assert bucket_signature(sim) != bucket_signature(
         AlignedSimulator(topo=sim.topo, n_msgs=sim.n_msgs, mode=sim.mode,
                          churn=sim.churn, pull_window=sim.pull_window,

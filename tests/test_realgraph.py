@@ -419,7 +419,7 @@ def test_serve_slot_reuse_and_zero_recompiles(tmp_path):
 
 
 # ---------------------------------------------------------------------
-# config / engines / tuning / checkpoint surface
+# config / engines / checkpoint surface
 # ---------------------------------------------------------------------
 
 def test_config_validates_realgraph_keys(tmp_path):
@@ -446,14 +446,6 @@ def test_from_config_n_peers_conflict(tmp_path):
     cfg, _ = _graph_cfg(tmp_path)
     with pytest.raises(ValueError, match="n_peers"):
         RealGraphSimulator.from_config(cfg, n_peers=999)
-
-
-def test_tuner_refuses_realgraph_by_name(tmp_path):
-    cfg, _ = _graph_cfg(tmp_path, extra="engine=realgraph\n")
-    from p2p_gossipprotocol_tpu.tuning import search
-
-    with pytest.raises(ValueError, match="realgraph"):
-        search.tune_config(cfg)
 
 
 def test_graph_identity_enters_fingerprint_not_pack_knobs(tmp_path):

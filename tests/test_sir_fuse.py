@@ -40,8 +40,7 @@ def _assert_bitwise(ra, rb, ctx):
 
 
 @pytest.mark.parametrize("bp", [False, True])
-@pytest.mark.parametrize("churn", [
-    pytest.param(0.0, marks=pytest.mark.slow), 0.05])
+@pytest.mark.parametrize("churn", [0.0, 0.05])
 def test_sir_fuse_bitwise_parity(bp, churn):
     """Fused == solo count_pass, bit for bit, on both overlay families
     with and without churn, prefetch on and off."""
@@ -74,7 +73,6 @@ def test_sir_fuse_pressure_plane_exact():
     np.testing.assert_array_equal(np.asarray(solo), np.asarray(fused))
 
 
-@pytest.mark.slow          # broadest matrix — outside the tier-1 budget
 def test_sir_fuse_sharded_parity(devices8):
     """The sharded SIR engine inherits the fused path through the
     shared aligned_sir_round — bitwise-equal to the solo fused run."""
