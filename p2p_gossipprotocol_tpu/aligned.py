@@ -1783,7 +1783,8 @@ class AlignedSimulator:
 
         Returns ``{term: bytes, ..., "total": bytes}``;
         :meth:`hbm_bytes_per_round` is the total."""
-        from p2p_gossipprotocol_tpu.ops.aligned_kernel import stream_plan
+        from p2p_gossipprotocol_tpu.ops.aligned_kernel import (ok_resident,
+                                                               stream_plan)
 
         topo = self.topo
         R, D, W, C = topo.rows, topo.n_slots, self.n_words, LANES
@@ -1828,7 +1829,7 @@ class AlignedSimulator:
             lk = leak if lk is None else lk
             return plan["y"] + lk * (plan["y_naive"] - plan["y"])
 
-        def pass_bytes(n_slots_d, final, seeded, active=None):
+        def pass_bytes(n_slots_d, final, seeded, active=None, pull=False):
             plan = stream_plan(rolls, T, ytab=ytab, n_slots=n_slots_d,
                                active=active)
             eff = y_eff(plan)
@@ -1836,7 +1837,9 @@ class AlignedSimulator:
             b += plan["tab"] * blk * C   # colidx (int8)
             b += plan["row"] * blk * C   # gate (int8)
             b += wp                      # OR-accumulator out
-            if fused:
+            if fused and self._prefetch == 2 and ok_resident(R, pull):
+                b += plane               # src_ok, read once into VMEM
+            elif fused:
                 b += eff * blk * C * 4   # src_ok rides each y fetch
             if final:
                 # in-kernel seen-update + census: seen in, seen' out,
@@ -1856,7 +1859,7 @@ class AlignedSimulator:
             # block roll — the replay sees the single stream directly.
             terms["pull_pass"] = pass_bytes(
                 self._pull_slots, final=fin,
-                seeded=fin and self.mode == "pushpull")
+                seeded=fin and self.mode == "pushpull", pull=True)
         n_passes = len(terms)
         # XLA-side mask + permute gather per non-fused pass
         terms["prep"] = 0 if fused else 3 * wp * n_passes
@@ -1866,7 +1869,7 @@ class AlignedSimulator:
             plan = stream_plan(rolls, T, ytab=ytab)
             lv = (y_eff(plan, leak_live) * blk * C * 4   # alive plane
                   + 4 * slot8                 # colidx/strikes r+w
-                  + 2 * slot8                 # evict8 write + reduce
+                  + 2 * T * 8 * C * 4         # eviction tiles w + reduce
                   + (plane if fused else 3 * plane))   # gather/prep
             terms["liveness"] = lv // self.liveness_every
         if fin:
@@ -2314,14 +2317,16 @@ def aligned_round(sim: AlignedSimulator, state: AlignedState,
                 col, stk = ops
                 y_alive = (gather(alive_w) if fused
                            else prow(gather(alive_w)))
-                col2, stk2, evict8 = liveness_pass(
+                # the pass updates the lane table and strikes in place
+                # and counts first crossings in per-block tiles
+                col2, stk2, evict_tiles = liveness_pass(
                     y_alive, col, stk, topo.deg, rolls_off, topo.subrolls,
                     gbase=grows[::blk], round_idx=state.round,
                     hash_seed=hash_seed,
                     ytab=ytab_local if fused else None,
                     max_strikes=sim.max_strikes,
                     rowblk=topo.rowblk, interpret=sim.interpret)
-                return col2, stk2, jnp.sum(evict8, dtype=jnp.int32)
+                return col2, stk2, jnp.sum(evict_tiles, dtype=jnp.int32)
 
             def lv_skip(ops):
                 col, stk = ops
@@ -2558,9 +2563,11 @@ def aligned_round(sim: AlignedSimulator, state: AlignedState,
             delta = jnp.where(deg32 > 0, delta,
                               jnp.int8(Dw))                # no contact
         with jax.named_scope("pull"):
-            pulled = gossip_pass(ys, topo.colidx[:Dw], delta, rolls_off[:Dw],
-                                 topo.subrolls[:Dw], pull=True,
-                                 ytab=ytab_local[:Dw] if fused else None,
+            # the Dw-slot grid reads the first Dw slots of the whole
+            # tables through its index maps: nothing is sliced
+            pulled = gossip_pass(ys, topo.colidx, delta, rolls_off,
+                                 topo.subrolls, pull=True, slots=Dw,
+                                 ytab=ytab_local if fused else None,
                                  src_ok=src_ok if fused else None,
                                  acc_init=(recv if fin and
                                            sim.mode == "pushpull" else None),

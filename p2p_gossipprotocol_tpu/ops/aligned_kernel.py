@@ -57,6 +57,22 @@ from jax.experimental.pallas import tpu as pltpu
 LANES = 128
 #: most vreg loads a rolled read emits for one ref (see _rolled_reader)
 _MAX_LOADS = 64
+#: largest ``src_ok`` plane a push pass keeps whole in VMEM (a v5e core
+#: has 128 MiB): 2^24 peers' int32 words
+OK_RESIDENT_BYTES = 64 << 20
+#: VMEM the rest of a pass may use beside a resident ``src_ok``
+_PASS_VMEM_BYTES = 32 << 20
+
+
+def ok_resident(ok_rows: int, pull: bool) -> bool:
+    """Whether a fused pass on the manual stream (``prefetch_depth=2``)
+    holds its ``src_ok`` plane of ``ok_rows`` rows whole in VMEM.  The
+    push fetches a ``src_ok`` block with every y block, a different one
+    at each slot: D reads of the plane from HBM.  Held in VMEM, it is
+    read from HBM once and the ring copies within VMEM.  The windowed
+    pull's slots share one block roll, so its stream reads each block
+    once and gains nothing."""
+    return not pull and ok_rows * LANES * 4 <= OK_RESIDENT_BYTES
 
 
 def interpret_for(platform: str) -> bool:
@@ -461,6 +477,7 @@ def gossip_pass(y: jax.Array, colidx: jax.Array, gate: jax.Array,
                 yact: jax.Array | None = None,
                 press: bool = False,
                 prefetch_depth: int = 0,
+                slots: int | None = None,
                 rowblk: int = 512,
                 name: str = "gossip",
                 interpret: bool = False):
@@ -489,7 +506,9 @@ def gossip_pass(y: jax.Array, colidx: jax.Array, gate: jax.Array,
                                  no host-side permute pass exists
     ``src_ok``  int32[Ry, 128] — with ``ytab``: the source-peer send
                                  mask (-1 alive&honest / 0), ANDed
-                                 in-kernel per gathered lane
+                                 in-kernel per gathered lane; a push
+                                 on the manual stream holds it whole
+                                 in VMEM where it fits (:func:`ok_resident`)
     ``fanout``/``shift`` — bounded fanout (push only): listen on the
                 fanout-slot circular window starting at ``shift`` (int8
                 [R, 128], per-round random in [0, deg)); fanout=0 floods
@@ -553,6 +572,13 @@ def gossip_pass(y: jax.Array, colidx: jax.Array, gate: jax.Array,
                 remapped dead steps, issue nothing).  0/1 = the legacy
                 BlockSpec-pipelined stream.  Bitwise-identical by
                 construction: the same blocks reach the same compute.
+    ``slots`` — the grid's slot count D (default: all of ``colidx``'s).
+                A smaller D walks only the first D slots: the per-slot
+                tables (``colidx``, ``rolls``, ``subrolls``, ``ytab``,
+                ``yidx``, ``yact``) are read through the index maps and
+                scalar prefetch at rows d < D, so a caller passes them
+                whole and no sliced copy of the lane table exists (the
+                windowed pull's Dw-slot grid).
     ``name``  — the kernel's name on the device (the profiler's op
                 name): the round passes ``push``, ``pull`` or
                 ``push_self``, so a trace tells the passes apart.
@@ -562,11 +588,18 @@ def gossip_pass(y: jax.Array, colidx: jax.Array, gate: jax.Array,
     """
     W, Ry, C = y.shape
     assert C == LANES, f"lane dim must be {LANES}, got {C}"
-    D, R, _ = colidx.shape
+    n_tab, R, _ = colidx.shape
+    D = n_tab if slots is None else slots
+    assert 0 < D <= n_tab, (D, n_tab)
     blk = min(rowblk, R)
     assert R % blk == 0 and Ry % blk == 0
     T = R // blk          # output (local) row blocks
     Ty = Ry // blk        # y (possibly global) row blocks
+
+    def slot_table(tab):  # an int32[>= D, T] per-slot table
+        assert tab.shape[1:] == (T,) and tab.shape[0] >= D, (tab.shape,
+                                                             (D, T))
+
     fanout = 0 if pull else fanout
     fused = ytab is not None
     masked = src_ok is not None
@@ -596,15 +629,15 @@ def gossip_pass(y: jax.Array, colidx: jax.Array, gate: jax.Array,
         assert gbase.shape == (T,), (gbase.shape, T)
     if skipped:
         assert yact is not None, "block skipping needs both yidx and yact"
-        assert yidx.shape == (D, T), (yidx.shape, (D, T))
-        assert yact.shape == (D, T), (yact.shape, (D, T))
+        slot_table(yidx)
+        slot_table(yact)
     # Index maps take ``*_`` so the optional skip/census/fault prefetch
     # operands (appended below) never change their arity.  Every y/ok
     # map routes through :func:`grid_y_index` — THE index rule the
     # prefetch stream and the traffic model's replay share, so the
     # three consumers cannot drift (tests/test_stream_plan.py).
     if fused:
-        assert ytab.shape == (D, T), (ytab.shape, (D, T))
+        slot_table(ytab)
         n_pref = 3
         prefetch = (rolls, subrolls, ytab)
         if skipped:
@@ -644,12 +677,17 @@ def gossip_pass(y: jax.Array, colidx: jax.Array, gate: jax.Array,
     if faulty:
         prefetch = prefetch + (gbase, fault_meta)
         n_pref += 2
+    params = None
     if pref2:
         # y (and src_ok) stay whole in HBM; the kernel's scratch ring
         # and its grid_y_index-driven copy stream replace the BlockSpec
         # pipeline for exactly these operands.
         y_spec = pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY)
         ok_spec = pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY)
+        if masked and ok_resident(Ry, pull):
+            ok_spec = pl.BlockSpec(memory_space=pltpu.MemorySpace.VMEM)
+            params = pltpu.CompilerParams(
+                vmem_limit_bytes=Ry * C * 4 + _PASS_VMEM_BYTES)
     else:
         y_spec = pl.BlockSpec((W, blk, C), y_map)
         ok_spec = pl.BlockSpec((blk, C), ok_map) if masked else None
@@ -723,6 +761,7 @@ def gossip_pass(y: jax.Array, colidx: jax.Array, gate: jax.Array,
                           skipped, press, pref2, Ty, n_pref),
         grid_spec=grid_spec,
         out_shape=out_shape,
+        compiler_params=params,
         interpret=interpret,
         name=name,
     )(*prefetch, *operands)
@@ -891,7 +930,17 @@ def _liveness_kernel(max_strikes, n_pref, *refs):
     take = evict & cand_alive
     col_out[0] = jnp.where(take, cand, col).astype(jnp.int8)
     strikes_out[0] = jnp.where(take, 0, s_new).astype(jnp.int8)
-    evict_out[0] = (s_new == max_strikes).astype(jnp.int8)
+    # first crossings, summed into the row block's (8, 128) tile, which
+    # stays resident across the slot loop like the census tiles
+    ev = _fold8((s_new == max_strikes).astype(jnp.int32))
+
+    @pl.when(d == 0)
+    def _():
+        evict_out[0] = ev
+
+    @pl.when(d > 0)
+    def _():
+        evict_out[0] = evict_out[0] + ev
 
 
 def liveness_pass(y_alive: jax.Array, colidx: jax.Array,
@@ -919,8 +968,14 @@ def liveness_pass(y_alive: jax.Array, colidx: jax.Array,
                                      draws shard-invariant)
     ``round_idx``/``hash_seed``    — the other hash inputs (traced scalar
                                      / static int)
-    Returns ``(colidx', strikes', evictions int8[D, R, 128])`` where the
-    eviction mask marks first crossings of the strike threshold.
+    Returns ``(colidx', strikes', evictions int32[T, 8, 128])``.
+    ``colidx`` and ``strikes`` are updated in place (aliased to outputs
+    0 and 1): grid step (t, d) reads and writes only block (d, t), so no
+    step reads a block another step wrote, and a caller that donates
+    them (the round's loop carry) pays no copy of either table.  The
+    evictions are per-row-block partial tiles (see :func:`_fold8`) of
+    the first crossings of the strike threshold; their sum is the
+    round's count.
     """
     Ry, C = y_alive.shape
     assert C == LANES, f"lane dim must be {LANES}, got {C}"
@@ -959,7 +1014,7 @@ def liveness_pass(y_alive: jax.Array, colidx: jax.Array,
         out_specs=[
             pl.BlockSpec((1, blk, C), tab_map),
             pl.BlockSpec((1, blk, C), tab_map),
-            pl.BlockSpec((1, blk, C), tab_map),
+            pl.BlockSpec((1, 8, C), lambda t, d, *_: (t, 0, 0)),
         ],
     )
     return pl.pallas_call(
@@ -968,8 +1023,9 @@ def liveness_pass(y_alive: jax.Array, colidx: jax.Array,
         out_shape=[
             jax.ShapeDtypeStruct((D, R, C), jnp.int8),
             jax.ShapeDtypeStruct((D, R, C), jnp.int8),
-            jax.ShapeDtypeStruct((D, R, C), jnp.int8),
+            jax.ShapeDtypeStruct((T, 8, C), jnp.int32),
         ],
+        input_output_aliases={n_pref + 1: 0, n_pref + 2: 1},
         interpret=interpret,
         name="liveness",
     )(*prefetch, y_alive, colidx, strikes, gate)

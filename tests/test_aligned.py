@@ -281,7 +281,47 @@ def test_liveness_pass_matches_ground_truth():
         rowblk=8, max_strikes=max_strikes)
     np.testing.assert_array_equal(np.asarray(col_k), col_n)
     np.testing.assert_array_equal(np.asarray(s_k), s_n)
-    np.testing.assert_array_equal(np.asarray(ev_k), ev_n)
+    # evictions: one int32 (8, 128) tile per row block, summing to the
+    # plane's count of first crossings
+    assert ev_k.shape == (R // 8, 8, LANES) and ev_k.dtype == jnp.int32
+    assert int(ev_n.sum()) > 0
+    assert int(jnp.sum(ev_k)) == int(ev_n.sum())
+
+
+def test_liveness_pass_donated_matches_undonated():
+    """The pass updates ``colidx`` and ``strikes`` in place (aliased to
+    its first two outputs): under ``jax.jit`` with both donated, as the
+    round's loop carry hands them over, it returns bitwise what the
+    undonated call returns, and the caller's undonated copies stay
+    intact."""
+    from p2p_gossipprotocol_tpu.ops.aligned_kernel import liveness_pass
+
+    rng = np.random.default_rng(29)
+    R, D, blk = 128, 4, 64
+    y_alive = jnp.asarray(np.where(rng.uniform(size=(R, LANES)) < 0.6, -1,
+                                   0).astype(np.int32))
+    colidx = rng.integers(0, LANES, size=(D, R, LANES), dtype=np.int8)
+    strikes = rng.integers(0, 5, size=(D, R, LANES), dtype=np.int8)
+    deg = jnp.asarray(rng.integers(0, D + 1, size=(R, LANES),
+                                   dtype=np.int8))
+    rolls = jnp.asarray(rng.integers(0, 2, size=D, dtype=np.int32))
+    subrolls = jnp.asarray(np.array([3, 0, 17, 40], np.int32))
+    gbase = jnp.arange(0, R, blk, dtype=jnp.int32)
+
+    def sweep(col, stk):
+        return liveness_pass(y_alive, col, stk, deg, rolls, subrolls,
+                             gbase=gbase, round_idx=4, hash_seed=11,
+                             rowblk=blk, interpret=True)
+
+    col0, stk0 = jnp.asarray(colidx), jnp.asarray(strikes)
+    want = jax.jit(sweep)(col0, stk0)
+    got = jax.jit(sweep, donate_argnums=(0, 1))(jnp.asarray(colidx),
+                                                jnp.asarray(strikes))
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+    assert int(jnp.sum(want[2])) > 0
+    np.testing.assert_array_equal(np.asarray(col0), colidx)
+    np.testing.assert_array_equal(np.asarray(stk0), strikes)
 
 
 # ----------------------------------------------------------------------
@@ -427,8 +467,10 @@ def test_liveness_and_count_match_ground_truth_multi_vreg():
     rand = np.asarray(rewire_candidates(grows, D, 5, 9))
     want = _numpy_liveness(y_alive, colidx, strikes, rand, deg, rolls,
                            subrolls, rowblk=blk, max_strikes=max_strikes)
-    for g, w in zip(got, want):
+    for g, w in zip(got[:2], want[:2]):
         np.testing.assert_array_equal(np.asarray(g), w)
+    assert got[2].shape == (R // blk, 8, LANES)
+    assert int(jnp.sum(got[2])) == int(want[2].sum()) > 0
 
     counts = count_pass(jnp.asarray(y_alive), jnp.asarray(colidx),
                         jnp.asarray(deg), *args, rowblk=blk, interpret=True)

@@ -39,6 +39,12 @@ def test_windowed_pass_equals_masked_full_pass():
                       topo.subrolls[:Dw], pull=True, rowblk=topo.rowblk,
                       interpret=True)
     np.testing.assert_array_equal(np.asarray(full), np.asarray(win))
+    # the same Dw-slot grid over the whole tables, as the round runs it:
+    # the index maps read only their first Dw slots
+    grid = gossip_pass(y, topo.colidx, delta, topo.rolls, topo.subrolls,
+                       pull=True, slots=Dw, rowblk=topo.rowblk,
+                       interpret=True)
+    np.testing.assert_array_equal(np.asarray(grid), np.asarray(win))
 
 
 def _sim(pw, mode="pushpull", **over):

@@ -16,6 +16,7 @@ imports this file.
 
 import dataclasses
 import os
+import re
 
 import numpy as np
 import pytest
@@ -197,11 +198,13 @@ def test_liveness_pass_blk512_fused_compiles(one_chip):
              _sds((o["T"],), jnp.int32, one_chip), scalar, scalar)
 
 
-def _scale_sim(n_shards=1):
+def _scale_sim(n_shards=1, **over):
     from p2p_gossipprotocol_tpu.aligned import AlignedSimulator
     from p2p_gossipprotocol_tpu.config import NetworkConfig
 
     cfg = NetworkConfig(os.path.join(REPO, "examples", "scale.txt"))
+    for k, v in over.items():
+        setattr(cfg, k, v)
     sim = AlignedSimulator.from_config(cfg, n_shards=n_shards)
     return dataclasses.replace(sim, **COMPILED)
 
@@ -224,6 +227,72 @@ def test_solo_step_compiles(one_chip):
     compiled = _compile(sim.step, *args)
     # the 16 GB chip holds the round's operands many times over
     assert compiled.memory_analysis().argument_size_in_bytes < 1 << 30
+
+
+_HEADER = re.compile(r"^(?:ENTRY )?%?([\w.\-]+) .*\{$")
+_CALLS = re.compile(r"(?:calls|body|condition|to_apply|true_computation"
+                    r"|false_computation)=%?([\w.\-]+)"
+                    r"|branch_computations=\{([^}]*)\}")
+
+
+def _loop_instructions(text):
+    """The instructions of an optimized HLO module's while loops: every
+    computation a loop body reaches (fusions, conditional branches,
+    nested loops), one stripped line each."""
+    comps, lines = {}, None
+    for line in text.splitlines():
+        m = _HEADER.match(line)
+        if m:
+            lines = comps.setdefault(m.group(1), [])
+        elif line.startswith("}"):
+            lines = None
+        elif lines is not None:
+            lines.append(line.strip())
+    todo = [m.group(1) for body in comps.values() for line in body
+            for m in [re.search(r" while\(.*body=%?([\w.\-]+)", line)] if m]
+    seen = set()
+    while todo:
+        name = todo.pop()
+        if name in seen or name not in comps:
+            continue
+        seen.add(name)
+        for line in comps[name]:
+            for m in _CALLS.finditer(line):
+                todo += ([m.group(1)] if m.group(1) else
+                         [c.strip().lstrip("%") for c in m.group(2).split(",")])
+    return [line for name in sorted(seen) for line in comps[name]]
+
+
+@pytest.mark.parametrize("traffic", [
+    dict(n_messages=32, message_stagger=1),
+    dict(n_messages=256, message_stagger=0)], ids=["stagger32", "burst256"])
+def test_coverage_loop_keeps_lane_table_in_place(one_chip, traffic):
+    """The scale deployment's coverage loop, as ``run_to_coverage``
+    compiles it (liveness sweeps, ``pull_window=1``; W=1 row-perm and W=8
+    block-perm overlays), copies neither the lane table nor the strike
+    counters (s8[D, R, 128]) inside its while loop, and materialises no
+    slice of the windowed pull's first Dw slots (s8[Dw, R, 128]): the
+    liveness pass updates both tables in place and the pull reads its
+    slots through its index maps."""
+    from p2p_gossipprotocol_tpu.state import (build_coverage_loop,
+                                              stagger_sched_end)
+
+    sim = _scale_sim(**traffic)
+    assert sim._liveness and sim.pull_window
+    D, R, Dw = sim.topo.n_slots, sim.topo.rows, sim._pull_slots
+    assert Dw < D
+    sh = lambda t: jax.tree.map(                            # noqa: E731
+        lambda x: _sds(np.shape(x), x.dtype, one_chip), t)
+    loop = build_coverage_loop(
+        sim.step, target=0.99, max_rounds=128, check_every=1,
+        sched_end=stagger_sched_end(sim._n_honest, sim.message_stagger))
+    body = _loop_instructions(_compile(
+        loop, sh(jax.eval_shape(sim.init_state)), sh(sim.topo)).as_text())
+    assert any(re.match(r"%liveness[.\d]* = ", line) for line in body)
+    waste = [line[:120] for line in body
+             if re.search(rf"= s8\[{D},{R},128\]\{{[^}}]*\}} copy\(", line)
+             or re.search(rf"= s8\[{Dw},{R},128\]\{{[^}}]*\}} slice\(", line)]
+    assert not waste, waste
 
 
 def test_sharded_step_compiles(topo):

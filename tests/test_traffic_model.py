@@ -105,10 +105,44 @@ def test_pull_window_cuts_the_pull_pass_only():
 
 
 def test_liveness_amortizes_with_stride():
+    """The sweep's bytes, pinned: the alive plane per effective stream,
+    the lane table and strikes read and written in place, the per-block
+    eviction tiles written and reduced, the row-perm prep; every third
+    round at stride 3."""
     k1 = _sim(churn=ChurnConfig(rate=0.05), liveness_every=1)
     k3 = _sim(churn=ChurnConfig(rate=0.05), liveness_every=3)
     t1, t3 = k1.traffic_model(), k3.traffic_model()
     assert t3["liveness"] == t1["liveness"] // 3
+    topo = k1.topo
+    R, D, C, blk = topo.rows, topo.n_slots, 128, topo.rowblk
+    T = R // blk
+    plan = stream_plan(np.asarray(topo.rolls), T)
+    eff = plan["y"] + topo.reuse_leak * (plan["y_naive"] - plan["y"])
+    assert topo.ytab is None
+    assert t1["liveness"] == (eff * blk * C * 4 + 4 * D * R * C
+                              + 2 * T * 8 * C * 4 + 3 * R * C * 4) // 1
+
+
+def test_resident_src_ok_is_read_once():
+    """A fused push on the manual stream holds src_ok whole in VMEM
+    where the plane fits: the model charges it once, not once per y
+    fetch.  The pull keeps its per-fetch charge."""
+    sim = _sim(roll_groups=4, block_perm=True, prefetch_depth=2, rowblk=64)
+    terms = sim.traffic_model()
+    topo = sim.topo
+    R, C, W, blk = topo.rows, 128, sim.n_words, topo.rowblk
+    ytab = np.asarray(topo.ytab)
+    push = stream_plan(np.asarray(topo.rolls), R // blk, ytab=ytab)
+    assert push["y"] > R // blk           # the push re-reads blocks
+    assert terms["push_pass"] == (push["y"] * W * blk * C * 4
+                                  + push["tab"] * blk * C
+                                  + push["row"] * blk * C
+                                  + W * R * C * 4 + R * C * 4)
+    pull = stream_plan(np.asarray(topo.rolls), R // blk, ytab=ytab,
+                       n_slots=sim._pull_slots)
+    assert terms["pull_pass"] == (pull["y"] * (W + 1) * blk * C * 4
+                                  + pull["tab"] * blk * C
+                                  + pull["row"] * blk * C + W * R * C * 4)
 
 
 def test_total_is_the_sum_and_feeds_the_bench():
