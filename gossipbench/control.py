@@ -31,16 +31,21 @@ def as_program(out, overlay) -> dict:
 
 
 def readings(workload: str, seed: int, bench_path: str | None = None) -> dict:
+    """Both runs on the cell's chips, as the check runs the reference."""
+    import jax
+
     cell = spec.load_cell(workload, bench_path)
     kv = cell.kv()
     ref_mod = spec.reference(kv["bench_reference"])
     dep = ref_mod.deployment(kv)
+    devices = jax.devices()[:cell.chips]
     key = window.scenario_key(seed, 0)
     t0 = time.perf_counter()
-    sound = ref_mod.Scenario(dep)
+    sound = ref_mod.Scenario(dep, devices=devices)
     want = sound.run(key)
     t1 = time.perf_counter()
-    broken = ref_mod.Scenario(dep, liveness_every=2 * dep.liveness_every)
+    broken = ref_mod.Scenario(dep, liveness_every=2 * dep.liveness_every,
+                              devices=devices)
     got = broken.run(key)
     t2 = time.perf_counter()
     numbers, parts = check.compare(as_program(got, broken.ov), want, sound.ov)

@@ -1,5 +1,7 @@
 """Kernels: the compulsory bytes of the rounds run (gossipbench.roofline)
-over the HBM peak times the kernels' device time, in percent."""
+over the HBM peak times the kernels' device time, in percent.  On a
+cell of several chips each chip moves its share of the bytes in its
+own kernel time, the mean over the chips."""
 
 from gossipbench import roofline
 
@@ -14,4 +16,5 @@ def read(run):
         rounds, n_peers=run.dep.n_peers,
         n_words=run.dep.n_words, n_slots=run.dep.n_slots,
         liveness_every=run.dep.liveness_every)
-    return 100.0 * moved / (roofline.hbm_peak(run.device_kind) * run.trace.kernel_s)
+    return 100.0 * moved / (roofline.hbm_peak(run.device_kind)
+                            * run.trace.kernel_s * run.dep.shards)

@@ -112,14 +112,14 @@ def keep_hash(flat_id, d, round_idx, seed):
 class Scenario(base.Scenario):
     """``aligned_gossip.Scenario`` with the fault plane in its round."""
 
-    def _round_fn(self, live: bool):
+    def _round(self, live: bool):
         dep, ov = self.dep, self.ov
         R, D, W = ov.rows, dep.n_slots, dep.n_words
         Dw = ov.pull_slots() if dep.pull_window else D
         hmask = jnp.asarray(base.honest_mask(dep.n_msgs, W))[None, :, None]
         rows = jnp.arange(R, dtype=jnp.int32)
         pid = rows[:, None] * LANES + jnp.arange(LANES, dtype=jnp.int32)[None]
-        chunk = self.chunk
+        chunk, parts, whole = self.chunk, self.parts, self._sharding(None)
         fseed = dep.fault_seed & 0x7FFFFFFF
         seeds = [fseed ^ (k * PASS_SEED & 0x7FFFFFFF) for k in (0, 1)]
         gmask = dep.groups - 1
@@ -130,7 +130,7 @@ class Scenario(base.Scenario):
 
         def nbr_words(x, srow_d, lanes_d):
             return base.lane_gather(jnp.take(x, srow_d, axis=0),
-                                    lanes_d.astype(jnp.int32), chunk)
+                                    lanes_d.astype(jnp.int32), chunk, parts)
 
         def link_ok(d, lane_d, r, split, k):
             """Whether slot d's transfer reaches each receiver in pass k."""
@@ -164,13 +164,13 @@ class Scenario(base.Scenario):
                     lanes, strikes = carry
                     y = jnp.take(alive_w[:, None], c["srow"][d], axis=0)
                     lane = lanes[d].astype(jnp.int32)
-                    nbr_alive = base.lane_gather(y, lane, chunk)[:, 0] != 0
+                    nbr_alive = base.lane_gather(y, lane, chunk, parts)[:, 0] != 0
                     s = strikes[d].astype(jnp.int32)
                     dead = (d < c["deg"]) & ~nbr_alive
                     s_new = jnp.where(dead, jnp.minimum(s + 1, dep.max_strikes + 1), 0)
                     cand = base.rewire_lane(pid, d, r, jnp.int32(dep.seed))
                     take = ((s_new >= dep.max_strikes)
-                            & (base.lane_gather(y, cand, chunk)[:, 0] != 0))
+                            & (base.lane_gather(y, cand, chunk, parts)[:, 0] != 0))
                     lanes = lanes.at[d].set(jnp.where(take, cand, lane).astype(jnp.int8))
                     strikes = strikes.at[d].set(jnp.where(take, 0, s_new).astype(jnp.int8))
                     return lanes, strikes
@@ -216,7 +216,7 @@ class Scenario(base.Scenario):
             seen = seen | new
             pc = jax.lax.population_count
             per_row = lambda x: jnp.sum(x.reshape(R, -1), axis=1, dtype=jnp.int32)
-            orw = jax.lax.reduce(seen, jnp.int32(0), jax.lax.bitwise_or, (0, 2))
+            orw = base.or_words(seen, whole)
             stats = {"hits": per_row(jnp.where((ok_w != 0)[:, None],
                                                pc(seen & hmask), 0)),
                      "n_ok": jnp.sum(ok_w != 0, dtype=jnp.int32),
@@ -225,5 +225,5 @@ class Scenario(base.Scenario):
                   "alive": alive, "strikes": strikes, "lanes": lanes, "key": key}
             return st, stats
 
-        return jax.jit(rnd)
+        return rnd
 

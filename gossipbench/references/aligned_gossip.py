@@ -50,7 +50,7 @@ BLOCK_PERM_MIN_WORDS = 4
 _UNMODELLED = {"fanout": "0", "byzantine_fraction": "0", "fault_link_drop": "0",
                "fault_delay": "0", "fault_byzantine": "0",
                "fault_partition": "", "fault_crash": "", "fault_recover": "",
-               "mesh_devices": "0", "msg_shards": "0"}
+               "msg_shards": "0", "hier_hosts": "0", "hier_devs": "0"}
 
 
 def _mix32(h):
@@ -93,6 +93,7 @@ class Deployment:
     seed: int
     target: float
     max_rounds: int
+    shards: int = 1           # mesh_devices: the row layout's shard count
 
     @property
     def n_words(self) -> int:
@@ -103,8 +104,30 @@ class Deployment:
         return (self.n_msgs - 1) * self.stagger + 1 if self.stagger > 0 else 0
 
 
+def layout(n_peers: int, rowblk: int, shards: int = 1) -> tuple[int, int]:
+    """(rows, row block) of the overlay for ``shards`` equal row shards:
+    rows rounded to a multiple of 8 (or 4, 2, 1) rows a shard while the
+    padding stays under 1/16, and the row block the largest divisor of
+    a shard's rows up to ``rowblk``, a multiple of 8 where one divides.
+    The row block shapes the neighbour map, so a layout whose shards
+    hold fewer rows than one chip's row block draws another overlay."""
+    rows0 = max(1, -(-n_peers // LANES))
+    for align in (8, 4, 2, 1):
+        rows = -(-rows0 // (align * shards)) * align * shards
+        if rows - rows0 <= max(rows0 // 16, 0) or align == 1:
+            break
+    local = rows // shards
+    cap = min(rowblk, local)
+    blk = next((b for b in range(cap - cap % 8, 0, -8) if local % b == 0),
+               0) or next(b for b in range(cap, 0, -1) if local % b == 0)
+    return rows, blk
+
+
 def deployment(kv: dict) -> Deployment:
-    """Resolve the key=value pairs of a configuration and its traffic."""
+    """Resolve the key=value pairs of a configuration and its traffic.
+    ``mesh_devices`` is a layout key: the sharded engine runs the
+    one-chip round on the same overlay, so it is modelled where the row
+    count it rounds to is one chip's."""
     for k, default in _UNMODELLED.items():
         if kv.get(k, default) not in (default, "0", "0.0"):
             raise ValueError(f"the reference does not model {k}={kv[k]}")
@@ -127,8 +150,15 @@ def deployment(kv: dict) -> Deployment:
     budget = WORDS_X_ROWBLK // (2 if int(kv.get("fuse_update", "0")) else 1)
     rowblk = min(ROWBLK_CAP, max(8, budget // words // 8 * 8))
     ping, msg = float(kv["ping_interval"]), float(kv["message_interval"])
+    n_peers = int(kv["n_peers"])
+    shards = max(int(kv.get("mesh_devices", "0")), 1)
+    if layout(n_peers, rowblk, shards)[0] != layout(n_peers, rowblk)[0]:
+        raise ValueError(
+            f"mesh_devices={shards} pads {n_peers} peers to another row "
+            "count than one chip does: the reference models the one-chip "
+            "row count only")
     return Deployment(
-        n_peers=int(kv["n_peers"]), n_slots=n_slots,
+        n_peers=n_peers, n_slots=n_slots,
         alpha=float(kv["powerlaw_alpha"]),
         churn_rate=float(kv["churn_rate"]),
         max_strikes=int(kv["max_missed_pings"]),
@@ -137,7 +167,7 @@ def deployment(kv: dict) -> Deployment:
         roll_groups=groups, pull_window=pull_window, block_perm=block_perm,
         rowblk=rowblk, seed=int(kv["prng_seed"]),
         target=float(kv["coverage_target"]),
-        max_rounds=int(kv["max_rounds"]))
+        max_rounds=int(kv["max_rounds"]), shards=shards)
 
 
 @dataclasses.dataclass
@@ -172,14 +202,7 @@ def sample_overlay(dep: Deployment) -> Overlay:
     n, D = dep.n_peers, dep.n_slots
     rowblk = min(dep.rowblk, max(8, (WORDS_X_ROWBLK // dep.n_words) // 8 * 8))
     rng = np.random.default_rng(dep.seed)
-    rows0 = max(1, -(-n // LANES))
-    for align in (8, 4, 2, 1):
-        rows = -(-rows0 // align) * align
-        if rows - rows0 <= max(rows0 // 16, 0) or align == 1:
-            break
-    cap = min(rowblk, rows)
-    blk = next((b for b in range(cap - cap % 8, 0, -8) if rows % b == 0),
-               0) or next(b for b in range(cap, 0, -1) if rows % b == 0)
+    rows, blk = layout(n, rowblk, dep.shards)
     T = rows // blk
     if dep.block_perm:
         pblock = rng.permutation(T).astype(np.int32)
@@ -231,60 +254,118 @@ class Outcome:
     coverage: float        # exact coverage of the final state
 
 
-def lane_gather(y, lanes, chunk: int):
+def lane_gather(y, lanes, chunk: int, parts: int = 1):
     """``out[r, k, c] = y[r, k, lanes[r, c]]``: each peer reads word ``k``
     of lane ``lanes[r, c]`` of the row beside it, exactly.  Written as a
     product with a one-hot matrix per row, byte by byte: a bfloat16
     holds 0-255 exactly and each output sums one nonzero term, so the
     product is exact on any backend, and on a TPU it runs on the matrix
-    unit instead of as one gather descriptor per element."""
+    unit instead of as one gather descriptor per element.  The rows go
+    ``chunk`` at a time; with ``parts`` > 1, rows split into that many
+    equal parts (one a device) and each step takes ``chunk`` rows of
+    every part, so no step waits on rows another device holds."""
     import jax
     import jax.numpy as jnp
 
     R, K, L = y.shape
     u = jax.lax.bitcast_convert_type(y, jnp.uint32)
     b = jnp.stack([(u >> (8 * i)) & 255 for i in range(4)], axis=2)
-    b = b.reshape(R // chunk, chunk, 4 * K, L).astype(jnp.bfloat16)
     iota = jnp.arange(L, dtype=jnp.int32)
+    if parts == 1:
+        b = b.reshape(R // chunk, chunk, 4 * K, L).astype(jnp.bfloat16)
 
-    def one(args):
-        bb, ll = args
-        onehot = (ll[:, None, :] == iota[None, :, None]).astype(jnp.bfloat16)
-        return jnp.einsum("rkj,rjc->rkc", bb, onehot,
-                          preferred_element_type=jnp.float32)
+        def one(args):
+            bb, ll = args
+            onehot = (ll[:, None, :] == iota[None, :, None]).astype(jnp.bfloat16)
+            return jnp.einsum("rkj,rjc->rkc", bb, onehot,
+                              preferred_element_type=jnp.float32)
 
-    out = jax.lax.map(one, (b, lanes.reshape(R // chunk, chunk, L)))
+        out = jax.lax.map(one, (b, lanes.reshape(R // chunk, chunk, L)))
+    else:
+        b = b.reshape(parts, R // parts // chunk, chunk, 4 * K, L)
+        b = b.swapaxes(0, 1).astype(jnp.bfloat16)
+        ls = lanes.reshape(parts, R // parts // chunk, chunk, L).swapaxes(0, 1)
+
+        def one(args):
+            bb, ll = args
+            onehot = (ll[..., None, :] == iota[:, None]).astype(jnp.bfloat16)
+            return jnp.einsum("prkj,prjc->prkc", bb, onehot,
+                              preferred_element_type=jnp.float32)
+
+        out = jax.lax.map(one, (b, ls)).swapaxes(0, 1)
     out = out.reshape(R, K, 4, L).astype(jnp.uint32)
     w = out[:, :, 0] | (out[:, :, 1] << 8) | (out[:, :, 2] << 16) | (out[:, :, 3] << 24)
     return jax.lax.bitcast_convert_type(w, jnp.int32)
 
 
-class Scenario:
-    """Runs scenarios of one deployment on JAX's default device (the
-    chip once the program's state is freed; the CPU in tests).  State
-    is kept as rows of 128 peers: planes int32[R, W, 128], masks
-    [R, 128], per-slot tables [D, R, 128]."""
+def or_words(x, gather=None):
+    """int32[W]: the OR of ``x[R, W, 128]`` over its rows and lanes.
+    Where rows are split over devices, ``gather`` (a sharding that puts
+    the whole on every device) brings the per-row ORs together first:
+    XLA's CPU backend has no OR all-reduce."""
+    import jax
+    import jax.numpy as jnp
 
-    def __init__(self, dep: Deployment, liveness_every: int | None = None):
+    if gather is None:
+        return jax.lax.reduce(x, jnp.int32(0), jax.lax.bitwise_or, (0, 2))
+    rows = jax.lax.reduce(x, jnp.int32(0), jax.lax.bitwise_or, (2,))
+    rows = jax.lax.with_sharding_constraint(rows, gather)
+    return jax.lax.reduce(rows, jnp.int32(0), jax.lax.bitwise_or, (0,))
+
+
+class Scenario:
+    """Runs scenarios of one deployment on the given devices (the chips
+    once the program's state is freed; the CPU in tests), by default
+    JAX's default device.  State is kept as rows of 128 peers: planes
+    int32[R, W, 128], masks [R, 128], per-slot tables [D, R, 128].  On
+    more than one device every row-indexed array is split by rows over
+    a 1-D mesh of them, and the round stays a plain ``jit``: XLA's
+    partitioner moves the rows a gather reads from another device."""
+
+    def __init__(self, dep: Deployment, liveness_every: int | None = None,
+                 devices=None):
         self.dep = dep
         self.every = liveness_every or dep.liveness_every
         self.ov = sample_overlay(dep)
         self.src = sources(self.ov.valid, dep.n_msgs)
         R = self.ov.rows
+        self.parts = len(devices) if devices else 1
+        self.mesh = None
+        if self.parts > 1:
+            from jax.sharding import Mesh
+
+            if R % self.parts:
+                raise ValueError(f"{R} rows do not split over {self.parts} devices")
+            self.mesh = Mesh(np.asarray(devices), ("rows",))
         self.chunk = next(c for c in (4096, 2048, 1024, 512, 256, 128, 64,
-                                      32, 16, 8, 4, 2, 1) if R % c == 0)
+                                      32, 16, 8, 4, 2, 1)
+                          if (R // self.parts) % c == 0)
         self._fns = {}
 
-    def _consts(self):
+    def _sharding(self, axis: int | None):
+        """Where an array goes: split along its row ``axis`` over the
+        mesh (None: whole on every device); no mesh, JAX's default."""
+        if self.mesh is None:
+            return None
+        from jax.sharding import NamedSharding, PartitionSpec as P
+
+        spec = P() if axis is None else P(*[None] * axis, "rows")
+        return NamedSharding(self.mesh, spec)
+
+    def _put(self, x, axis: int | None):
         import jax.numpy as jnp
 
-        ov = self.ov
-        return {"srow": jnp.asarray(ov.sender_rows()),
-                "deg": jnp.asarray(ov.deg.astype(np.int32)),
-                "valid": jnp.asarray(ov.valid),
-                "src": jnp.asarray(self.src)}
+        return jnp.asarray(x, device=self._sharding(axis))
 
-    def _round_fn(self, live: bool):
+    def _consts(self):
+        ov = self.ov
+        return {"srow": self._put(ov.sender_rows(), 1),
+                "deg": self._put(ov.deg.astype(np.int32), 0),
+                "valid": self._put(ov.valid, 0),
+                "src": self._put(self.src, None)}
+
+    def _round(self, live: bool):
+        """The round, unjitted; ``run`` calls this under ``jit``."""
         import jax
         import jax.numpy as jnp
 
@@ -294,12 +375,12 @@ class Scenario:
         hmask = jnp.asarray(honest_mask(dep.n_msgs, W))[None, :, None]
         rows = jnp.arange(R, dtype=jnp.int32)
         pid = rows[:, None] * LANES + jnp.arange(LANES, dtype=jnp.int32)[None]
-        chunk = self.chunk
+        chunk, parts, whole = self.chunk, self.parts, self._sharding(None)
 
         def nbr_words(x, srow_d, lanes_d):
             """x[R, K, 128] as seen by every peer through one slot."""
             return lane_gather(jnp.take(x, srow_d, axis=0),
-                               lanes_d.astype(jnp.int32), chunk)
+                               lanes_d.astype(jnp.int32), chunk, parts)
 
         def rnd(st, c, r, m, inject):
             key, k_churn, _, k_pull, _ = jax.random.split(st["key"], 5)
@@ -313,13 +394,13 @@ class Scenario:
                     lanes, strikes = carry
                     y = jnp.take(alive_w[:, None], c["srow"][d], axis=0)
                     lane = lanes[d].astype(jnp.int32)
-                    nbr_alive = lane_gather(y, lane, chunk)[:, 0] != 0
+                    nbr_alive = lane_gather(y, lane, chunk, parts)[:, 0] != 0
                     s = strikes[d].astype(jnp.int32)
                     dead = (d < c["deg"]) & ~nbr_alive
                     s_new = jnp.where(dead, jnp.minimum(s + 1, dep.max_strikes + 1), 0)
                     cand = rewire_lane(pid, d, r, jnp.int32(dep.seed))
                     take = ((s_new >= dep.max_strikes)
-                            & (lane_gather(y, cand, chunk)[:, 0] != 0))
+                            & (lane_gather(y, cand, chunk, parts)[:, 0] != 0))
                     lanes = lanes.at[d].set(jnp.where(take, cand, lane).astype(jnp.int8))
                     strikes = strikes.at[d].set(jnp.where(take, 0, s_new).astype(jnp.int8))
                     return lanes, strikes
@@ -358,7 +439,7 @@ class Scenario:
             seen = seen | new
             pc = jax.lax.population_count
             per_row = lambda x: jnp.sum(x.reshape(R, -1), axis=1, dtype=jnp.int32)
-            orw = jax.lax.reduce(seen, jnp.int32(0), jax.lax.bitwise_or, (0, 2))
+            orw = or_words(seen, whole)
             stats = {"hits": per_row(jnp.where((ok_w != 0)[:, None],
                                                pc(seen & hmask), 0)),
                      "n_ok": jnp.sum(ok_w != 0, dtype=jnp.int32),
@@ -367,7 +448,7 @@ class Scenario:
                   "strikes": strikes, "lanes": lanes, "key": key}
             return st, stats
 
-        return jax.jit(rnd)
+        return rnd
 
     def initial(self, key: np.ndarray) -> dict:
         import jax.numpy as jnp
@@ -378,11 +459,12 @@ class Scenario:
         if dep.stagger <= 0:
             for m, s in enumerate(self.src):
                 seen[s // LANES, m // WORD_BITS, s % LANES] |= np.uint32(1 << (m % WORD_BITS))
-        seen = jnp.asarray(seen.view(np.int32))
-        return {"seen": seen, "frontier": seen, "alive": jnp.asarray(ov.valid),
-                "strikes": jnp.zeros((dep.n_slots, R, LANES), jnp.int8),
-                "lanes": jnp.asarray(ov.lanes),
-                "key": jnp.asarray(np.asarray(key, np.uint32))}
+        seen = self._put(seen.view(np.int32), 0)
+        return {"seen": seen, "frontier": seen, "alive": self._put(ov.valid, 0),
+                "strikes": jnp.zeros((dep.n_slots, R, LANES), jnp.int8,
+                                     device=self._sharding(1)),
+                "lanes": self._put(ov.lanes, 1),
+                "key": self._put(np.asarray(key, np.uint32), None)}
 
     def run(self, key: np.ndarray) -> Outcome:
         """The scenario from ``key`` to the stopping rule of a coverage
@@ -399,7 +481,12 @@ class Scenario:
         while r < dep.max_rounds:
             live = r % self.every == 0
             if live not in self._fns:
-                self._fns[live] = self._round_fn(live)
+                # the round's tables are built under the trace: a peer-id
+                # plane made outside it would be baked into the program
+                # as a constant (512 MB at 2^27 peers), an executable too
+                # large for the persistent compile cache
+                self._fns[live] = jax.jit(
+                    lambda *a, live=live: self._round(live)(*a))
             m = r // dep.stagger if dep.stagger > 0 else 0
             inject = dep.stagger > 0 and r % dep.stagger == 0 and m < dep.n_msgs
             st, stats = self._fns[live](st, c, jnp.int32(r), jnp.int32(m),

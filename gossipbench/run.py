@@ -86,10 +86,8 @@ def execute(args, *, require_tpu: bool = True, bench_path: str | None = None,
     ref_mod = spec.reference(kv["bench_reference"])
     dep = ref_mod.deployment(kv)
 
-    os.environ["GOSSIP_TUNING_CACHE"] = "off"      # only committed inputs
     import jax
 
-    from p2p_gossipprotocol_tpu.aligned import aligned_coverage
     from p2p_gossipprotocol_tpu.device import enable_compile_cache
 
     if enable_compile_cache() is not None:
@@ -122,12 +120,12 @@ def execute(args, *, require_tpu: bool = True, bench_path: str | None = None,
 
     # the sampled scenario's answer; then the program's state goes
     index, key, st, tp, rounds = kept
-    prog = check.flatten(st, tp, rounds, aligned_coverage(setup.sim, st, tp))
+    prog = check.flatten(st, tp, rounds, setup.coverage(st, tp))
     build_s = setup.build_s
     del st, tp, kept, setup
     gc.collect()
     t_ref = time.perf_counter()
-    scen = ref_mod.Scenario(dep)
+    scen = ref_mod.Scenario(dep, devices=devs)
     numbers, parts = check.compare(prog, scen.run(key), scen.ov)
     ref_s = time.perf_counter() - t_ref
 

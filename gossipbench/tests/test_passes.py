@@ -67,3 +67,14 @@ def test_unnamed_kernels_read_nothing():
     for name in READERS:
         assert _read(name, run) is None
     assert _read("push_ms_per_round", _Run(None, [])) is None
+
+
+def test_pass_time_averages_over_chips():
+    # two chips, one round: chip 0's push takes 3 ms, chip 1's 4 ms
+    body = "jit(looped)/while/body/"
+    ops = [tr.Op(c, "push.8", "custom-call", 0.0, (3 + c) * 1e-3,
+                 body + "push/push/pallas_call") for c in range(2)]
+    s = tr.summarize(ops, [tr.Span("run", 0, 0.0, 8e-3)], n_chips=2)
+    run = _Run(s, [Scenario(0, 0.0, 8e-3, 1, False)])
+    assert _read("push_ms_per_round", run) == pytest.approx(3.5)
+    assert _read("pull_ms_per_round", run) is None

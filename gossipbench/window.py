@@ -71,6 +71,28 @@ class Setup:
     cap: int
     sched_end: int
 
+    def coverage(self, state, topo) -> float:
+        """The program's census of a final state, ``aligned_coverage``.
+        The sharded engine keeps its rules on its single-chip twin.  On
+        the CPU the census's OR over rows would be an all-reduce that
+        XLA's CPU backend lacks, so there the planes it counts are read
+        on one device; a TPU reduces them where they lie."""
+        import jax
+
+        from p2p_gossipprotocol_tpu.aligned import aligned_coverage
+
+        one = getattr(self.sim, "_inner", None)
+        if one is None:
+            return aligned_coverage(self.sim, state, topo)
+        first = self.sim.mesh.devices.flat[0]
+        if first.platform != "cpu":
+            return aligned_coverage(one, state, topo)
+        seen, alive, byz, valid = jax.device_put(
+            (state.seen_w, state.alive_b, state.byz_w, topo.valid_w), first)
+        return aligned_coverage(
+            one, state.replace(seen_w=seen, alive_b=alive, byz_w=byz),
+            topo.replace(valid_w=valid))
+
 
 def network_config(cell):
     from p2p_gossipprotocol_tpu.config import NetworkConfig
@@ -96,8 +118,6 @@ def run_scenario(setup: Setup, key: np.ndarray, index: int):
     import jax
     import jax.numpy as jnp
 
-    from p2p_gossipprotocol_tpu.aligned import aligned_coverage
-
     sim = setup.sim
     t0 = time.perf_counter()
     with _span("init", index):
@@ -110,20 +130,43 @@ def run_scenario(setup: Setup, key: np.ndarray, index: int):
             warmup=False)
     t2 = time.perf_counter()
     failed = rounds < setup.sched_end or (
-        rounds >= setup.cap and aligned_coverage(sim, st, tp) < setup.target)
+        rounds >= setup.cap and setup.coverage(st, tp) < setup.target)
     return Scenario(index, t1 - t0, t2 - t0, rounds, failed), st, tp
 
 
+def engine_for(cell) -> str:
+    """The engine a cell's chips imply: the aligned engine on one chip,
+    its flat row-sharded twin on more.  The configuration's
+    ``mesh_devices`` has to say as many chips; the 2-D and two-tier
+    factorizations are not benchmarked."""
+    from gossipbench import spec
+
+    kv = cell.kv()
+    mesh = max(int(kv.get("mesh_devices", "0")), 1)
+    if mesh != cell.chips:
+        raise spec.SpecError(f"{cell.name}: the cell asks for {cell.chips} "
+                             f"chips, its config for mesh_devices={mesh}")
+    for k in ("msg_shards", "hier_hosts", "hier_devs"):
+        if int(kv.get(k, "0")) > 1:
+            raise spec.SpecError(f"{cell.name}: {k}={kv[k]}: the benchmark "
+                                 "runs the flat row-sharded engine only")
+    return "aligned" if cell.chips == 1 else f"aligned-sharded-{cell.chips}"
+
+
 def build(cell) -> Setup:
+    # the engine's module is imported before the clock starts: build_s
+    # times the overlay and the simulator, not Python's imports
+    import p2p_gossipprotocol_tpu.aligned  # noqa: F401
     from p2p_gossipprotocol_tpu.engines import build_simulator
 
     kv = cell.kv()
+    want = engine_for(cell)
     cfg = network_config(cell)
     t0 = time.perf_counter()
     sim, engine = build_simulator(cfg)
     build_s = time.perf_counter() - t0
-    if engine != "aligned":
-        raise RuntimeError(f"{cell.name}: engine {engine!r}, not aligned")
+    if engine != want:
+        raise RuntimeError(f"{cell.name}: engine {engine!r}, not {want}")
     n_msgs = int(kv["n_messages"])
     stagger = int(kv.get("message_stagger", "0"))
     return Setup(sim=sim, build_s=build_s,
@@ -135,10 +178,8 @@ def build(cell) -> Setup:
 def warm_up(setup: Setup, seed: int) -> None:
     """The warm-up scenario, plus the census the window calls on a
     scenario that reaches the round cap."""
-    from p2p_gossipprotocol_tpu.aligned import aligned_coverage
-
     _, st, tp = run_scenario(setup, scenario_key(seed, -1), -1)
-    aligned_coverage(setup.sim, st, tp)
+    setup.coverage(st, tp)
 
 
 def window(setup: Setup, seed: int, seconds: float):
